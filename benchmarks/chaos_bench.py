@@ -53,11 +53,12 @@ from repro.serve import (PRIORITY_HIGH, PRIORITY_LOW, PlanCache, ShedResult,
                          TransformService)
 from repro.tuning import wisdom as wisdom_lib
 from repro.tuning.candidates import default_candidate
+from repro.launch.mesh import make_mesh
 
 SMOKE = {smoke}
 N = 16
 AXES = {{"y": 2, "z": 4}}
-mesh = jax.make_mesh((2, 4), ("y", "z"))
+mesh = make_mesh((2, 4), ("y", "z"))
 rng = np.random.RandomState(0)
 xc = (rng.randn(N, N, N) + 1j * rng.randn(N, N, N)).astype(np.complex64)
 xr = rng.randn(N, N, N).astype(np.float32)

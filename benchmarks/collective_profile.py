@@ -30,7 +30,8 @@ CODE = """
 import time, jax, json
 from repro.core import Croft3D, Decomposition, FFTOptions
 from repro.launch import hlo_cost
-mesh = jax.make_mesh((8,), ("p",), axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("p",))
 N = {n}  # scaled-down stand-in for 1024^3 (same op structure)
 out = {{}}
 for tag, opts in {{

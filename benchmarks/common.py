@@ -50,7 +50,11 @@ def load_dryrun(name: str):
 
 
 def run_subprocess_bench(code: str, n_devices: int, timeout: int = 600) -> str:
+    """Run ``code`` in a child on ``n_devices`` virtual CPU devices: a
+    CPU-mesh rehearsal, pinned to ``JAX_PLATFORMS=cpu`` so it never
+    competes with the parent for an accelerator."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
@@ -65,7 +69,9 @@ def run_subprocess_bench(code: str, n_devices: int, timeout: int = 600) -> str:
 # Param Bioblaze-analogue on TPU v5e constants; used to extrapolate the
 # P-sweeps of tables 1-3 from the per-device transpose/compute volumes.
 
-from repro.launch.roofline import HBM_BW, LINK_BW, PEAK_FLOPS  # noqa: E402
+from repro.tuning.cost_model import (  # noqa: E402
+    PRIOR_HBM_BW as HBM_BW, PRIOR_LINK_BW as LINK_BW,
+    PRIOR_PEAK_FLOPS as PEAK_FLOPS)
 
 
 def fft_step_model(grid, n_procs: int, decomposition: str = "pencil",

@@ -59,6 +59,7 @@ from repro.core import Croft3D, Decomposition, FFTOptions
 from repro.tuning import cost_model
 from repro.tuning.candidates import Candidate
 from repro.tuning.measure import _random_input
+from repro.launch.mesh import make_mesh
 
 rounds = {rounds}
 N = 64
@@ -73,9 +74,9 @@ report = {{"backend": jax.default_backend(), "shape": [N, N, N],
            "cases": {{}}}}
 
 cases = [
-    ("pencil", jax.make_mesh((2, 4), ("y", "z")),
+    ("pencil", make_mesh((2, 4), ("y", "z")),
      Decomposition("pencil", ("y", "z"))),
-    ("slab", jax.make_mesh((8,), ("p",)), Decomposition("slab", ("p",))),
+    ("slab", make_mesh((8,), ("p",)), Decomposition("slab", ("p",))),
 ]
 pencil_plans = None
 for name, mesh, dec in cases:

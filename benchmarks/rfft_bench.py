@@ -47,10 +47,11 @@ from repro.core import Croft3D, Decomposition, FFTOptions
 from repro.tuning import cost_model
 from repro.tuning.candidates import Candidate
 from repro.tuning.measure import _random_input
+from repro.launch.mesh import make_mesh
 
 shapes = {shapes!r}
 rounds = {rounds}
-mesh = jax.make_mesh((2, 4), ("y", "z"))
+mesh = make_mesh((2, 4), ("y", "z"))
 dec = Decomposition("pencil", ("y", "z"))
 report = {{"mesh": {{"y": 2, "z": 4}}, "backend": jax.default_backend(),
            "decomp": "pencil[yxz]", "shapes": {{}}}}
@@ -124,7 +125,7 @@ for shape in shapes:
 # 1-axis mesh it serves ------------------------------------------------
 sshape = tuple(shapes[-1])
 stag = "x".join(map(str, sshape))
-mesh1 = jax.make_mesh((8,), ("p",))
+mesh1 = make_mesh((8,), ("p",))
 sdec = Decomposition("slab", ("p",))
 splans = {{strat: Croft3D(sshape, mesh1, sdec, FFTOptions(),
                           problem="r2c",

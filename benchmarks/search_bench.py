@@ -96,10 +96,11 @@ from repro.core import Croft3D
 from repro.launch import hlo_cost
 from repro.tuning import candidates as cand_lib, cost_model
 from repro.tuning.measure import _random_input, time_forward
+from repro.launch.mesh import make_mesh
 
 shape = tuple({shape})
 axes = {axes}
-mesh = jax.make_mesh(tuple(axes.values()), tuple(axes))
+mesh = make_mesh(tuple(axes.values()), tuple(axes))
 
 cand = cand_lib.ScheduleCandidate.from_plan_key({token!r})
 cand.validate(shape, axes)

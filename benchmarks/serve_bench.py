@@ -55,8 +55,9 @@ TRACE = {trace!r}
 tracer = None
 if TRACE:
     from repro import obs
+    from repro.launch.mesh import make_mesh
     tracer = obs.enable()
-mesh = jax.make_mesh((2, 4), ("y", "z"))
+mesh = make_mesh((2, 4), ("y", "z"))
 wisdom = os.path.join(tempfile.mkdtemp(), "serve_wisdom.json")
 report = {{"backend": jax.default_backend(),
            "mesh": dict(mesh.shape),

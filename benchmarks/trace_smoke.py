@@ -46,9 +46,10 @@ from repro.core import Croft3D, Decomposition, FFTOptions
 from repro.obs import instrument
 from repro.serve import TransformService
 from repro.tuning.measure import _random_input
+from repro.launch.mesh import make_mesh
 
 tracer = obs.enable()
-mesh = jax.make_mesh((2, 4), ("y", "z"))
+mesh = make_mesh((2, 4), ("y", "z"))
 N = 32
 
 # -- tuned 32^3 forward + the two acceptance plans -------------------------

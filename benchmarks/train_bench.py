@@ -55,11 +55,12 @@ from repro.models.spectral import (init_spectral_filter_params,
                                    spectral_filter_apply)
 from repro.train import make_spectral_train_step, spectral_loss_fn
 from repro.tuning import Candidate, per_stage_costs
+from repro.launch.mesh import make_mesh
 
 N = {n}
 steps = {steps}
 shape = (N, N, N)
-mesh = jax.make_mesh((4, 2), ("y", "x"))
+mesh = make_mesh((4, 2), ("y", "x"))
 dec = Decomposition("pencil", ("y", "x"))
 sizes = dict(mesh.shape)
 report = {{"shape": list(shape), "mesh": sizes,

@@ -28,11 +28,12 @@ _SWEEP_CODE = """
 import dataclasses, json, numpy as np, jax, jax.numpy as jnp
 from repro.core import Croft3D
 from repro import tuning
+from repro.launch.mesh import make_mesh
 
 shapes = {shapes!r}
 top_k = {top_k}
 iters = {iters}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 report = {{"mesh": {{"data": 2, "model": 4}}, "backend": jax.default_backend(),
            "shapes": {{}}}}
 for shape in shapes:

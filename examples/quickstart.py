@@ -12,6 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import Croft3D, Decomposition, FFTOptions
+from repro.launch.mesh import make_mesh
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -22,6 +24,7 @@ def main():
     ap.add_argument("--decomp", default="pencil",
                     choices=["pencil", "slab", "cell"])
     args = ap.parse_args()
+    use_compile_cache()
 
     n = args.n
     rng = np.random.RandomState(0)
@@ -30,17 +33,13 @@ def main():
     if args.devices > 1:
         if args.decomp == "pencil":
             py = 2
-            mesh = jax.make_mesh(
-                (py, args.devices // py), ("y", "z"),
-                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+            mesh = make_mesh((py, args.devices // py), ("y", "z"))
             decomp = Decomposition("pencil", ("y", "z"))
         elif args.decomp == "slab":
-            mesh = jax.make_mesh((args.devices,), ("z",),
-                                 axis_types=(jax.sharding.AxisType.Auto,))
+            mesh = make_mesh((args.devices,), ("z",))
             decomp = Decomposition("slab", ("z",))
         else:
-            mesh = jax.make_mesh((2, 2, args.devices // 4), ("a", "b", "c"),
-                                 axis_types=(jax.sharding.AxisType.Auto,) * 3)
+            mesh = make_mesh((2, 2, args.devices // 4), ("a", "b", "c"))
             decomp = Decomposition("cell", ("a", "b", "c"))
     else:
         mesh = decomp = None

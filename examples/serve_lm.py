@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.models.spectral import spectral_mixer
 from repro.serve import TransformService
+from repro.launch.compile_cache import use_compile_cache
 
 
 def mixer_via_service(svc: TransformService, x: np.ndarray) -> np.ndarray:
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--dmodel", type=int, default=32)
     ap.add_argument("--wisdom", default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     rng = np.random.RandomState(0)
     prompts = [rng.randn(args.seq, args.dmodel).astype(np.float32)

@@ -20,6 +20,7 @@ import threading
 import numpy as np
 
 from repro.serve import TransformService
+from repro.launch.compile_cache import use_compile_cache
 
 N = 16
 
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--requests", type=int, default=8,
                     help="requests per client app")
     args = ap.parse_args()
+    use_compile_cache()
 
     rng = np.random.RandomState(0)
     errs = []

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import Croft3D, FFTOptions, poisson_solve
+from repro.launch.mesh import make_mesh
+from repro.launch.compile_cache import use_compile_cache
 
 
 def wavenumbers(n):
@@ -58,12 +60,12 @@ def main():
                     choices=["packed", "embed"],
                     help="force the r2c strategy (default: planner/auto)")
     args = ap.parse_args()
+    use_compile_cache()
 
     n = args.n
     nh = n // 2 + 1
     if args.devices > 1:
-        mesh = jax.make_mesh((2, args.devices // 2), ("y", "z"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        mesh = make_mesh((2, args.devices // 2), ("y", "z"))
         if args.strategy is None:
             plan = Croft3D.tuned((n, n, n), mesh, mode=args.tune,
                                  problem="r2c", wisdom_path=args.wisdom)

@@ -25,6 +25,7 @@ from repro.configs import get_config  # noqa: F401  (registry also usable)
 from repro.launch import train as train_cli
 from repro.models.config import (AttentionSpec, LayerSpec, ModelConfig,
                                  simple_stack)
+from repro.launch.compile_cache import use_compile_cache
 
 PRESETS = {
     # ~101M params: 12L d=768 12H swiglu, 32k vocab (GPT-2-small-ish)
@@ -59,6 +60,7 @@ def run_spectral(args):
                                        place_spectral_filter_params,
                                        spectral_filter_apply)
     from repro.train import make_spectral_train_step
+    from repro.launch.mesh import make_mesh
 
     n = args.size
     shape = (n, n, n)
@@ -68,9 +70,9 @@ def run_spectral(args):
         print(f"spectral workload: {shape} single-device")
     else:
         if n_dev % 2 == 0:
-            mesh = jax.make_mesh((n_dev // 2, 2), ("y", "x"))
+            mesh = make_mesh((n_dev // 2, 2), ("y", "x"))
         else:
-            mesh = jax.make_mesh((n_dev,), ("y",))
+            mesh = make_mesh((n_dev,), ("y",))
         # grad=True: the planner prices forward + adjoint schedule, so
         # the chosen plan is the best *training step*, not best forward
         plan = Croft3D.tuned(shape, mesh, mode="model", problem="r2c",
@@ -109,6 +111,7 @@ def main():
                     help="spectral: SGD learning rate")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    use_compile_cache()
     if args.workload == "spectral":
         run_spectral(args)
         return

@@ -1,10 +1,1 @@
-"""repro: CROFT-style distributed 3-D FFT reproduction on JAX.
-
-Importing the package installs the JAX version-compat shims (see
-``repro.compat``) so every subpackage, test snippet, and example can be
-written against the newer jax surface.
-"""
-
-from repro import compat as _compat
-
-_compat.install()
+"""repro: CROFT-style distributed 3-D FFT reproduction on JAX."""

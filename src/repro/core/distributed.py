@@ -51,9 +51,8 @@ from typing import Callable, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.core import local_fft
 from repro.core import schedule as schedule_lib

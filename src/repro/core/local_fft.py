@@ -44,8 +44,7 @@ def fft_matmul(x: jax.Array, sign: int = -1, *, plan_cache: bool = True,
 
     n <= max_radix           : single DFT matmul
     n <= max_radix**2        : reshape (n1, n2); DFT(n1) matmul; twiddle;
-                               DFT(n2) matmul; transpose  (the Pallas kernel
-                               implements exactly this path)
+                               DFT(n2) matmul; transpose
     larger                   : six-step recursion on the n2 axis
     """
     n = x.shape[-1]

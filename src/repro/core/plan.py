@@ -25,9 +25,6 @@ import numpy as np
 # Largest DFT applied as a single matmul.  64 keeps the stacked-real complex
 # matmul at exactly 128x128 — one MXU tile on TPU.
 MAX_RADIX = 64
-# Largest 1-D size handled by a single two-level four-step plan (the Pallas
-# kernel path).  Larger sizes recurse (six-step) on the jnp path.
-MAX_TWO_LEVEL = MAX_RADIX * MAX_RADIX
 
 
 def _is_pow2(n: int) -> bool:
@@ -60,7 +57,8 @@ def dft_matrix(n: int, sign: int, dtype=np.complex64) -> np.ndarray:
 def twiddle_matrix(n1: int, n2: int, sign: int, dtype=np.complex64) -> np.ndarray:
     """Four-step inter-stage twiddles T[n2, k1] = exp(sign*2πi*k1*n2/(n1*n2)).
 
-    Laid out (n2, k1) to match the kernel's post-stage-1 operand layout.
+    Laid out (n2, k1) to match ``local_fft.fft_matmul``'s post-stage-1
+    operand layout.
     """
     k1 = np.arange(n1)
     j2 = np.arange(n2)
@@ -95,12 +93,6 @@ class FFTPlan:
     w1: np.ndarray  # (n1, n1) complex DFT matrix
     w2: Optional[np.ndarray]  # (n2, n2) or None when n2 == 1
     tw: Optional[np.ndarray]  # (n2, n1) twiddles or None when n2 == 1
-    w1_stacked: np.ndarray  # (2*n1, 2*n1) float32
-    w2_stacked: Optional[np.ndarray]
-
-    @property
-    def two_level(self) -> bool:
-        return self.n2 <= MAX_RADIX
 
     def constants_jnp(self, rematerialize: bool = False):
         """Return (w1, w2, tw) as jnp complex arrays.
@@ -150,8 +142,6 @@ def make_plan(n: int, sign: int = -1, dtype_name: str = "complex64",
     return FFTPlan(
         n=n, n1=n1, n2=n2, sign=sign, dtype=dtype,
         w1=w1, w2=w2, tw=tw,
-        w1_stacked=stacked_real(w1),
-        w2_stacked=None if w2 is None else stacked_real(w2),
     )
 
 

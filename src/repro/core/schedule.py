@@ -46,7 +46,6 @@ from typing import Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 from repro.core import local_fft
 
 AxisName = Union[str, tuple]
@@ -71,8 +70,8 @@ def _flat(axis) -> tuple:
 def _axis_size(axis: AxisName) -> int:
     """Size of a (possibly folded) mesh axis from inside shard_map."""
     if isinstance(axis, tuple):
-        return math.prod(axis_size(a) for a in _flat(axis))
-    return axis_size(axis)
+        return math.prod(jax.lax.axis_size(a) for a in _flat(axis))
+    return jax.lax.axis_size(axis)
 
 
 def _axis_str(axis: AxisName) -> str:
@@ -551,7 +550,7 @@ def _pack_pieces(blk: jax.Array, axis: AxisName, split_axis: int) -> list:
     slice, replacing the per-round ``dynamic_slice`` of the old path.
     """
     from repro.kernels import transpose_pack
-    p = axis_size(axis)
+    p = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     return transpose_pack.pack_pieces(blk, split_axis, idx, p)
 
@@ -571,7 +570,7 @@ def _ring_transpose(blk: jax.Array, axis: AxisName, split_axis: int,
     ``dynamic_update_slice`` writes the pairwise emulation pays.
     """
     from repro.kernels import transpose_pack
-    p = axis_size(axis)
+    p = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     pieces = _pack_pieces(blk, axis, split_axis)
     recv = [pieces[0]]                      # round 0: my own block, no comm
@@ -599,7 +598,7 @@ def _pairwise_transpose(blk: jax.Array, axis: AxisName, split_axis: int,
     impls; this is the baseline whose serialized rounds the ring
     pipeline exists to avoid (figs 12-15).  The send side shares the
     fused rotated pack."""
-    p = axis_size(axis)
+    p = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     n_cat = blk.shape[concat_axis]
     pieces = _pack_pieces(blk, axis, split_axis)
@@ -690,7 +689,7 @@ def ring_round(blk: jax.Array, st: Stage, opts, rnd: int,
     pieces = _pack_pieces(blk, axis, st.split_axis + off)
     if rnd == 0:
         return pieces[0]
-    p = axis_size(axis)
+    p = jax.lax.axis_size(axis)
     perm = [(i, (i + rnd) % p) for i in range(p)]
     return jax.lax.ppermute(pieces[rnd], axis, perm)
 

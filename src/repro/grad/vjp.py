@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import schedule as schedule_lib
 from repro.grad.adjoint import (adjoint_schedule, fold_dc_plane_t,
                                 unfold_dc_plane_t)

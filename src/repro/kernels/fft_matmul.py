@@ -5,122 +5,144 @@ factorization — the MXU-native replacement for FFTW's butterfly kernels
 Layout decisions:
   * complex data travels as separate float32 real/imag planes (TPU Pallas has
     no complex registers);
-  * each DFT stage is ONE real matmul against a stacked-real matrix
+  * each DFT matmul is ONE real matmul against a stacked-real matrix
       [xr xi] @ [[Wr, Wi], [-Wi, Wr]] = [Re(xW), Im(xW)]
-    so with the default radix 64 the stage-1 operand is (rows, 128) @
-    (128, 128) — exactly an MXU tile;
-  * the batch dimension is tiled into VMEM blocks via BlockSpec; DFT
-    matrices/twiddles are small (<=128x128 f32) and loaded whole per block.
+    run at ``Precision.HIGHEST`` (f32 contraction);
+  * every in-kernel operation works on 2-D (rows, lanes) slabs cut at
+    128-lane boundaries — Mosaic refuses the (rows, n1, n2) reshapes and
+    transposes of a textbook four-step.
 
-VMEM budget per block (N = n1*n2 points, Bb batch rows):
-  2 input planes + 2 output planes + ~4 intermediates ~= 8 * Bb * N * 4 bytes;
-  Bb is chosen in ops.py so this stays under ~4 MiB.
+For N <= 128 the kernel is one dense DFT matmul.  For N = n1 * 128
+(n1 <= 32) it splits j = 128*j1 + j2, k = k1 + n1*k2:
+
+  stage 1  S_k1 = sum_j1 W_n1[j1, k1] * x[:, 128*j1 : 128*(j1+1)]
+           (complex axpys of 128-lane slabs by constant scalars, VPU)
+  stage 2  S_k1 *= T[k1, j2] = exp(sign*2πi*k1*j2/N)         (twiddles)
+  stage 3  out[:, 128*k1 : 128*(k1+1)] = S_k1 @ W_128        (MXU)
+
+so the kernel writes Y[k1 + n1*k2] at lane 128*k1 + k2; the wrapper's
+one (B, n1, 128) -> (B, 128, n1) transpose restores natural order.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core import plan as plan_lib
+from repro.kernels import backend
+
+LANES = backend.LANES
+#: largest stage-1 radix (N = MAX_N1 * 128): stage 1 costs n1^2 slab
+#: axpys, so the kernel stops where that outgrows the MXU stage
+MAX_N1 = 32
+MAX_N = MAX_N1 * LANES
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _complex_mul(ar, ai, br, bi):
-    return ar * br - ai * bi, ar * bi + ai * br
+def _snap(v: float) -> float:
+    """Exact 0/±1 for the trivial roots of unity (skips their multiply)."""
+    r = round(v)
+    return float(r) if abs(v - r) < 1e-12 else float(v)
 
 
-def _fft4step_kernel(xr_ref, xi_ref, w1_ref, w2_ref, twr_ref, twi_ref,
-                     or_ref, oi_ref, *, n1: int, n2: int):
-    """One batch block: (Bb, N) real/imag planes -> transformed planes."""
-    bb = xr_ref.shape[0]
-    n = n1 * n2
-    xr = xr_ref[...]
-    xi = xi_ref[...]
+def _cmul_const(xr, xi, c: complex):
+    a, b = _snap(c.real), _snap(c.imag)
+    if b == 0.0:
+        return (xr, xi) if a == 1.0 else (a * xr, a * xi)
+    if a == 0.0:
+        return (-b * xi, b * xr)
+    return a * xr - b * xi, a * xi + b * xr
 
-    if n2 == 1:
-        # single-matmul DFT: (Bb, 2N) @ (2N, 2N)
-        xs = jnp.concatenate([xr, xi], axis=1)
-        ys = jnp.dot(xs, w1_ref[...], preferred_element_type=jnp.float32)
-        or_ref[...] = ys[:, :n]
-        oi_ref[...] = ys[:, n:]
-        return
 
-    # stage 1: DFT over j1.  x[b, j1*n2 + j2] -> rows (b, j2), cols j1
-    xr3 = xr.reshape(bb, n1, n2).transpose(0, 2, 1).reshape(bb * n2, n1)
-    xi3 = xi.reshape(bb, n1, n2).transpose(0, 2, 1).reshape(bb * n2, n1)
-    xs = jnp.concatenate([xr3, xi3], axis=1)              # (Bb*n2, 2*n1)
-    ys = jnp.dot(xs, w1_ref[...], preferred_element_type=jnp.float32)
-    yr = ys[:, :n1].reshape(bb, n2, n1)                   # [b, j2, k1]
-    yi = ys[:, n1:].reshape(bb, n2, n1)
+def _dense_kernel(xr_ref, xi_ref, w_ref, or_ref, oi_ref):
+    """N <= 128: (Bb, 2N) @ (2N, 2N) stacked-real DFT."""
+    n = xr_ref.shape[-1]
+    xs = jnp.concatenate([xr_ref[...], xi_ref[...]], axis=1)
+    ys = jnp.dot(xs, w_ref[...], precision=_HIGHEST,
+                 preferred_element_type=jnp.float32)
+    or_ref[...] = ys[:, :n]
+    oi_ref[...] = ys[:, n:]
 
-    # stage 2: twiddles T[j2, k1] = exp(sign*2πi*k1*j2/N)
-    zr, zi = _complex_mul(yr, yi, twr_ref[...], twi_ref[...])
 
-    # stage 3: DFT over j2.  rows (b, k1), cols j2
-    zr2 = zr.transpose(0, 2, 1).reshape(bb * n1, n2)
-    zi2 = zi.transpose(0, 2, 1).reshape(bb * n1, n2)
-    zs = jnp.concatenate([zr2, zi2], axis=1)              # (Bb*n1, 2*n2)
-    ws = jnp.dot(zs, w2_ref[...], preferred_element_type=jnp.float32)
-    wr = ws[:, :n2].reshape(bb, n1, n2)                   # [b, k1, k2]
-    wi = ws[:, n2:].reshape(bb, n1, n2)
-
-    # output index k = k1 + n1*k2  ->  lay out (b, k2, k1), ravel
-    or_ref[...] = wr.transpose(0, 2, 1).reshape(bb, n)
-    oi_ref[...] = wi.transpose(0, 2, 1).reshape(bb, n)
+def _fft4step_kernel(xr_ref, xi_ref, w2_ref, twr_ref, twi_ref,
+                     or_ref, oi_ref, *, w1: np.ndarray):
+    """N = n1 * 128: stage-1 slab axpys, twiddles, stage-3 matmuls."""
+    n1 = w1.shape[0]
+    xr = [xr_ref[:, j * LANES:(j + 1) * LANES] for j in range(n1)]
+    xi = [xi_ref[:, j * LANES:(j + 1) * LANES] for j in range(n1)]
+    for k1 in range(n1):
+        sr, si = _cmul_const(xr[0], xi[0], w1[0, k1])
+        for j1 in range(1, n1):
+            tr, ti = _cmul_const(xr[j1], xi[j1], w1[j1, k1])
+            sr, si = sr + tr, si + ti
+        twr = twr_ref[k1:k1 + 1, :]
+        twi = twi_ref[k1:k1 + 1, :]
+        zr, zi = sr * twr - si * twi, sr * twi + si * twr
+        zs = jnp.concatenate([zr, zi], axis=1)               # (Bb, 256)
+        ys = jnp.dot(zs, w2_ref[...], precision=_HIGHEST,
+                     preferred_element_type=jnp.float32)
+        or_ref[:, k1 * LANES:(k1 + 1) * LANES] = ys[:, :LANES]
+        oi_ref[:, k1 * LANES:(k1 + 1) * LANES] = ys[:, LANES:]
 
 
 def fft4step_planes(xr: jax.Array, xi: jax.Array, sign: int = -1, *,
-                    block_rows: int = 0, interpret: bool = True) -> tuple:
-    """Batched FFT over float32 planes of shape (B, N); N = n1*n2 pow-2,
-    N <= MAX_TWO_LEVEL.  Returns (yr, yi).
+                    block_rows: int = 0,
+                    interpret: Optional[bool] = None) -> tuple:
+    """Batched FFT over float32 planes of shape (B, N); N a power of two,
+    N <= ``MAX_N``.  Returns (yr, yi) in natural frequency order.
     """
+    interpret = backend.resolve_interpret(interpret)
     b, n = xr.shape
-    plan = plan_lib.make_plan(n, sign, "complex64")
-    if plan.n2 > plan_lib.MAX_RADIX:
+    if not plan_lib._is_pow2(n) or n > MAX_N:
         raise ValueError(
-            f"N={n} exceeds the two-level kernel limit "
-            f"{plan_lib.MAX_TWO_LEVEL}; use the jnp six-step path")
-    n1, n2 = plan.n1, plan.n2
-
-    if block_rows <= 0:
-        # keep ~8 live (Bb, N) f32 planes under ~4 MiB of VMEM
-        block_rows = max(1, min(b, (4 * 1024 * 1024) // (8 * n * 4)))
-        while b % block_rows:
-            block_rows -= 1
-    grid = (b // block_rows,)
-
-    w1 = jnp.asarray(plan.w1_stacked)                     # (2n1, 2n1)
-    if n2 == 1:
-        w2 = jnp.zeros((2, 2), jnp.float32)               # placeholder
-        twr = jnp.zeros((1, 1), jnp.float32)
-        twi = jnp.zeros((1, 1), jnp.float32)
-    else:
-        w2 = jnp.asarray(plan.w2_stacked)                 # (2n2, 2n2)
-        twr = jnp.asarray(plan.tw.real.astype(jnp.float32))   # (n2, n1)
-        twi = jnp.asarray(plan.tw.imag.astype(jnp.float32))
-
+            f"the Pallas FFT kernel takes power-of-two N <= {MAX_N}, got "
+            f"N={n}; use local_impl='matmul' for this length")
+    row = lambda shape: pl.BlockSpec(shape, lambda i: (i, 0))
     const = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))
-    kernel = functools.partial(_fft4step_kernel, n1=n1, n2=n2)
+    out_shape = backend.f32_outputs((b, n), 2, xr, xi)
+
+    if n <= LANES:
+        if block_rows <= 0:
+            block_rows = backend.pick_block_rows(b, n, 10)
+        w = jnp.asarray(plan_lib.stacked_real(
+            plan_lib.dft_matrix(n, sign, np.complex128)))
+        return tuple(pl.pallas_call(
+            _dense_kernel,
+            grid=(b // block_rows,),
+            in_specs=[row((block_rows, n)), row((block_rows, n)),
+                      const(w.shape)],
+            out_specs=[row((block_rows, n))] * 2,
+            out_shape=out_shape,
+            interpret=interpret,
+        )(xr, xi, w))
+
+    n1 = n // LANES
+    if block_rows <= 0:
+        block_rows = backend.pick_block_rows(b, n, 12)
+    w1 = plan_lib.dft_matrix(n1, sign, np.complex128)
+    w2 = jnp.asarray(plan_lib.stacked_real(
+        plan_lib.dft_matrix(LANES, sign, np.complex128)))
+    tw = np.exp(sign * 2j * np.pi
+                * np.outer(np.arange(n1), np.arange(LANES)) / n)
+    twr = jnp.asarray(tw.real, jnp.float32)                   # (n1, 128)
+    twi = jnp.asarray(tw.imag, jnp.float32)
     yr, yi = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-            const(w1.shape), const(w2.shape),
-            const(twr.shape), const(twi.shape),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n), jnp.float32),
-            jax.ShapeDtypeStruct((b, n), jnp.float32),
-        ],
+        functools.partial(_fft4step_kernel, w1=w1),
+        grid=(b // block_rows,),
+        in_specs=[row((block_rows, n)), row((block_rows, n)),
+                  const(w2.shape), const(twr.shape), const(twi.shape)],
+        out_specs=[row((block_rows, n))] * 2,
+        out_shape=out_shape,
         interpret=interpret,
-    )(xr, xi, w1, w2, twr, twi)
-    return yr, yi
+    )(xr, xi, w2, twr, twi)
+    # kernel lane 128*k1 + k2 holds Y[k1 + n1*k2]
+    def unscramble(y):
+        return y.reshape(b, n1, LANES).swapaxes(1, 2).reshape(b, n)
+    return unscramble(yr), unscramble(yi)

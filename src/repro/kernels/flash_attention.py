@@ -10,7 +10,8 @@ output — one HBM read per operand, one write per result.
 
 Forward only (serving + projection for training-fwd); the train path keeps
 the XLA blockwise implementation whose backward is autodiff'd.
-Validated in interpret mode against ``ref.ref_flash_attention``.
+Validated in interpret mode against ``ref.ref_flash_attention``; compiled
+on TPU (``kernels/backend.py``).
 """
 
 from __future__ import annotations
@@ -20,6 +21,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import backend
 
 NEG_INF = -2.0 ** 30
 
@@ -73,7 +76,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, kv_chunk: int, causal: bool,
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     scale=None, q_block: int = 128, kv_chunk: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q (B, Sq, H, D) · k,v (B, Skv, KV, D) -> (B, Sq, H, Dv).
 
     H % KV == 0 (GQA);  Sq % q_block == 0;  Skv % kv_chunk == 0.
@@ -106,6 +109,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         out_specs=pl.BlockSpec((1, 1, q_block, dv),
                                lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
-        interpret=interpret,
+        interpret=backend.resolve_interpret(interpret),
     )(qt[:, :, :, :], kt, vt)
     return jnp.moveaxis(out, 1, 2)

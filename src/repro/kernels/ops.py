@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Complex arrays are split into float32 planes at this boundary; callers see
-normal complex64 in/out.  ``interpret=True`` on CPU (the validation mode);
-on a real TPU backend the same calls lower to Mosaic.
+normal complex64 in/out.  ``interpret=None`` resolves per backend
+(``kernels/backend.py``): Mosaic on TPU, the interpreter elsewhere.
 """
 
 from __future__ import annotations
@@ -15,15 +15,9 @@ import jax.numpy as jnp
 from repro.kernels import fft_matmul, spectral_scale
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("sign", "interpret"))
 def fft_matmul_1d(x: jax.Array, sign: int = -1, interpret: bool | None = None):
     """Batched 1-D FFT along the last axis of a complex64 array (any rank)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     shape = x.shape
     n = shape[-1]
     b = 1
@@ -39,8 +33,6 @@ def fft_matmul_1d(x: jax.Array, sign: int = -1, interpret: bool | None = None):
 def spectral_scale_op(x: jax.Array, h: jax.Array, alpha: float = 1.0,
                       interpret: bool | None = None):
     """alpha * x * h with h of shape (N,) broadcast against x (..., N)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     shape = x.shape
     n = shape[-1]
     b = 1

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import backend
+
 
 def _scale_kernel(xr_ref, xi_ref, hr_ref, hi_ref, or_ref, oi_ref, *,
                   alpha: float):
@@ -27,13 +29,13 @@ def _scale_kernel(xr_ref, xi_ref, hr_ref, hi_ref, or_ref, oi_ref, *,
 
 
 def spectral_scale_planes(xr, xi, hr, hi, alpha: float = 1.0, *,
-                          block_rows: int = 0, interpret: bool = True):
+                          block_rows: int = 0,
+                          interpret: bool | None = None):
     """(B, N) f32 planes times (N,)-broadcast filter planes."""
+    interpret = backend.resolve_interpret(interpret)
     b, n = xr.shape
     if block_rows <= 0:
-        block_rows = max(1, min(b, (4 * 1024 * 1024) // (6 * n * 4)))
-        while b % block_rows:
-            block_rows -= 1
+        block_rows = backend.pick_block_rows(b, n, 6)
     grid = (b // block_rows,)
     hr2 = hr.reshape(1, n)
     hi2 = hi.reshape(1, n)
@@ -51,23 +53,20 @@ def spectral_scale_planes(xr, xi, hr, hi, alpha: float = 1.0, *,
             pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n), jnp.float32),
-            jax.ShapeDtypeStruct((b, n), jnp.float32),
-        ],
+        out_shape=backend.f32_outputs((b, n), 2, xr, xi, hr, hi),
         interpret=interpret,
     )(xr, xi, hr2, hi2)
 
 
 def spectral_scale_planes_full(xr, xi, hr, hi, alpha: float = 1.0, *,
-                               block_rows: int = 0, interpret: bool = True):
+                               block_rows: int = 0,
+                               interpret: bool | None = None):
     """(B, N) f32 planes times same-shape (B, N) filter planes (the full
     3-D k-space filter of a spectral solver, flattened to rows)."""
+    interpret = backend.resolve_interpret(interpret)
     b, n = xr.shape
     if block_rows <= 0:
-        block_rows = max(1, min(b, (4 * 1024 * 1024) // (6 * n * 4)))
-        while b % block_rows:
-            block_rows -= 1
+        block_rows = backend.pick_block_rows(b, n, 6)
     grid = (b // block_rows,)
     kernel = functools.partial(_scale_kernel, alpha=alpha)
     blk = pl.BlockSpec((block_rows, n), lambda i: (i, 0))
@@ -76,10 +75,7 @@ def spectral_scale_planes_full(xr, xi, hr, hi, alpha: float = 1.0, *,
         grid=grid,
         in_specs=[blk, blk, blk, blk],
         out_specs=[blk, blk],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n), jnp.float32),
-            jax.ShapeDtypeStruct((b, n), jnp.float32),
-        ],
+        out_shape=backend.f32_outputs((b, n), 2, xr, xi, hr, hi),
         interpret=interpret,
     )(xr, xi, hr, hi)
 
@@ -97,9 +93,7 @@ def spectral_scale(x: jax.Array, h: jax.Array, alpha: float = 1.0, *,
     HBM round trip over the spectrum.
     """
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        use_pallas = backend.on_tpu()
     if use_pallas and x.dtype == jnp.complex64 and h.shape == x.shape:
         b, n = math.prod(x.shape[:-1]), x.shape[-1]
         xr = jnp.real(x).astype(jnp.float32).reshape(b, n)

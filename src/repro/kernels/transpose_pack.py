@@ -38,16 +38,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is not None:
-        return interpret
-    from repro.kernels.ops import _on_tpu
-    return not _on_tpu()
+from repro.kernels import backend
 
 
 def _rotate_kernel(shift_ref, xr_ref, xi_ref, or_ref, oi_ref):
-    """Pure block copy: the rotation lives entirely in the index maps."""
+    """Pure tile copy: the rotation lives entirely in the index maps."""
     del shift_ref
     or_ref[...] = xr_ref[...]
     oi_ref[...] = xi_ref[...]
@@ -61,32 +56,40 @@ def rotate_block_rows_planes(xr: jax.Array, xi: jax.Array, shift: jax.Array,
     (i + shift) % n_blocks).  ``shift`` is a shape-(1,) int32 array and
     may be traced (the rank index inside ``shard_map``).
 
-    The shift rides as a *scalar-prefetch* operand consumed by the input
-    index map — grid step i simply fetches block ``(i + shift) %
-    n_blocks`` — so the kernel body is a pure tiled copy with no
-    data-dependent indexing (the Mosaic-friendly form: the scalar lands
-    in SMEM and only block scheduling depends on it)."""
-    interpret = _resolve_interpret(interpret)
+    The planes are viewed as (n_blocks, R / n_blocks, M) and each
+    row-block is tiled over its rows and columns, so one window stays a
+    few MiB at any block size (a whole row-block of a 1024^3 pencil is
+    256 MiB, far past VMEM).  The shift rides as a *scalar-prefetch*
+    operand consumed by the input index map — grid step (i, j, k)
+    fetches tile (j, k) of block ``(i + shift) % n_blocks`` — so the
+    kernel body is a pure copy with no data-dependent indexing."""
+    interpret = backend.resolve_interpret(interpret)
     r, m = xr.shape
     if r % n_blocks:
         raise ValueError(f"{r} rows not divisible into {n_blocks} blocks")
     block_rows = r // n_blocks
+    # four windows: two input planes, two output planes
+    tile_rows = backend.pick_block_rows(block_rows,
+                                        min(m, 16 * backend.LANES), 4)
+    tile_cols = backend.pick_block_cols(m, tile_rows, 4)
+    tile = (pl.Squeezed(), tile_rows, tile_cols)
     from jax.experimental.pallas import tpu as pltpu
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_blocks,),
+        grid=(n_blocks, block_rows // tile_rows, m // tile_cols),
         in_specs=[pl.BlockSpec(
-            (block_rows, m),
-            lambda i, s_ref: ((i + s_ref[0]) % n_blocks, 0))] * 2,
-        out_specs=[pl.BlockSpec((block_rows, m),
-                                lambda i, s_ref: (i, 0))] * 2,
+            tile, lambda i, j, k, s_ref: ((i + s_ref[0]) % n_blocks, j, k))
+        ] * 2,
+        out_specs=[pl.BlockSpec(tile, lambda i, j, k, s_ref: (i, j, k))] * 2,
     )
-    return pl.pallas_call(
+    shape3 = (n_blocks, block_rows, m)
+    yr, yi = pl.pallas_call(
         _rotate_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((r, m), jnp.float32)] * 2,
+        out_shape=backend.f32_outputs(shape3, 2, xr, xi),
         interpret=interpret,
-    )(shift, xr, xi)
+    )(shift, xr.reshape(shape3), xi.reshape(shape3))
+    return yr.reshape(r, m), yi.reshape(r, m)
 
 
 def rotate_blocks(x: jax.Array, axis: int, shift, n_blocks: int, *,
@@ -109,8 +112,7 @@ def rotate_blocks(x: jax.Array, axis: int, shift, n_blocks: int, *,
             f"axis {axis} extent {extent} not divisible by {n_blocks}")
     block = extent // n_blocks
     if use_pallas is None:
-        from repro.kernels.ops import _on_tpu
-        use_pallas = _on_tpu()
+        use_pallas = backend.on_tpu()
     if not use_pallas or x.dtype != jnp.complex64:
         # NOT jnp.roll: a *traced* shift makes roll lower to a gather
         # over the axis (index arithmetic per element).  Doubling the
@@ -151,8 +153,7 @@ def unpack_pieces(pieces: list, axis: int, shift, *,
     if p == 1:
         return pieces[0]
     if use_pallas is None:
-        from repro.kernels.ops import _on_tpu
-        use_pallas = _on_tpu()
+        use_pallas = backend.on_tpu()
     if use_pallas and pieces[0].dtype == jnp.complex64:
         return rotate_blocks(jnp.concatenate(pieces, axis=axis), axis,
                              shift, p, use_pallas=use_pallas)
@@ -186,8 +187,7 @@ def pack_pieces(blk: jax.Array, axis: int, idx, n_blocks: int, *,
             f"axis {axis} extent {extent} not divisible by {n_blocks}")
     block = extent // n_blocks
     if use_pallas is None:
-        from repro.kernels.ops import _on_tpu
-        use_pallas = _on_tpu()
+        use_pallas = backend.on_tpu()
     if use_pallas and blk.dtype == jnp.complex64:
         packed = rotate_blocks(blk, axis, idx, n_blocks,
                                use_pallas=use_pallas)

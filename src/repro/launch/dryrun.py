@@ -166,7 +166,7 @@ def lower_lm_cell(arch: str, shape_name: str, multi_pod: bool,
         t_compile = time.time() - t0
 
     terms, coll, mem = rl.terms_from_compiled(
-        compiled, n_dev, model_flops_for(cfg, shape))
+        compiled, n_dev, rl.TARGET_DEVICE_KIND, model_flops_for(cfg, shape))
     return {
         "status": "ok", "arch": arch, "shape": shape_name,
         "mesh": "2x16x16" if multi_pod else "16x16",
@@ -208,8 +208,8 @@ def lower_fft_cell(grid_name: str, multi_pod: bool,
     lowered = plan.lower_forward()
     compiled = lowered.compile()
     t_compile = time.time() - t0
-    terms, coll, mem = rl.terms_from_compiled(compiled, n_dev,
-                                              plan.flops_model())
+    terms, coll, mem = rl.terms_from_compiled(
+        compiled, n_dev, rl.TARGET_DEVICE_KIND, plan.flops_model())
     return {
         "status": "ok", "arch": f"croft-{decomposition}",
         "shape": grid_name,

@@ -1,8 +1,13 @@
-"""Production mesh construction.
+"""Mesh construction: the one place this repo builds a ``jax.sharding.Mesh``.
 
-A FUNCTION (not a module-level constant) so importing this module never
+Functions (not module-level constants) so importing this module never
 touches jax device state; ``dryrun.py`` sets the 512-placeholder-device
-XLA flag before calling it.
+XLA flag before calling them.
+
+Every mesh here has ``Auto`` axes.  ``jax.make_mesh`` defaults to
+``Explicit`` axes, under which sharding becomes part of each array's
+type and the transforms' ``jax.grad``/``jit`` paths reject the
+replicated scalars they carry.
 """
 
 from __future__ import annotations
@@ -12,12 +17,18 @@ import math
 import jax
 
 
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axis types (see module doc)."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(
-        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 0):
@@ -28,8 +39,7 @@ def make_local_mesh(model: int = 0):
         for cand in (2, 4, 8, 16):
             if n % cand == 0 and cand <= n:
                 model = cand
-    return jax.make_mesh((n // model, model), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def fft_mesh_axes(mesh) -> tuple:

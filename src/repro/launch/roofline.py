@@ -2,9 +2,13 @@
 
 Three terms per (arch x shape x mesh), in seconds (DESIGN.md §8):
 
-  compute    = HLO_FLOPs_per_device / peak_FLOPs          (197 TF/s bf16)
-  memory     = HLO_bytes_per_device / HBM_bw              (819 GB/s)
-  collective = collective_bytes_per_device / link_bw      (~50 GB/s/link)
+  compute    = HLO_FLOPs_per_device / peak_FLOPs
+  memory     = HLO_bytes_per_device / HBM_bw
+  collective = collective_bytes_per_device / ICI_bw
+
+with the peaks of the device the program targets, looked up by its
+``device_kind`` in :data:`DEVICE_PEAKS`.  A kind not in the table is an
+error, never a default.
 
 ``cost_analysis()`` on the compiled executable is per-partition (verified
 empirically in tests/test_roofline.py), matching the formulas'
@@ -20,10 +24,36 @@ import dataclasses
 import re
 from typing import Optional
 
-# TPU v5e-class constants (per chip) — from the assignment brief
-PEAK_FLOPS = 197e12        # bf16
-HBM_BW = 819e9             # bytes/s
-LINK_BW = 50e9             # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    flops: float            # bf16 FLOP/s
+    hbm_bw: float           # HBM bytes/s
+    ici_bw: float           # chip-to-chip interconnect bytes/s, all links
+    source: str
+
+
+#: keyed by ``jax.Device.device_kind``
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               '16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip'),
+}
+
+#: the chip the dry runs compile for (``launch/dryrun.py``)
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> DevicePeaks:
+    """The published peaks of ``device_kind``; raises on an unknown kind."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -78,19 +108,23 @@ class RooflineTerms:
     bytes_per_device: float
     collective_bytes_per_device: float
     n_devices: int
+    device_kind: str
     model_flops: float = 0.0       # 6*N*D (train) / 2*N_active*tokens (serve)
+
+    def __post_init__(self):
+        self.peaks = peaks_for(self.device_kind)
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes_per_device / LINK_BW
+        return self.collective_bytes_per_device / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -116,7 +150,7 @@ class RooflineTerms:
         t = self.step_time_s
         if not t:
             return 0.0
-        return self.model_flops / (self.n_devices * PEAK_FLOPS * t)
+        return self.model_flops / (self.n_devices * self.peaks.flops * t)
 
     def to_dict(self) -> dict:
         return {
@@ -124,6 +158,7 @@ class RooflineTerms:
             "bytes_per_device": self.bytes_per_device,
             "collective_bytes_per_device": self.collective_bytes_per_device,
             "n_devices": self.n_devices,
+            "device_kind": self.device_kind,
             "model_flops": self.model_flops,
             "compute_s": self.compute_s,
             "memory_s": self.memory_s,
@@ -135,19 +170,19 @@ class RooflineTerms:
         }
 
 
-def terms_from_compiled(compiled, n_devices: int,
+def terms_from_compiled(compiled, n_devices: int, device_kind: str,
                         model_flops: float = 0.0) -> tuple:
-    """(RooflineTerms, collective_stats dict, memory dict).
+    """(RooflineTerms, collective_stats dict, memory dict) against the
+    peaks of ``device_kind``.
 
     Uses the trip-count-aware HLO analyzer (hlo_cost.py): XLA's own
     cost_analysis counts scan bodies once, undercounting layer-scanned
     models by O(depth).  The raw cost_analysis numbers ride along in the
     memory dict for cross-checking.
     """
-    from repro import compat
     from repro.launch import hlo_cost
 
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     hlo = compiled.as_text()
     cost = hlo_cost.analyze(hlo)
     ma = compiled.memory_analysis()
@@ -162,5 +197,6 @@ def terms_from_compiled(compiled, n_devices: int,
     terms = RooflineTerms(
         flops_per_device=cost.flops, bytes_per_device=cost.bytes,
         collective_bytes_per_device=cost.collective_bytes,
-        n_devices=n_devices, model_flops=model_flops)
+        n_devices=n_devices, device_kind=device_kind,
+        model_flops=model_flops)
     return terms, cost.collectives, mem

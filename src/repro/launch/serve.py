@@ -23,6 +23,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
+
 
 # -- transform-service mode (default) ---------------------------------------
 
@@ -35,7 +38,7 @@ def _mesh_for_transforms():
     py = int(math.sqrt(n))
     while n % py:
         py -= 1
-    return jax.make_mesh((py, n // py), ("y", "z"))
+    return make_mesh((py, n // py), ("y", "z"))
 
 
 def transforms_main(args) -> None:
@@ -185,6 +188,7 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--kv-block", type=int, default=512)
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.arch:
         lm_main(args)
     else:

@@ -30,7 +30,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from repro.compat import pcast, shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.distributed import _stage, FFTOptions
@@ -147,7 +147,7 @@ def moe_fwd_sharded(params, x, m: MoESpec, *, mesh: Mesh, dp, cp_axis,
             bb, ss, _ = x_loc.shape
             xt = x_loc.reshape(bb * ss, d)
             buf, meta = _local_dispatch(xt, router_w, m, cap)
-            buf = pcast(buf, (tp_axis,), to="varying")
+            buf = jax.lax.pcast(buf, (tp_axis,), to="varying")
             y = _experts_swiglu(buf, w_gate, w_up, w_down)
             # combine is linear in y: psum AFTER combining so the wire
             # carries (T, D) tokens, not the k*capacity-padded buffer

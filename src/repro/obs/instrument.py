@@ -38,7 +38,7 @@ from typing import Optional
 import jax
 from jax.sharding import NamedSharding
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import schedule as schedule_lib
 from repro.launch import hlo_cost
 from repro.obs import tracer as tracer_lib

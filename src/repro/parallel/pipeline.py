@@ -27,7 +27,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from repro.compat import pcast, shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -57,7 +57,7 @@ def pipeline_apply(layer_fn: Callable, stacked_params, x, *, mesh: Mesh,
     def body(params_loc, x_all):
         # params_loc: (L/P, ...) this stage's layers; x_all replicated
         stage = jax.lax.axis_index(stage_axis)
-        x_all = pcast(x_all, (stage_axis,), to="varying")
+        x_all = jax.lax.pcast(x_all, (stage_axis,), to="varying")
         micro = x_all.reshape((n_micro, mb) + x_all.shape[1:])
 
         def run_stage(h):
@@ -68,7 +68,7 @@ def pipeline_apply(layer_fn: Callable, stacked_params, x, *, mesh: Mesh,
 
         n_steps = n_micro + n_stages - 1
         outputs = jnp.zeros_like(micro)
-        buf = pcast(
+        buf = jax.lax.pcast(
             jnp.zeros((mb,) + x_all.shape[1:], x_all.dtype),
             (stage_axis,), to="varying")
         fwd_perm = [(i, i + 1) for i in range(n_stages - 1)]

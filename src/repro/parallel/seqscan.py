@@ -21,7 +21,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from repro.compat import axis_size, pcast, shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models import recurrent as rec
@@ -30,7 +30,7 @@ from repro.models import recurrent as rec
 def _prefix_scan(pairs_combine: Callable, identity, local, axis_name: str):
     """Hillis-Steele inclusive scan over the mesh axis, then shift by one
     rank to make it exclusive (rank 0 receives ``identity``)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     acc = local
     d = 1
@@ -60,7 +60,7 @@ def cp_vector_recurrence(log_a, b, h0, *, mesh: Mesh, cp_axis: str,
     def body(la_loc, b_loc, h0_loc):
         # replicated operands must be marked varying before mixing with
         # shard-local values inside scans (shard_map vma typing)
-        h0_loc = pcast(h0_loc, (cp_axis,), to="varying")
+        h0_loc = jax.lax.pcast(h0_loc, (cp_axis,), to="varying")
         # local pass from zero state
         h_loc, h_last = rec.vector_recurrence(
             la_loc, b_loc, jnp.zeros_like(h0_loc), chunk)
@@ -79,7 +79,7 @@ def cp_vector_recurrence(log_a, b, h0, *, mesh: Mesh, cp_axis: str,
         h = h_loc + jnp.exp(a_cum) * h_in[:, None, :]
         # global final state lives on the last rank; broadcast via psum
         idx = jax.lax.axis_index(cp_axis)
-        n = axis_size(cp_axis)
+        n = jax.lax.axis_size(cp_axis)
         h_out_last = jax.lax.psum(
             jnp.where(idx == n - 1, h[:, -1], jnp.zeros_like(h[:, -1])),
             cp_axis)
@@ -101,8 +101,8 @@ def cp_matrix_recurrence(log_w, k, v, r, u, s0, *, mesh: Mesh, cp_axis: str,
     spec_u = P(None, None)
 
     def body(lw_loc, k_loc, v_loc, r_loc, u_loc, s0_loc):
-        s0_loc = pcast(s0_loc, (cp_axis,), to="varying")
-        u_loc = pcast(u_loc, (cp_axis,), to="varying")
+        s0_loc = jax.lax.pcast(s0_loc, (cp_axis,), to="varying")
+        u_loc = jax.lax.pcast(u_loc, (cp_axis,), to="varying")
         o_loc, s_loc = rec.matrix_recurrence(
             lw_loc, k_loc, v_loc, r_loc, u_loc,
             jnp.zeros_like(s0_loc), chunk)
@@ -124,7 +124,7 @@ def cp_matrix_recurrence(log_w, k, v, r, u, s0, *, mesh: Mesh, cp_axis: str,
         d_last = dcum[:, -1]
         s_out = jnp.exp(d_last)[..., None] * s_in + s_loc
         idx = jax.lax.axis_index(cp_axis)
-        n = axis_size(cp_axis)
+        n = jax.lax.axis_size(cp_axis)
         s_out = jax.lax.psum(
             jnp.where(idx == n - 1, s_out, jnp.zeros_like(s_out)), cp_axis)
         return o, s_out

@@ -58,7 +58,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import schedule as schedule_lib
 from repro.core.decomposition import Decomposition, _mesh_axis_sizes
 from repro.core.distributed import FFTOptions, _norm_scale

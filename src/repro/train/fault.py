@@ -23,6 +23,8 @@ from typing import Callable, Optional
 
 import jax
 
+from repro.launch.mesh import make_mesh
+
 
 class PreemptionHandler:
     """SIGTERM/SIGINT -> graceful checkpoint-and-exit flag."""
@@ -110,5 +112,4 @@ def elastic_mesh(axis_names=("data", "model"), prefer_model: int = 16):
     n = len(jax.devices())
     model = math.gcd(n, prefer_model)
     data = n // model
-    return jax.make_mesh((data, model), axis_names,
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    return make_mesh((data, model), axis_names)

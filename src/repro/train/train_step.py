@@ -106,7 +106,7 @@ def init_train_state(key, cfg: ModelConfig, opt_cfg: opt_lib.OptConfig,
                              is_leaf=lambda x: isinstance(x, P))
     init_fn = jax.jit(lambda k: model_lib.init_params(k, cfg),
                       out_shardings=shardings)
-    with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+    with jax.set_mesh(mesh):
         params = init_fn(key)
     opt_state = {
         "m": jax.tree.map(lambda p, s: jax.device_put(

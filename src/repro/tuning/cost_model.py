@@ -57,8 +57,16 @@ import jax.numpy as jnp
 from repro.core.decomposition import Decomposition
 from repro.core.distributed import FFTOptions, build_schedule
 from repro.core.schedule import Schedule
-from repro.launch.roofline import HBM_BW, LINK_BW, PEAK_FLOPS
+from repro.launch.roofline import DEVICE_PEAKS, TARGET_DEVICE_KIND
 from repro.tuning.candidates import Candidate
+
+# Ranking priors.  The planner ranks candidates against one chip's
+# peaks whatever it runs on: v5e's published FLOP/s and HBM bandwidth,
+# and a guessed per-link collective bandwidth.  Chip traces calibrate
+# them (ROADMAP queue 1, item 6); nothing reports a share of these.
+PRIOR_PEAK_FLOPS = DEVICE_PEAKS[TARGET_DEVICE_KIND].flops
+PRIOR_HBM_BW = DEVICE_PEAKS[TARGET_DEVICE_KIND].hbm_bw
+PRIOR_LINK_BW = 50e9       # bytes/s per ICI link, a guess
 
 # fraction of peak FLOPs each local 1-D implementation is expected to
 # sustain — coarse priors that mode="measure" refines empirically
@@ -112,7 +120,7 @@ def collective_constants() -> tuple:
     degenerate lstsq on noisy walls can go negative — the hardcoded
     floor is better than a nonsense model).
     """
-    alpha, beta = COLLECTIVE_LATENCY_S, 1.0 / LINK_BW
+    alpha, beta = COLLECTIVE_LATENCY_S, 1.0 / PRIOR_LINK_BW
     file_vals = _calibration_from_file()
     if file_vals is not None:
         fa, fb = file_vals
@@ -239,12 +247,12 @@ def _schedule_cost(shape: Sequence[int], cand: Candidate, sched: Schedule,
         flops += f
         eff = IMPL_EFFICIENCY.get(opts.stage_impl(impl_stage),
                                   _DEFAULT_EFFICIENCY)
-        compute_s += f / (PEAK_FLOPS * eff)
+        compute_s += f / (PRIOR_PEAK_FLOPS * eff)
     flops *= batch
     compute_s *= batch
 
     local_bytes = sched.layout_in.bytes(shape, axis_sizes, itemsize) * batch
-    memory_s = LOCAL_PASSES * local_bytes / HBM_BW
+    memory_s = LOCAL_PASSES * local_bytes / PRIOR_HBM_BW
 
     events = sched.comm_events(shape, axis_sizes, itemsize)
     coll_bytes = float(sum(ev["bytes"] for ev in events)) * batch
@@ -275,14 +283,15 @@ def _schedule_cost(shape: Sequence[int], cand: Candidate, sched: Schedule,
         n_coll += k_eff * ops
         ev_bytes = ev["bytes"] * batch
         if impl == "ring":
-            transpose_overhead_s += 2 * ev_bytes / HBM_BW
+            transpose_overhead_s += 2 * ev_bytes / PRIOR_HBM_BW
         elif impl == "pairwise":
-            transpose_overhead_s += (ev["comm_size"] - 1) * ev_bytes / HBM_BW
+            transpose_overhead_s += ((ev["comm_size"] - 1) * ev_bytes
+                                     / PRIOR_HBM_BW)
     latency_s = n_coll * alpha
 
     replan_s = 0.0
     if not opts.plan_cache:
-        replan_s = REPLAN_PASSES * local_bytes / HBM_BW
+        replan_s = REPLAN_PASSES * local_bytes / PRIOR_HBM_BW
 
     busy = compute_s + memory_s
     if impl == "ring":
@@ -339,12 +348,12 @@ def _searched_schedule_cost(shape: Sequence[int], cand, sched: Schedule,
         flops += f
         eff = IMPL_EFFICIENCY.get(opts.stage_impl(impl_stage),
                                   _DEFAULT_EFFICIENCY)
-        compute_s += f / (PEAK_FLOPS * eff)
+        compute_s += f / (PRIOR_PEAK_FLOPS * eff)
     flops *= batch
     compute_s *= batch
 
     local_bytes = sched.layout_in.bytes(shape, axis_sizes, itemsize) * batch
-    memory_s = LOCAL_PASSES * local_bytes / HBM_BW
+    memory_s = LOCAL_PASSES * local_bytes / PRIOR_HBM_BW
 
     events = sched.comm_events(shape, axis_sizes, itemsize)
     coll_bytes = float(sum(ev["bytes"] for ev in events)) * batch
@@ -365,14 +374,15 @@ def _searched_schedule_cost(shape: Sequence[int], cand, sched: Schedule,
         n_coll += k_eff * ops
         ev_bytes = ev["bytes"] * batch
         if impl == "ring":
-            transpose_overhead_s += 2 * ev_bytes / HBM_BW
+            transpose_overhead_s += 2 * ev_bytes / PRIOR_HBM_BW
         elif impl == "pairwise":
-            transpose_overhead_s += (ev["comm_size"] - 1) * ev_bytes / HBM_BW
+            transpose_overhead_s += ((ev["comm_size"] - 1) * ev_bytes
+                                     / PRIOR_HBM_BW)
     latency_s = n_coll * alpha
 
     replan_s = 0.0
     if not opts.plan_cache:
-        replan_s = REPLAN_PASSES * local_bytes / HBM_BW
+        replan_s = REPLAN_PASSES * local_bytes / PRIOR_HBM_BW
 
     # the per-stage combine: each stage hides the smaller of its own two
     # legs when it pipelines (ring overhead is already inside the rows'
@@ -425,7 +435,7 @@ def per_stage_costs(shape: Sequence[int], cand: Candidate,
 
     One row per schedule stage (plus one per out-of-body reshard), using
     the same conventions as :func:`analytic_cost`: FFT flops at the
-    layout-reported block size over ``PEAK_FLOPS * IMPL_EFFICIENCY``,
+    layout-reported block size over ``PRIOR_PEAK_FLOPS * IMPL_EFFICIENCY``,
     the ``LOCAL_PASSES`` HBM budget spread evenly across the stages that
     do local work, ring pack/unpack passes charged to the compute leg,
     and the §5.1 overlap rule (0.9 of the smaller leg hides under the
@@ -464,11 +474,11 @@ def _stage_rows(shape, cand, sched, axis_sizes, dtype, batch,
             f = 5.0 * math.prod(loc) * math.log2(loc[st.fft_axis])
             eff = IMPL_EFFICIENCY.get(opts.stage_impl(st.impl_stage),
                                       _DEFAULT_EFFICIENCY)
-            compute_s += f / (PEAK_FLOPS * eff)
+            compute_s += f / (PRIOR_PEAK_FLOPS * eff)
         if st.fft_axis is not None or st.prologue or st.epilogue:
             compute_s += (mem_passes
                           * pts.entry.bytes(shape, axis_sizes, itemsize)
-                          / HBM_BW)
+                          / PRIOR_HBM_BW)
         compute_s *= batch
 
         collective_s = 0.0
@@ -481,10 +491,10 @@ def _stage_rows(shape, cand, sched, axis_sizes, dtype, batch,
             k_eff = next(eff_ks)
             overlaps = impl != "pairwise" and (k_eff >= 2 or impl == "ring")
             if impl == "ring":
-                compute_s += 2 * ev_bytes / HBM_BW
+                compute_s += 2 * ev_bytes / PRIOR_HBM_BW
             elif impl == "pairwise":
                 csize = math.prod(axis_sizes[n] for n in _flat(st.comm_axis))
-                compute_s += (csize - 1) * ev_bytes / HBM_BW
+                compute_s += (csize - 1) * ev_bytes / PRIOR_HBM_BW
 
         hidden = 0.9 * min(compute_s, collective_s) if overlaps else 0.0
         rows.append({

@@ -2,7 +2,9 @@
 
 NOTE: no XLA_FLAGS here — unit tests and benches must see the real (single)
 device.  Multi-device tests spawn subprocesses with
-``--xla_force_host_platform_device_count`` set (see ``run_multidevice``).
+``--xla_force_host_platform_device_count`` set (see ``run_multidevice``);
+those children are CPU-mesh rehearsals pinned to ``JAX_PLATFORMS=cpu``, so
+they never compete with a parent for an accelerator.
 """
 
 import os
@@ -20,6 +22,7 @@ def run_multidevice(code: str, n_devices: int = 8, timeout: int = 480) -> str:
     """Run a python snippet in a subprocess with N virtual CPU devices.
     Returns stdout; raises on nonzero exit."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", code], env=env,

@@ -51,8 +51,8 @@ def test_kernel_explicit_block_rows(rng):
 
 
 def test_kernel_too_large_raises():
-    import repro.core.plan as plan_lib
-    n = plan_lib.MAX_TWO_LEVEL * 2
+    from repro.kernels.fft_matmul import MAX_N
+    n = MAX_N * 2
     xr = jnp.zeros((1, n), jnp.float32)
     with pytest.raises(ValueError):
         fft4step_planes(xr, xr)

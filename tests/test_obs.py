@@ -326,8 +326,9 @@ from repro import obs
 from repro.core import Croft3D, Decomposition, FFTOptions
 from repro.obs import instrument, report as report_lib
 from repro.tuning.measure import _random_input
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("y", "z"))
+mesh = make_mesh((2, 4), ("y", "z"))
 N = 16
 plans = {
     "alltoall-k2": Croft3D((N, N, N), mesh, Decomposition("pencil", ("y", "z")),

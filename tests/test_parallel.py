@@ -101,7 +101,7 @@ def test_compressed_psum_cross_pod():
     run_multidevice("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.parallel.compression import compressed_psum
 mesh = jax.make_mesh((4,), ("pod",), axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.RandomState(0)

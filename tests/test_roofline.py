@@ -6,8 +6,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.launch import hlo_cost
-from repro.launch.roofline import (HBM_BW, LINK_BW, PEAK_FLOPS,
-                                   RooflineTerms, collective_stats)
+from repro.launch.roofline import (RooflineTerms, collective_stats,
+                                   peaks_for)
 
 
 def test_scan_trip_count_multiplied():
@@ -22,8 +22,7 @@ def test_scan_trip_count_multiplied():
         return y
 
     compiled = scanned.lower(w).compile()
-    from repro import compat
-    xla_flops = compat.cost_analysis(compiled)["flops"]
+    xla_flops = compiled.cost_analysis()["flops"]
     ours = hlo_cost.analyze(compiled.as_text())
     expect = 10 * 2 * 256 ** 3
     assert abs(ours.flops - expect) / expect < 0.02
@@ -60,8 +59,7 @@ def test_unrolled_matches_xla():
 
     compiled = unrolled.lower(w).compile()
     ours = hlo_cost.analyze(compiled.as_text())
-    from repro import compat
-    assert abs(ours.flops - compat.cost_analysis(compiled)["flops"]) \
+    assert abs(ours.flops - compiled.cost_analysis()["flops"]) \
         / ours.flops < 0.02
 
 
@@ -79,9 +77,12 @@ def test_dot_flops_with_batch_dims():
 
 
 def test_roofline_terms_math():
-    t = RooflineTerms(flops_per_device=197e12, bytes_per_device=819e9,
-                      collective_bytes_per_device=50e9, n_devices=4,
-                      model_flops=4 * 197e12 * 0.5)
+    pk = peaks_for("TPU v5 lite")
+    assert (pk.flops, pk.hbm_bw) == (197e12, 819e9)
+    t = RooflineTerms(flops_per_device=pk.flops, bytes_per_device=pk.hbm_bw,
+                      collective_bytes_per_device=pk.ici_bw, n_devices=4,
+                      device_kind="TPU v5 lite",
+                      model_flops=4 * pk.flops * 0.5)
     assert abs(t.compute_s - 1.0) < 1e-9
     assert abs(t.memory_s - 1.0) < 1e-9
     assert abs(t.collective_s - 1.0) < 1e-9
@@ -109,13 +110,12 @@ def test_cost_analysis_is_per_partition():
     run_multidevice("""
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import compat
 mesh = jax.make_mesh((8,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
 sh = NamedSharding(mesh, P("x", None))
 @jax.jit
 def f(a):
     return a @ a.T
-ca = compat.cost_analysis(f.lower(jax.ShapeDtypeStruct((512, 512), jnp.float32, sharding=sh)).compile())
+ca = f.lower(jax.ShapeDtypeStruct((512, 512), jnp.float32, sharding=sh)).compile().cost_analysis()
 full = 2 * 512**3
 # per-partition: roughly full/8 (plus collective overhead terms)
 assert ca["flops"] < full / 4, ca["flops"]

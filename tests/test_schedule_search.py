@@ -357,8 +357,9 @@ def test_searched_schedules_execute_and_invert():
 import dataclasses, numpy as np, jax, jax.numpy as jnp
 from repro.core import Croft3D, Decomposition, FFTOptions
 from repro.tuning.candidates import Candidate, ScheduleCandidate
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 shape = (16, 16, 8)
 rng = np.random.default_rng(0)
 x = (rng.standard_normal(shape)
@@ -399,8 +400,9 @@ def test_searched_schedule_differentiates():
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import Croft3D
 from repro.tuning.candidates import ScheduleCandidate
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 shape = (16, 16, 8)
 plan = Croft3D(shape, mesh=mesh,
                schedule=ScheduleCandidate.from_plan_key({MIXED_KEY!r}))
@@ -431,14 +433,15 @@ def test_ring_round_callback_and_instrument_rounds():
     run_multidevice("""
 import numpy as np, jax, jax.numpy as jnp
 from repro import obs
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import Croft3D, Decomposition, FFTOptions
 from repro.core import schedule as schedule_lib
 from repro.core.distributed import build_schedule
 from repro.obs import instrument
 from repro.tuning.measure import _random_input
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 dec = Decomposition("pencil", ("data", "model"))
 opts = FFTOptions(overlap_k=1, transpose_impl="ring",
                   output_layout="spectral")
@@ -491,8 +494,9 @@ import os, tempfile
 import jax, jax.numpy as jnp
 from repro.core import Croft3D
 from repro.tuning.planner import tune
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 shape = (16, 16, 8)
 wpath = os.path.join(tempfile.mkdtemp(), "w.json")
 r = tune(shape, mesh, mode="measure", search="schedule", top_k=2,

@@ -368,8 +368,9 @@ _MULTIDEVICE_CODE = """
 import json, os, tempfile, time
 import numpy as np, jax
 from repro.serve import TransformService, PlanCache
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("y", "z"))
+mesh = make_mesh((2, 4), ("y", "z"))
 wisdom = os.path.join(tempfile.mkdtemp(), "w.json")
 cache = PlanCache(mesh, wisdom_path=wisdom, max_plans=2, measure_after=3,
                   upgrade_async=False, tune_kw=dict(top_k=2, measure_iters=1))

@@ -383,7 +383,7 @@ def test_collective_constants_calibration_precedence(tmp_path, monkeypatch):
     monkeypatch.delenv(cost_model.CALIBRATION_ENV, raising=False)
     try:
         assert cost_model.collective_constants() == (
-            cost_model.COLLECTIVE_LATENCY_S, 1.0 / cost_model.LINK_BW)
+            cost_model.COLLECTIVE_LATENCY_S, 1.0 / cost_model.PRIOR_LINK_BW)
         path = str(tmp_path / "calibration.json")
         with open(path, "w") as f:
             json.dump({"collective_alpha_s": 3e-6,
