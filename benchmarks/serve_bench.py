@@ -48,6 +48,7 @@ import numpy as np, jax, jax.numpy as jnp
 
 from repro.core import Croft3D
 from repro.launch import hlo_cost
+from repro.launch.mesh import make_mesh
 from repro.serve import PlanCache, TransformService
 
 SMOKE = {smoke}
@@ -55,7 +56,6 @@ TRACE = {trace!r}
 tracer = None
 if TRACE:
     from repro import obs
-    from repro.launch.mesh import make_mesh
     tracer = obs.enable()
 mesh = make_mesh((2, 4), ("y", "z"))
 wisdom = os.path.join(tempfile.mkdtemp(), "serve_wisdom.json")

@@ -16,8 +16,9 @@ user calls:
                  kernel
   service        a meshless ``TransformService`` answering a dozen mixed
                  c2c / r2c / filtered requests at 128^3 and 256^3
-  kernels        every Pallas kernel at a cell's line length against its
-                 jnp or numpy reference, compiled to Mosaic
+  kernels        every transform-stack Pallas kernel at a cell's line
+                 length against its jnp or numpy reference, compiled to
+                 Mosaic
 
 ``--chips 4`` runs only the distributed pencil transform on a 2x2
 ("y", "z") mesh: croft-1024 with the default ``FFTOptions`` (alltoall,
@@ -61,6 +62,7 @@ import numpy as np  # noqa: E402
 
 from repro.core import (Croft3D, Decomposition, FFTOptions,  # noqa: E402
                         poisson_solve)
+from repro.kernels.backend import on_tpu  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.obs import metrics as metrics_lib  # noqa: E402
@@ -273,10 +275,9 @@ def phase_service(sizes=(128, 256), *, seed: int = 0, rounds: int = 2
         compile_s_per_round=compile_s, counters=counters))
 
 
-def phase_kernels(*, n: int = 1024, rows: int = 4096,
-                  expect_mosaic: bool = False) -> dict:
-    """Each Pallas kernel at line length ``n`` against its jnp or numpy
-    reference; with ``expect_mosaic`` each must lower to a Mosaic kernel
+def phase_kernels(*, n: int = 1024, rows: int = 4096) -> dict:
+    """Each transform-stack Pallas kernel at line length ``n`` against its
+    jnp or numpy reference; on a TPU each must lower to a Mosaic kernel
     (``tpu_custom_call``), i.e. none runs interpreted."""
     from repro.kernels import ops, spectral_scale, transpose_pack
     from repro.real import packing
@@ -286,7 +287,7 @@ def phase_kernels(*, n: int = 1024, rows: int = 4096,
     def run(name, fn, args, ref, t):
         jitted = jax.jit(fn)
         kernels[name] = mosaic_kernels(jitted.lower(*args))
-        if expect_mosaic and not kernels[name]:
+        if on_tpu() and not kernels[name]:
             raise RuntimeError(f"kernel {name} did not compile to Mosaic")
         errors[name] = rel_err(jitted(*args), ref)
         tol[name] = t
@@ -348,8 +349,7 @@ def plane_waves(n: int) -> list:
 
 
 def phase_pencil_planewave(phase: str, n: int, mesh, opts: FFTOptions, *,
-                           iters: int = 3, expect_mosaic: bool = False
-                           ) -> dict:
+                           iters: int = 3) -> dict:
     """croft-n c2c on the pencil mesh against plane waves: the forward
     spectrum is n^3 * a at each wave's k and zero elsewhere.  Input and
     checks stay on the devices (croft-1024 is 8 GiB)."""
@@ -382,7 +382,7 @@ def phase_pencil_planewave(phase: str, n: int, mesh, opts: FFTOptions, *,
         return jnp.max(jnp.abs(xb - x)) / jnp.max(jnp.abs(x))
 
     kernels = mosaic_kernels(plan.lower_forward())
-    if expect_mosaic and opts.transpose_impl == "ring" and not kernels:
+    if on_tpu() and opts.transpose_impl == "ring" and not kernels:
         raise RuntimeError("the ring transpose did not compile its Mosaic "
                            "block-rotation kernel")
     x = make_input()
@@ -430,7 +430,7 @@ def phase_pencil_numpy(phase: str, n: int, mesh, problem: str, *,
 # -- entry point ------------------------------------------------------------
 
 def one_chip_phases():
-    yield phase_kernels(expect_mosaic=True)
+    yield phase_kernels()
     yield phase_c2c("croft-128", 128)
     yield phase_c2c("c2c-512", 512)
     yield phase_poisson(512)
@@ -440,10 +440,10 @@ def one_chip_phases():
 def four_chip_phases():
     mesh = pencil_mesh(4)
     yield phase_pencil_planewave("croft-1024-alltoall-k2", 1024, mesh,
-                                 FFTOptions(), expect_mosaic=True)
+                                 FFTOptions())
     yield phase_pencil_planewave(
         "croft-1024-ring-k1", 1024, mesh,
-        FFTOptions(transpose_impl="ring", overlap_k=1), expect_mosaic=True)
+        FFTOptions(transpose_impl="ring", overlap_k=1))
     yield phase_pencil_numpy("pencil-256-c2c", 256, mesh, "c2c")
     yield phase_pencil_numpy("pencil-256-r2c", 256, mesh, "r2c")
 

@@ -31,21 +31,20 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, kv_chunk: int, causal: bool,
                   window, bq: int, scale: float):
     """One grid cell: q (BQ, D) vs full K/V (Skv, D) for its kv head."""
     qi = pl.program_id(2)
-    skv, d = k_ref.shape[-2:]
+    skv = k_ref.shape[-2]
     dv = v_ref.shape[-1]
-    q = q_ref[...].reshape(bq, d).astype(jnp.float32) * scale
-    k_all = k_ref[...].reshape(skv, d)
-    v_all = v_ref[...].reshape(skv, dv)
+    q = q_ref[0, 0].astype(jnp.float32) * scale
     n_chunks = skv // kv_chunk
 
     q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
 
     def body(c, carry):
         m_prev, l_prev, acc = carry
-        k = jax.lax.dynamic_slice_in_dim(
-            k_all, c * kv_chunk, kv_chunk, 0).astype(jnp.float32)
-        v = jax.lax.dynamic_slice_in_dim(
-            v_all, c * kv_chunk, kv_chunk, 0).astype(jnp.float32)
+        # a chunk is sliced from the ref: Mosaic lowers no dynamic slice
+        # of a loaded value
+        rows = pl.ds(pl.multiple_of(c * kv_chunk, kv_chunk), kv_chunk)
+        k = k_ref[0, 0, rows, :].astype(jnp.float32)
+        v = v_ref[0, 0, rows, :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         k_pos = c * kv_chunk + jax.lax.broadcasted_iota(
@@ -71,7 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, kv_chunk: int, causal: bool,
     a0 = jnp.zeros((bq, dv), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, a0))
     out = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-    o_ref[...] = out.reshape(o_ref.shape)
+    o_ref[0, 0] = out
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,

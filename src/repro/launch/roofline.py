@@ -4,7 +4,7 @@ Three terms per (arch x shape x mesh), in seconds (DESIGN.md §8):
 
   compute    = HLO_FLOPs_per_device / peak_FLOPs
   memory     = HLO_bytes_per_device / HBM_bw
-  collective = collective_bytes_per_device / ICI_bw
+  collective = collective_bytes_per_device / ICI_link_bw
 
 with the peaks of the device the program targets, looked up by its
 ``device_kind`` in :data:`DEVICE_PEAKS`.  A kind not in the table is an
@@ -31,15 +31,23 @@ class DevicePeaks:
     flops: float            # bf16 FLOP/s
     hbm_bw: float           # HBM bytes/s
     ici_bw: float           # chip-to-chip interconnect bytes/s, all links
+    ici_links: int          # ICI links per chip
     source: str
+
+    @property
+    def link_bw(self) -> float:
+        """Bytes/s of one ICI link: a collective between two neighbours
+        (one hop of a ring, one pair of a 2x2 all-to-all) uses one."""
+        return self.ici_bw / self.ici_links
 
 
 #: keyed by ``jax.Device.device_kind``
 DEVICE_PEAKS = {
     "TPU v5 lite": DevicePeaks(
-        flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8,
+        flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8, ici_links=4,
         source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
-               '16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip'),
+               '16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip over '
+               '4 links'),
 }
 
 #: the chip the dry runs compile for (``launch/dryrun.py``)
@@ -124,7 +132,7 @@ class RooflineTerms:
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes_per_device / self.peaks.ici_bw
+        return self.collective_bytes_per_device / self.peaks.link_bw
 
     @property
     def bottleneck(self) -> str:

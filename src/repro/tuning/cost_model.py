@@ -61,12 +61,12 @@ from repro.launch.roofline import DEVICE_PEAKS, TARGET_DEVICE_KIND
 from repro.tuning.candidates import Candidate
 
 # Ranking priors.  The planner ranks candidates against one chip's
-# peaks whatever it runs on: v5e's published FLOP/s and HBM bandwidth,
-# and a guessed per-link collective bandwidth.  Chip traces calibrate
-# them (ROADMAP queue 1, item 6); nothing reports a share of these.
+# peaks whatever it runs on: v5e's published FLOP/s, HBM bandwidth and
+# per-link ICI bandwidth.  Chip traces calibrate them (ROADMAP queue 1,
+# item 6); nothing reports a share of these.
 PRIOR_PEAK_FLOPS = DEVICE_PEAKS[TARGET_DEVICE_KIND].flops
 PRIOR_HBM_BW = DEVICE_PEAKS[TARGET_DEVICE_KIND].hbm_bw
-PRIOR_LINK_BW = 50e9       # bytes/s per ICI link, a guess
+PRIOR_LINK_BW = DEVICE_PEAKS[TARGET_DEVICE_KIND].link_bw
 
 # fraction of peak FLOPs each local 1-D implementation is expected to
 # sustain — coarse priors that mode="measure" refines empirically

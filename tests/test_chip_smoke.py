@@ -53,15 +53,17 @@ def test_service_phase_and_its_counters(smoke):
     assert rec["compile_s_per_round"][1] < rec["compile_s_per_round"][0]
 
 
-def test_kernels_phase(smoke):
+def test_kernels_phase(smoke, monkeypatch):
     rec = _ok(smoke.phase_kernels(n=256, rows=16))
     assert {"rotate_block_rows", "hermitian_unpack", "hermitian_extend",
             "fft4step_64", "fft4step_256", "spectral_scale"} \
         == set(rec["max_rel_err"])
     # interpreted on the CPU: no Mosaic kernel in the lowered programs
     assert not any(rec["mosaic_kernels"].values())
+    # on a TPU the same interpreted kernels fail the phase
+    monkeypatch.setattr(smoke, "on_tpu", lambda: True)
     with pytest.raises(RuntimeError, match="did not compile to Mosaic"):
-        smoke.phase_kernels(n=256, rows=16, expect_mosaic=True)
+        smoke.phase_kernels(n=256, rows=16)
 
 
 def test_checks_raise(smoke):

@@ -366,18 +366,25 @@ dec = Decomposition("pencil", ("y", "z"))
 
 # HLO pin: an armed-but-unmatched injector contributes zero ops — a plan
 # compiled under it is byte-identical to one compiled with no injector,
-# up to the source-location table (the two plans are built on different
-# lines of this script)
-def hlo_text(plan):
-    text = plan.lower_forward().compile().as_text()
-    return "\\n".join(l for l in text.splitlines() if "file_name_id=" not in l)
+# up to the source lines in the location table that compiled HLO text
+# opens with (FileNames ... StackFrames, before the first computation):
+# the two plans are built on different lines of this script
+def hlo_split(plan):
+    lines = plan.lower_forward().compile().as_text().splitlines()
+    end = next(i for i, l in enumerate(lines)
+               if l.startswith(("%", "ENTRY")))
+    start = lines.index("FileNames") if "FileNames" in lines[:end] else end
+    return lines[:start] + lines[end:], lines[start:end]
 
 pa = Croft3D((N, N, N), mesh, dec, FFTOptions(overlap_k=2))
-hlo_off = hlo_text(pa)
+ops_off, locs_off = hlo_split(pa)
 with injection([FaultSpec("exec.output", match="no-such-schedule")]):
     pb = Croft3D((N, N, N), mesh, dec, FFTOptions(overlap_k=2))
-    hlo_on = hlo_text(pb)
-assert hlo_on == hlo_off, "armed injector changed compiled HLO"
+    ops_on, locs_on = hlo_split(pb)
+assert ops_on == ops_off, "armed injector changed compiled HLO"
+assert len(locs_on) == len(locs_off), "location tables differ in size"
+assert all("file_name_id=" in a for a, b in zip(locs_on, locs_off)
+           if a != b), "location tables differ outside source lines"
 
 # executor-output poisoning: finite input -> NaN output is treated as a
 # poisoned plan; at quarantine_after=1 the entry degrades to the bottom

@@ -79,8 +79,9 @@ def test_dot_flops_with_batch_dims():
 def test_roofline_terms_math():
     pk = peaks_for("TPU v5 lite")
     assert (pk.flops, pk.hbm_bw) == (197e12, 819e9)
+    assert pk.link_bw == 50e9      # 1,600 Gbit/s over 4 links
     t = RooflineTerms(flops_per_device=pk.flops, bytes_per_device=pk.hbm_bw,
-                      collective_bytes_per_device=pk.ici_bw, n_devices=4,
+                      collective_bytes_per_device=pk.link_bw, n_devices=4,
                       device_kind="TPU v5 lite",
                       model_flops=4 * pk.flops * 0.5)
     assert abs(t.compute_s - 1.0) < 1e-9

@@ -21,8 +21,8 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.core import Croft3D, Decomposition, FFTOptions
-from repro.kernels import backend, fft_matmul, hermitian, spectral_scale
-from repro.kernels import transpose_pack
+from repro.kernels import backend, fft_matmul, flash_attention, hermitian
+from repro.kernels import spectral_scale, transpose_pack
 
 F32 = jnp.float32
 
@@ -110,6 +110,17 @@ def test_fft4step_refuses_lengths_past_its_limit():
     x = jax.ShapeDtypeStruct((8, n), F32)
     with pytest.raises(ValueError, match="local_impl='matmul'"):
         jax.eval_shape(lambda a: fft_matmul.fft4step_planes(a, a), x)
+
+
+# gemma3-4b's local attention layer (8 query heads over 4 kv heads,
+# head_dim 256, window 1024) at 2048 tokens, bf16
+def test_flash_attention_compiles_at_gemma3_local_layer(one_chip):
+    q = jax.ShapeDtypeStruct((1, 2048, 8, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 2048, 4, 256), jnp.bfloat16,
+                              sharding=one_chip)
+    _compile(lambda q, k, v: flash_attention.flash_attention(
+        q, k, v, window=1024), q, kv, kv)
 
 
 def test_pencil_ring_forward_compiles_on_2x2(tpu):
