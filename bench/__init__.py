@@ -1,0 +1,2 @@
+"""The chip benchmark of this repository: ``python3 bench/run.py`` (see
+``BENCHMARK.json`` and ``PERF.md``)."""
