@@ -35,13 +35,9 @@ legs — two deterministic, one catastrophic-only:
   wall   recorded, floor 0.5 (catches a real pack/unpack regression,
          not host-load coin flips)
 
-``run(smoke=True)`` is the CI path (fewer rounds, same gate).
-``run(trace=...)`` (CLI: ``--trace out.json``) additionally runs the
-``repro.obs`` per-stage attribution pass on the pencil alltoall-K2 and
-ring-K1 plans — after the timed sweep, so tracing never perturbs the
-wall numbers — records the model-vs-measured phase breakdown under
-``phases`` in ``BENCH_overlap.json``, and saves the Chrome trace.  The
-breakdown itself is always recorded; ``trace`` only adds the JSON file.
+``run(smoke=True)`` is the CI path (fewer rounds, same gate).  Per-stage
+device time is not this sweep's: it comes from the op scopes of
+``repro.obs.scopes`` in a chip trace.
 """
 
 from __future__ import annotations
@@ -78,7 +74,6 @@ cases = [
      Decomposition("pencil", ("y", "z"))),
     ("slab", make_mesh((8,), ("p",)), Decomposition("slab", ("p",))),
 ]
-pencil_plans = None
 for name, mesh, dec in cases:
     plans = {{}}
     for impl in IMPLS:
@@ -89,8 +84,6 @@ for name, mesh, dec in cases:
                            output_layout="spectral"))
     x = _random_input((N, N, N), jnp.complex64,
                       plans[("alltoall", 1)].input_sharding)
-    if name == "pencil":
-        pencil_plans, pencil_x = plans, x
     for p in plans.values():
         for _ in range(3):
             jax.block_until_ready(p.forward(x))
@@ -209,35 +202,14 @@ if pr < 0.5:
 if fails:
     raise SystemExit("REGRESSION: " + "; ".join(fails))
 
-# ---- per-phase attribution (repro.obs) -------------------------------------
-# Runs AFTER the timed sweep so span bookkeeping never touches the wall
-# numbers above.  Traces the two acceptance plans stage by stage and
-# joins measured legs against the cost model's predicted split.
-from repro import obs
-from repro.obs import instrument
-tracer = obs.enable()
-report["phases"] = {{}}
-for label, pk in (("alltoall-k2", ("alltoall", 2)), ("ring-k1", ("ring", 1))):
-    _, summary = instrument.trace_forward(pencil_plans[pk], pencil_x,
-                                          tracer=tracer, iters=2,
-                                          label=label)
-    report["phases"][label] = summary
-    print("ROW,overlap/attrib/%s/overlap-eff-pct,%0.3f,0"
-          % (label, 100.0 * summary["overall"]["efficiency"]))
-trace_path = {trace!r}
-if trace_path:
-    tracer.save(trace_path)
-    print("TRACE_WRITTEN " + trace_path)
-
 with open({out!r}, "w") as f:
     json.dump(report, f, indent=1, sort_keys=True)
 print("JSON_WRITTEN")
 """
 
 
-def run(smoke: bool = False, trace: str | None = None) -> None:
-    code = _SWEEP_CODE.format(rounds=21 if smoke else 41, out=BENCH_JSON,
-                              trace=trace)
+def run(smoke: bool = False) -> None:
+    code = _SWEEP_CODE.format(rounds=21 if smoke else 41, out=BENCH_JSON)
     out = run_subprocess_bench(code, n_devices=8, timeout=1800)
     for line in out.splitlines():
         if line.startswith("ROW,"):
@@ -245,16 +217,12 @@ def run(smoke: bool = False, trace: str | None = None) -> None:
             emit(name, float(us), bool(int(derived)))
     if "JSON_WRITTEN" not in out:
         raise RuntimeError("overlap sweep did not write BENCH_overlap.json")
-    if trace and "TRACE_WRITTEN" not in out:
-        raise RuntimeError("overlap sweep did not write the trace JSON")
 
 
 if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--trace", metavar="OUT.json", default=None,
-                    help="save the attribution pass's Chrome trace here")
     args = ap.parse_args()
     print("name,us_per_call,derived")
-    run(smoke=args.smoke, trace=args.trace)
+    run(smoke=args.smoke)

@@ -14,9 +14,9 @@ the CI path exercising the planner, the r2c pipeline, all three
 transpose impls, and the serving layer (including its deterministic
 batched-collective gate) end to end on every push.
 
-``--trace DIR`` has the overlap and serve sweeps save Chrome-trace JSON
-(``DIR/overlap_trace.json`` / ``DIR/serve_trace.json``) alongside their
-``BENCH_*.json`` phase breakdowns.
+``--trace DIR`` has the serve sweep save its Chrome-trace JSON
+(``DIR/serve_trace.json``) alongside its ``BENCH_serve.json`` phase
+breakdown.
 """
 
 import argparse
@@ -28,7 +28,7 @@ FULL_MODULES = ["benchmarks.fft_tables", "benchmarks.collective_profile",
                 "benchmarks.train_bench", "benchmarks.tuning_bench",
                 "benchmarks.search_bench", "benchmarks.rfft_bench",
                 "benchmarks.overlap_bench", "benchmarks.serve_bench",
-                "benchmarks.chaos_bench", "benchmarks.trace_smoke"]
+                "benchmarks.chaos_bench"]
 
 
 def main() -> None:
@@ -36,8 +36,8 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="fast tuner-only sweep (CI)")
     ap.add_argument("--trace", metavar="DIR", default=None,
-                    help="save Chrome-trace JSON from the overlap/serve "
-                         "sweeps into DIR")
+                    help="save the serve sweep's Chrome-trace JSON "
+                         "into DIR")
     args = ap.parse_args()
 
     print("name,us_per_call,derived")
@@ -47,21 +47,18 @@ def main() -> None:
 
         from benchmarks import (chaos_bench, collective_profile,
                                 overlap_bench, rfft_bench, serve_bench,
-                                trace_smoke, tuning_bench)
+                                tuning_bench)
         tdir = args.trace
         if tdir:
             os.makedirs(tdir, exist_ok=True)
         tuning_bench.run(smoke=True)
         rfft_bench.run(smoke=True)
-        overlap_bench.run(
-            smoke=True,
-            trace=os.path.join(tdir, "overlap_trace.json") if tdir else None)
+        overlap_bench.run(smoke=True)
         serve_bench.run(
             smoke=True,
             trace=os.path.join(tdir, "serve_trace.json") if tdir else None)
         chaos_bench.run(smoke=True)
         collective_profile.run(smoke=True)
-        trace_smoke.run(smoke=True)
         return
     for modname in FULL_MODULES:
         try:

@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import distributed, local_fft
 from repro.core.decomposition import Decomposition, pencil_grid_for
 from repro.core.distributed import FFTOptions
+from repro.obs.tracer import get_tracer
 
 
 @dataclasses.dataclass
@@ -120,24 +121,38 @@ class Croft3D:
             self.strategy = real_lib.resolve_strategy(
                 self.strategy, self.shape, self.mesh, self.decomp, self.opts)
             strat, nz = self.strategy, self.shape[-1]
-            self._fwd = jax.jit(lambda v: rfft.rfft3d(
-                v, self.mesh, self.decomp, self.opts, strategy=strat))
-            self._inv = jax.jit(lambda v: rfft.irfft3d(
-                v, nz, self.mesh, self.decomp, self.opts, strategy=strat))
+
+            def croft_forward(v):
+                return rfft.rfft3d(v, self.mesh, self.decomp, self.opts,
+                                   strategy=strat)
+
+            def croft_inverse(v):
+                return rfft.irfft3d(v, nz, self.mesh, self.decomp, self.opts,
+                                    strategy=strat)
         elif self.schedule is not None:
             fsched = self.schedule.build_schedule()
             isched = distributed.inverse_schedule(fsched)
             self._sched_fwd = fsched
             mesh, opts = self.mesh, self.opts
-            self._fwd = jax.jit(lambda v: distributed.scheduled_fft3d(
-                v, mesh, fsched, opts))
-            self._inv = jax.jit(lambda v: distributed.scheduled_fft3d(
-                v, mesh, isched, opts, norm="backward"))
+
+            def croft_forward(v):
+                return distributed.scheduled_fft3d(v, mesh, fsched, opts)
+
+            def croft_inverse(v):
+                return distributed.scheduled_fft3d(v, mesh, isched, opts,
+                                                   norm="backward")
         else:
-            self._fwd = jax.jit(lambda v: distributed.fft3d(
-                v, self.mesh, self.decomp, self.opts))
-            self._inv = jax.jit(lambda v: distributed.ifft3d(
-                v, self.mesh, self.decomp, self.opts))
+            def croft_forward(v):
+                return distributed.fft3d(v, self.mesh, self.decomp,
+                                         self.opts)
+
+            def croft_inverse(v):
+                return distributed.ifft3d(v, self.mesh, self.decomp,
+                                          self.opts)
+        # named functions, not lambdas: the XLA module names
+        # (jit_croft_forward, ...) tell the programs apart in a trace
+        self._fwd = jax.jit(croft_forward)
+        self._inv = jax.jit(croft_inverse)
 
     # -- dtypes / shapes -----------------------------------------------------
     @property
@@ -197,11 +212,18 @@ class Croft3D:
         return self.decomp.local_shape(self.shape, self.mesh)
 
     # -- transforms ----------------------------------------------------------
+    #
+    # Each entry opens a ``croft.<entry>`` span on the process tracer
+    # (``repro.obs.tracer``): a shared null context by default, a
+    # ``jax.profiler`` annotation under ``tracer.profiler_sink()``.
+
     def forward(self, x: jax.Array) -> jax.Array:
-        return self._fwd(x)
+        with get_tracer().span("croft.forward"):
+            return self._fwd(x)
 
     def inverse(self, y: jax.Array) -> jax.Array:
-        return self._inv(y)
+        with get_tracer().span("croft.inverse"):
+            return self._inv(y)
 
     _fwd_filtered = None
 
@@ -215,20 +237,28 @@ class Croft3D:
             if self.problem == "r2c":
                 from repro.core import rfft
                 strat = self.strategy
-                fn = jax.jit(lambda v, hh: rfft.rfft3d(
-                    v, self.mesh, self.decomp, self.opts, strategy=strat,
-                    kspace_filter=hh, fold_filter=fold))
+
+                def croft_forward_filtered(v, hh):
+                    return rfft.rfft3d(v, self.mesh, self.decomp, self.opts,
+                                       strategy=strat, kspace_filter=hh,
+                                       fold_filter=fold)
             elif fold:
                 raise ValueError("fold=True is the packed r2c folded "
                                  "epilogue; c2c filters are always fused "
                                  "in-schedule")
             elif self.schedule is not None:
                 mesh, opts, fsched = self.mesh, self.opts, self._sched_fwd
-                fn = jax.jit(lambda v, hh: distributed.scheduled_fft3d(
-                    v, mesh, fsched, opts, kspace_filter=hh))
+
+                def croft_forward_filtered(v, hh):
+                    return distributed.scheduled_fft3d(
+                        v, mesh, fsched, opts, kspace_filter=hh)
             else:
-                fn = jax.jit(lambda v, hh: distributed.fft3d(
-                    v, self.mesh, self.decomp, self.opts, kspace_filter=hh))
+                def croft_forward_filtered(v, hh):
+                    return distributed.fft3d(v, self.mesh, self.decomp,
+                                             self.opts, kspace_filter=hh)
+            if fold:
+                croft_forward_filtered.__name__ += "_folded"
+            fn = jax.jit(croft_forward_filtered)
             self._fwd_filtered[fold] = fn
         return fn
 
@@ -250,8 +280,9 @@ class Croft3D:
         (e.g. a kz-independent low-pass over (kx, ky), or any filter
         whose DC and Nyquist kz-planes coincide).
         """
-        hh = h if alpha == 1.0 else h * jnp.asarray(alpha, h.dtype)
-        return self._filtered_fn(fold)(x, hh)
+        with get_tracer().span("croft.forward_filtered"):
+            hh = h if alpha == 1.0 else h * jnp.asarray(alpha, h.dtype)
+            return self._filtered_fn(fold)(x, hh)
 
     # -- batched dispatch (the serving path) ---------------------------------
     #
@@ -272,28 +303,41 @@ class Croft3D:
         if fn is not None:
             return fn
         native_packed = self.problem == "r2c" and self.strategy == "packed"
+        donate = (0,) if self.problem == "c2c" else ()
         if kind == "forward":
             if native_packed:
                 from repro.core import rfft
                 strat = self.strategy
-                fn = jax.jit(lambda v: rfft.rfft3d(
-                    v, self.mesh, self.decomp, self.opts, strategy=strat))
+
+                def croft_forward_batched(v):
+                    return rfft.rfft3d(v, self.mesh, self.decomp, self.opts,
+                                       strategy=strat)
             else:
-                donate = (0,) if self.problem == "c2c" else ()
-                fn = jax.jit(jax.vmap(self._fwd), donate_argnums=donate)
+                one = self._fwd
+
+                def croft_forward_batched(v):
+                    return jax.vmap(one)(v)
+            fn = jax.jit(croft_forward_batched, donate_argnums=donate)
         elif kind == "inverse":
             if native_packed:
                 from repro.core import rfft
                 strat, nz = self.strategy, self.shape[-1]
-                fn = jax.jit(lambda v: rfft.irfft3d(
-                    v, nz, self.mesh, self.decomp, self.opts,
-                    strategy=strat))
+
+                def croft_inverse_batched(v):
+                    return rfft.irfft3d(v, nz, self.mesh, self.decomp,
+                                        self.opts, strategy=strat)
             else:
-                donate = (0,) if self.problem == "c2c" else ()
-                fn = jax.jit(jax.vmap(self._inv), donate_argnums=donate)
+                one = self._inv
+
+                def croft_inverse_batched(v):
+                    return jax.vmap(one)(v)
+            fn = jax.jit(croft_inverse_batched, donate_argnums=donate)
         elif kind == "filtered":
-            donate = (0,) if self.problem == "c2c" else ()
-            fn = jax.jit(jax.vmap(self._filtered_fn()),
+            one = self._filtered_fn()
+
+            def croft_forward_filtered_batched(v, hh):
+                return jax.vmap(one)(v, hh)
+            fn = jax.jit(croft_forward_filtered_batched,
                          donate_argnums=donate)
         else:
             raise ValueError(f"unknown batched kind {kind!r}")
@@ -304,18 +348,21 @@ class Croft3D:
         """``forward`` over a (B, Nx, Ny, Nz) stack — same per-stage
         collective count as B=1, results bitwise equal to B calls of
         :meth:`forward`.  c2c donates ``x``."""
-        return self._batched_fn("forward")(x)
+        with get_tracer().span("croft.forward_batched"):
+            return self._batched_fn("forward")(x)
 
     def inverse_batched(self, y: jax.Array) -> jax.Array:
         """``inverse`` over a (B, ...) spectrum stack (see
         :meth:`forward_batched`)."""
-        return self._batched_fn("inverse")(y)
+        with get_tracer().span("croft.inverse_batched"):
+            return self._batched_fn("inverse")(y)
 
     def forward_filtered_batched(self, x: jax.Array,
                                  h: jax.Array) -> jax.Array:
         """:meth:`forward_filtered` over (B, ...) field and filter stacks
         (each request brings its own ``h``)."""
-        return self._batched_fn("filtered")(x, h)
+        with get_tracer().span("croft.forward_filtered_batched"):
+            return self._batched_fn("filtered")(x, h)
 
     def batched_sharding(self, which: str = "input"):
         """``input_sharding``/``output_sharding`` widened with a leading
@@ -375,10 +422,27 @@ class Croft3D:
                    tune_kw=tune_kw or None)
 
     # -- AOT artifacts for the dry-run / roofline ----------------------------
+    def lower(self, entry: str = "forward"):
+        """One entry point's program (``forward``, ``inverse`` or
+        ``forward_filtered``), lowered at this plan's shapes and
+        shardings: the program a call with arrays placed as
+        ``input_sharding``/``output_sharding`` say runs.  Its compiled
+        text names each instruction's ``op_name`` scopes, which a device
+        trace does not carry."""
+        x = jax.ShapeDtypeStruct(self.shape, self.input_dtype,
+                                 sharding=self.input_sharding)
+        y = jax.ShapeDtypeStruct(self.spectrum_shape, self.dtype,
+                                 sharding=self.output_sharding)
+        if entry == "forward":
+            return self._fwd.lower(x)
+        if entry == "inverse":
+            return self._inv.lower(y)
+        if entry == "forward_filtered":
+            return self._filtered_fn().lower(x, y)
+        raise ValueError(f"unknown entry {entry!r}")
+
     def lower_forward(self):
-        spec = jax.ShapeDtypeStruct(self.shape, self.input_dtype,
-                                    sharding=self.input_sharding)
-        return self._fwd.lower(spec)
+        return self.lower("forward")
 
     def candidate(self):
         """This plan's tuner-space identity: the searched

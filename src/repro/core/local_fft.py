@@ -26,12 +26,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import plan as plan_lib
+from repro.obs import scopes
 
 
+@scopes.role(scopes.DFT)
 def fft_xla(x: jax.Array, sign: int = -1) -> jax.Array:
     return jnp.fft.fft(x) if sign == -1 else jnp.fft.ifft(x) * x.shape[-1]
 
 
+@scopes.role(scopes.DFT)
 def _apply_dft_matrix(x: jax.Array, w: jax.Array) -> jax.Array:
     # x (..., n), w (n, k): complex matmul on the MXU (XLA decomposes to
     # real dots); contraction over the last axis.
@@ -49,34 +52,41 @@ def fft_matmul(x: jax.Array, sign: int = -1, *, plan_cache: bool = True,
     """
     n = x.shape[-1]
     plan = plan_lib.make_plan(n, sign, str(x.dtype), max_radix)
-    w1, w2, tw = plan.constants_jnp(rematerialize=not plan_cache)
+    with jax.named_scope(scopes.DFT):
+        w1, w2, tw = plan.constants_jnp(rematerialize=not plan_cache)
     if plan.n2 == 1:
         return _apply_dft_matrix(x, w1)
 
     batch = x.shape[:-1]
     n1, n2 = plan.n1, plan.n2
     # n = n2*j1 + j2  (row-major reshape)
-    xr = x.reshape(batch + (n1, n2))
-    # stage 1: DFT over j1 -> (..., n2, k1)
-    y = jnp.einsum("...jt,jk->...tk", xr, w1,
-                   precision=jax.lax.Precision.HIGHEST)
-    # stage 2: twiddles T[j2, k1]
-    y = y * tw
+    with jax.named_scope(scopes.RELAYOUT):
+        xr = x.reshape(batch + (n1, n2))
+    with jax.named_scope(scopes.DFT):
+        # stage 1: DFT over j1 -> (..., n2, k1)
+        y = jnp.einsum("...jt,jk->...tk", xr, w1,
+                       precision=jax.lax.Precision.HIGHEST)
+        # stage 2: twiddles T[j2, k1]
+        y = y * tw
     if n2 <= max_radix:
         # stage 3: DFT over j2 -> (..., k1, k2): contract the t axis
-        z = jnp.einsum("...tk,ts->...ks", y, w2,
-                       precision=jax.lax.Precision.HIGHEST)
+        with jax.named_scope(scopes.DFT):
+            z = jnp.einsum("...tk,ts->...ks", y, w2,
+                           precision=jax.lax.Precision.HIGHEST)
     else:
         # six-step: recurse along the n2 axis (currently axis -2); move it
         # last, recurse, move back
-        y = jnp.swapaxes(y, -1, -2)  # (..., k1, n2)
+        with jax.named_scope(scopes.RELAYOUT):
+            y = jnp.swapaxes(y, -1, -2)  # (..., k1, n2)
         z = fft_matmul(y, sign, plan_cache=plan_cache, max_radix=max_radix)
         # z[..., k1, k2] already
     # output index k = k1 + n1*k2  -> lay out (..., k2, k1) then ravel
-    z = jnp.swapaxes(z, -1, -2)
-    return z.reshape(batch + (n,))
+    with jax.named_scope(scopes.RELAYOUT):
+        z = jnp.swapaxes(z, -1, -2)
+        return z.reshape(batch + (n,))
 
 
+@scopes.role(scopes.DFT)
 def fft_stockham(x: jax.Array, sign: int = -1, *, plan_cache: bool = True) -> jax.Array:
     """Radix-2 DIT FFT along the last axis (power-of-two sizes).
 
@@ -119,15 +129,18 @@ def fft_1d(x: jax.Array, axis: int, sign: int = -1, *, impl: str = "matmul",
     """1-D FFT along ``axis`` with the chosen implementation."""
     if impl == "pallas":
         from repro.kernels import ops as kernel_ops  # lazy: optional dep path
-        fn = lambda v: kernel_ops.fft_matmul_1d(v, sign=sign)
+        fn = scopes.role(scopes.DFT)(
+            lambda v: kernel_ops.fft_matmul_1d(v, sign=sign))
     elif impl == "xla":
         fn = lambda v: fft_xla(v, sign)
     else:
         base = _IMPLS[impl]
         fn = lambda v: base(v, sign, plan_cache=plan_cache)
-    x = jnp.moveaxis(x, axis, -1)
+    with jax.named_scope(scopes.RELAYOUT):
+        x = jnp.moveaxis(x, axis, -1)
     y = fn(x)
-    return jnp.moveaxis(y, -1, axis)
+    with jax.named_scope(scopes.RELAYOUT):
+        return jnp.moveaxis(y, -1, axis)
 
 
 def fft3d_local(x: jax.Array, sign: int = -1, *, impl="matmul",
@@ -141,10 +154,12 @@ def fft3d_local(x: jax.Array, sign: int = -1, *, impl="matmul",
     assert x.ndim >= 3
     for stage, ax in enumerate((-3, -2, -1)):
         stage_impl = impl[stage] if isinstance(impl, (tuple, list)) else impl
-        x = fft_1d(x, ax, sign, impl=stage_impl, plan_cache=plan_cache)
+        with scopes.stage("xyz"[stage] + "-fft"):
+            x = fft_1d(x, ax, sign, impl=stage_impl, plan_cache=plan_cache)
     return apply_norm(x, sign, norm)
 
 
+@scopes.role(scopes.SCALE)
 def apply_norm(x: jax.Array, sign: int, norm: Optional[str]) -> jax.Array:
     """Paper convention (eq. 2): forward unnormalized, inverse 1/(NxNyNz)."""
     nxyz = x.shape[-3] * x.shape[-2] * x.shape[-1]

@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import distributed, local_fft
 from repro.core.decomposition import Decomposition
 from repro.core.distributed import FFTOptions
+from repro.obs import scopes
 from repro import real as real_lib
 # submodule-import form: resolves even while repro.real's own __init__ is
 # still running (e.g. `import repro.real` pulls repro.core, which pulls
@@ -66,15 +67,15 @@ def _guarded_half_slice(y: jax.Array, nz: int, mesh, decomp, opts) -> jax.Array:
     r2c spectrum comes back in the z-local layout.
     """
     nh = nz // 2 + 1
-    if not _is_multidevice(mesh) or decomp is None:
+    if (_is_multidevice(mesh) and decomp is not None
+            and _z_shard_count(decomp, mesh, opts.output_layout) > 1):
+        if decomp.kind in ("pencil", "slab"):
+            target = decomp.spectral_spec()    # z local, x/y take the shards
+        else:  # cell: no 3-axis layout keeps z local; replicate over z
+            target = P(decomp.axes[0], decomp.axes[1], None)
+        y = real_lib.constrain_sharding(y, NamedSharding(mesh, target))
+    with jax.named_scope(scopes.RELAYOUT):
         return y[..., :nh]
-    if _z_shard_count(decomp, mesh, opts.output_layout) == 1:
-        return y[..., :nh]
-    if decomp.kind in ("pencil", "slab"):
-        target = decomp.spectral_spec()        # z local, x/y take the shards
-    else:  # cell: no 3-axis layout keeps z local; replicate over the z axis
-        target = P(decomp.axes[0], decomp.axes[1], None)
-    return real_lib.constrain_sharding(y, NamedSharding(mesh, target))[..., :nh]
 
 
 def rfft3d(x: jax.Array, mesh=None, decomp: Optional[Decomposition] = None,
@@ -117,8 +118,9 @@ def rfft3d(x: jax.Array, mesh=None, decomp: Optional[Decomposition] = None,
                                           fold_filter=fold_filter)
     else:
         nz = x.shape[-1]
-        xc = x.astype(jnp.complex64 if x.dtype != jnp.float64
-                      else jnp.complex128)
+        with jax.named_scope(scopes.RELAYOUT):
+            xc = x.astype(jnp.complex64 if x.dtype != jnp.float64
+                          else jnp.complex128)
         y = distributed.fft3d(xc, mesh, decomp, opts, norm=norm)
         y = _guarded_half_slice(y, nz, mesh, decomp, opts)
     if kspace_filter is not None:
@@ -148,15 +150,17 @@ def irfft3d(y: jax.Array, nz: int, mesh=None,
         if not _is_multidevice(mesh):
             return real_lib.local_irfft3d_packed(y, nz, opts, norm=norm)
         return real_lib.packed_irfft3d(y, nz, mesh, decomp, opts, norm=norm)
-    body = y[..., 1: (nz + 1) // 2]           # kz' = 1 .. ceil(nz/2)-1
-    tail = jnp.conj(body)
-    tail = _negate_freq(tail, -3)             # -kx mod Nx
-    tail = _negate_freq(tail, -2)             # -ky mod Ny
-    tail = jnp.flip(tail, -1)                 # ascending kz = nz-kz' order
-    full = jnp.concatenate([y, tail], axis=-1)
+    with jax.named_scope(scopes.RELAYOUT):
+        body = y[..., 1: (nz + 1) // 2]       # kz' = 1 .. ceil(nz/2)-1
+        tail = jnp.conj(body)
+        tail = _negate_freq(tail, -3)         # -kx mod Nx
+        tail = _negate_freq(tail, -2)         # -ky mod Ny
+        tail = jnp.flip(tail, -1)             # ascending kz = nz-kz' order
+        full = jnp.concatenate([y, tail], axis=-1)
     assert full.shape[-1] == nz, (full.shape, nz)
     x = distributed.ifft3d(full, mesh, decomp, opts, norm=norm)
-    return jnp.real(x)
+    with jax.named_scope(scopes.RELAYOUT):
+        return jnp.real(x)
 
 
 def rfft3d_local(x: jax.Array) -> jax.Array:

@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import local_fft
+from repro.obs import scopes
 
 AxisName = Union[str, tuple]
 
@@ -541,6 +542,7 @@ def _fft_along(blk: jax.Array, axis: int, sign: int, opts,
                             plan_cache=opts.plan_cache)
 
 
+@scopes.role(scopes.RELAYOUT)
 def _pack_pieces(blk: jax.Array, axis: AxisName, split_axis: int) -> list:
     """Rotated-block pack shared by the ring and pairwise transposes.
 
@@ -556,7 +558,7 @@ def _pack_pieces(blk: jax.Array, axis: AxisName, split_axis: int) -> list:
 
 
 def _ring_transpose(blk: jax.Array, axis: AxisName, split_axis: int,
-                    concat_axis: int, round_cb=None) -> jax.Array:
+                    concat_axis: int) -> jax.Array:
     """P-1-round ring transpose: pack -> send -> unpack, no serial chain.
 
     The rounds are structurally independent (each ppermute consumes its
@@ -574,18 +576,15 @@ def _ring_transpose(blk: jax.Array, axis: AxisName, split_axis: int,
     idx = jax.lax.axis_index(axis)
     pieces = _pack_pieces(blk, axis, split_axis)
     recv = [pieces[0]]                      # round 0: my own block, no comm
-    for s in range(1, p):
-        perm = [(i, (i + s) % p) for i in range(p)]
-        piece = jax.lax.ppermute(pieces[s], axis, perm)
-        if round_cb is not None:
-            # round-indexed observability hook (repro.obs): must return the
-            # piece (possibly wrapped); the default None emits identical HLO
-            piece = round_cb(s, piece)
-        recv.append(piece)
+    with jax.named_scope(scopes.TRANSPOSE):
+        for s in range(1, p):
+            perm = [(i, (i + s) % p) for i in range(p)]
+            recv.append(jax.lax.ppermute(pieces[s], axis, perm))
     # concat order [round 0, round P-1, ..., round 1] puts the piece from
     # src (idx + m) % P at block m; rotating by -idx restores src order.
     ordered = [recv[0]] + recv[:0:-1]
-    return transpose_pack.unpack_pieces(ordered, concat_axis, -idx)
+    with jax.named_scope(scopes.RELAYOUT):
+        return transpose_pack.unpack_pieces(ordered, concat_axis, -idx)
 
 
 def _pairwise_transpose(blk: jax.Array, axis: AxisName, split_axis: int,
@@ -605,26 +604,28 @@ def _pairwise_transpose(blk: jax.Array, axis: AxisName, split_axis: int,
     out_shape = list(blk.shape)
     out_shape[split_axis] = pieces[0].shape[split_axis]
     out_shape[concat_axis] = n_cat * p
-    out = jnp.zeros(out_shape, blk.dtype)
-    out = jax.lax.dynamic_update_slice_in_dim(out, pieces[0], idx * n_cat,
-                                              concat_axis)
+    with jax.named_scope(scopes.RELAYOUT):
+        out = jnp.zeros(out_shape, blk.dtype)
+        out = jax.lax.dynamic_update_slice_in_dim(out, pieces[0],
+                                                  idx * n_cat, concat_axis)
     for s in range(1, p):
         perm = [(i, (i + s) % p) for i in range(p)]
-        recv = jax.lax.ppermute(pieces[s], axis, perm)
-        if s + 1 < p:
-            # blocking round: the next send may not start until this
-            # round's receive has completed
-            pieces[s + 1], _ = jax.lax.optimization_barrier(
-                (pieces[s + 1], recv))
+        with jax.named_scope(scopes.TRANSPOSE):
+            recv = jax.lax.ppermute(pieces[s], axis, perm)
+            if s + 1 < p:
+                # blocking round: the next send may not start until this
+                # round's receive has completed
+                pieces[s + 1], _ = jax.lax.optimization_barrier(
+                    (pieces[s + 1], recv))
         src = (idx - s) % p
-        out = jax.lax.dynamic_update_slice_in_dim(out, recv, src * n_cat,
-                                                  concat_axis)
+        with jax.named_scope(scopes.RELAYOUT):
+            out = jax.lax.dynamic_update_slice_in_dim(out, recv, src * n_cat,
+                                                      concat_axis)
     return out
 
 
 def _all_to_all(blk: jax.Array, axis: AxisName, split_axis: int,
-                concat_axis: int, impl: str = "alltoall",
-                ring_round_cb=None) -> jax.Array:
+                concat_axis: int, impl: str = "alltoall") -> jax.Array:
     """Global transpose along one communicator.
 
     ``impl="alltoall"``  one fused collective (CROFT's MPI_Alltoall).
@@ -637,25 +638,22 @@ def _all_to_all(blk: jax.Array, axis: AxisName, split_axis: int,
                          ops; used for the figs 12-15 benchmark.
     """
     if impl == "alltoall":
-        return jax.lax.all_to_all(blk, axis, split_axis=split_axis,
-                                  concat_axis=concat_axis, tiled=True)
+        with jax.named_scope(scopes.TRANSPOSE):
+            return jax.lax.all_to_all(blk, axis, split_axis=split_axis,
+                                      concat_axis=concat_axis, tiled=True)
     if impl not in ("ring", "pairwise"):
         raise ValueError(f"unknown transpose impl {impl!r}")
     if isinstance(axis, tuple):
         raise ValueError(f"{impl} transpose supports single mesh axes only")
     if impl == "ring":
-        return _ring_transpose(blk, axis, split_axis, concat_axis,
-                               round_cb=ring_round_cb)
+        return _ring_transpose(blk, axis, split_axis, concat_axis)
     return _pairwise_transpose(blk, axis, split_axis, concat_axis)
 
 
 def stage_pre(blk: jax.Array, st: Stage, sign: int, opts, off: int = 0,
               ctx=None) -> jax.Array:
     """The compute leg of one stage: prologue ops -> local FFT ->
-    epilogue ops, on one (chunk of a) local block.  Module-level so the
-    tracer's per-stage attribution (``repro.obs.instrument``) can build
-    a compute-only executable from the exact emission ``run_stage``
-    uses."""
+    epilogue ops, on one (chunk of a) local block."""
     ctx = ctx or {}
     for op in st.prologue:
         blk = op.apply(blk, opts, ctx, off)
@@ -666,32 +664,11 @@ def stage_pre(blk: jax.Array, st: Stage, sign: int, opts, off: int = 0,
     return blk
 
 
-def stage_comm(blk: jax.Array, st: Stage, opts, off: int = 0,
-               ring_round_cb=None) -> jax.Array:
+def stage_comm(blk: jax.Array, st: Stage, opts, off: int = 0) -> jax.Array:
     """The collective leg of one stage (the global transpose); the
-    counterpart of :func:`stage_pre`.  ``ring_round_cb(round, piece)``,
-    when given and the stage resolves to the ring impl, is invoked on each
-    of the P-1 received pieces so ``repro.obs`` can tag per-round spans."""
+    counterpart of :func:`stage_pre`."""
     return _all_to_all(blk, st.comm_axis, st.split_axis + off,
-                       st.concat_axis + off, stage_transpose_impl(st, opts),
-                       ring_round_cb=ring_round_cb)
-
-
-def ring_round(blk: jax.Array, st: Stage, opts, rnd: int,
-               off: int = 0) -> jax.Array:
-    """One ring-transpose round of a comm stage, as a standalone jittable
-    unit: the fused rotated pack plus round ``rnd``'s single ppermute
-    (round 0 is the rank's own piece — no wire traffic).  Returns the
-    received piece without placing it; production execution stays in
-    :func:`stage_comm`.  Used by ``repro.obs.instrument`` to time ring
-    stages round by round."""
-    axis = st.comm_axis
-    pieces = _pack_pieces(blk, axis, st.split_axis + off)
-    if rnd == 0:
-        return pieces[0]
-    p = jax.lax.axis_size(axis)
-    perm = [(i, (i + rnd) % p) for i in range(p)]
-    return jax.lax.ppermute(pieces[rnd], axis, perm)
+                       st.concat_axis + off, stage_transpose_impl(st, opts))
 
 
 def stage_category(st: Stage) -> str:
@@ -706,7 +683,7 @@ def stage_category(st: Stage) -> str:
 
 
 def run_stage(blk: jax.Array, st: Stage, sign: int, opts, off: int = 0,
-              ctx=None, ring_round_cb=None) -> jax.Array:
+              ctx=None) -> jax.Array:
     """Execute one stage on a local block (axis indices offset by ``off``
     for leading batch dims).  Owns the K-chunked overlap and the silent
     fallback to one chunk when ``chunk_axis`` is not divisible by K.
@@ -721,14 +698,19 @@ def run_stage(blk: jax.Array, st: Stage, sign: int, opts, off: int = 0,
     i+1's FFT only in the dependence graph, relying on XLA's async
     collective scheduler to interleave them).  Both modes run the same
     ops on the same chunks, so their outputs are bitwise identical.
+
+    Every op lands under ``croft.stage.<name>`` (``repro.obs.scopes``),
+    and chunk i's ops under ``k<i>`` inside it.
     """
     ctx = ctx or {}
 
-    def pre(c):
-        return stage_pre(c, st, sign, opts, off, ctx)
+    def pre(c, i=None):
+        with scopes.stage(st.name, i):
+            return stage_pre(c, st, sign, opts, off, ctx)
 
-    def comm(c):
-        return stage_comm(c, st, opts, off, ring_round_cb=ring_round_cb)
+    def comm(c, i=None):
+        with scopes.stage(st.name, i):
+            return stage_comm(c, st, opts, off)
 
     if st.comm_axis is None:
         return pre(blk)  # nothing to overlap with: never chunked
@@ -736,37 +718,39 @@ def run_stage(blk: jax.Array, st: Stage, sign: int, opts, off: int = 0,
     if k <= 1 or blk.shape[st.chunk_axis + off] % k:
         return comm(pre(blk))
     ax = st.chunk_axis + off
-    chunks = jnp.split(blk, k, axis=ax)
+    with scopes.stage(st.name), jax.named_scope(scopes.RELAYOUT):
+        chunks = jnp.split(blk, k, axis=ax)
     if opts.stage_overlap(st.impl_stage) == "unrolled":
-        return jnp.concatenate([comm(pre(c)) for c in chunks], axis=ax)
-    # pipelined: double-buffered staged unroll — while chunk i is on the
-    # wire, chunk i+1 is in the FFT (the paper's second OpenMP thread)
-    outs = []
-    inflight = pre(chunks[0])
-    for i in range(k):
-        nxt = pre(chunks[i + 1]) if i + 1 < k else None
-        outs.append(comm(inflight))
-        inflight = nxt
-    return jnp.concatenate(outs, axis=ax)
+        outs = [comm(pre(c, i), i) for i, c in enumerate(chunks)]
+    else:
+        # pipelined: double-buffered staged unroll — while chunk i is on
+        # the wire, chunk i+1 is in the FFT (the paper's second OpenMP
+        # thread)
+        outs = []
+        inflight = pre(chunks[0], 0)
+        for i in range(k):
+            nxt = pre(chunks[i + 1], i + 1) if i + 1 < k else None
+            outs.append(comm(inflight, i))
+            inflight = nxt
+    with scopes.stage(st.name), jax.named_scope(scopes.RELAYOUT):
+        return jnp.concatenate(outs, axis=ax)
 
 
 def run_schedule(blk: jax.Array, sched: Schedule, opts,
-                 operands=None, ring_round_cb=None) -> jax.Array:
+                 operands=None) -> jax.Array:
     """Execute a schedule on a local (shard_map) block.
 
     Leading batch axes are carried along unsharded: every axis index in
     the schedule is offset by ``blk.ndim - 3``.  ``operands`` supplies
     named blocks to ops that need them (e.g. the fused k-space filter).
-    ``ring_round_cb(round, piece)`` is the observability hook threaded to
-    every ring-impl transpose (see :func:`stage_comm`).
     """
     off = blk.ndim - 3
     ctx = dict(operands or {})
     for st in sched.stages:
-        blk = run_stage(blk, st, sched.sign, opts, off, ctx,
-                        ring_round_cb=ring_round_cb)
-    for op in sched.epilogue:
-        blk = op.apply(blk, opts, ctx, off)
+        blk = run_stage(blk, st, sched.sign, opts, off, ctx)
+    with scopes.stage("epilogue"):
+        for op in sched.epilogue:
+            blk = op.apply(blk, opts, ctx, off)
     # Fault plane: trace-time output poisoning.  ``corrupt`` is decided
     # while tracing, so an unarmed (or unmatched) injector contributes
     # zero ops — the compiled HLO is byte-identical to a build with no

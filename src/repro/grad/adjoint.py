@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from repro.core.schedule import (_DIMS, PackTwo, RepackHalves, Schedule,
                                  ScheduleError, SpectralScale, SplitPairs,
                                  Stage, StageOp, UnpackTwo)
+from repro.obs import scopes
 from repro.real import packing
 
 
@@ -62,6 +63,7 @@ class PackTwoT(StageOp):
 
     pair_axis: int
 
+    @scopes.role(scopes.RELAYOUT)
     def apply(self, blk, opts, ctx, off):
         ax = self.pair_axis + off
         return jnp.concatenate([jnp.real(blk), -jnp.imag(blk)], axis=ax)
@@ -83,6 +85,7 @@ class SplitPairsT(StageOp):
 
     pair_axis: int
 
+    @scopes.role(scopes.RELAYOUT)
     def apply(self, blk, opts, ctx, off):
         ax = self.pair_axis + off
         m = blk.shape[ax]
@@ -120,6 +123,7 @@ class UnpackTwoT(StageOp):
     z_axis: int = 2
     impl_stage: int = 0
 
+    @scopes.role(scopes.RELAYOUT)
     def apply(self, blk, opts, ctx, off):
         ax = self.pair_axis + off
         m = blk.shape[ax]
@@ -158,6 +162,7 @@ class RepackHalvesT(StageOp):
     z_axis: int = 2
     impl_stage: int = 2
 
+    @scopes.role(scopes.RELAYOUT)
     def apply(self, blk, opts, ctx, off):
         ax = self.pair_axis + off
         n = blk.shape[-1]
@@ -335,6 +340,7 @@ def _herm2(p: jax.Array) -> jax.Array:
         packing.negate_freq(p, -1), -2)))
 
 
+@scopes.role(scopes.RELAYOUT)
 def unfold_dc_plane_t(ct: jax.Array) -> jax.Array:
     """Transpose of :func:`repro.real.pipeline.unfold_dc_plane`:
     rfftn-shaped cotangent (..., Nz2 + 1) -> packed cotangent (..., Nz2)
@@ -344,6 +350,7 @@ def unfold_dc_plane_t(ct: jax.Array) -> jax.Array:
     return jnp.concatenate([g[..., None], ct[..., 1:nz2]], axis=-1)
 
 
+@scopes.role(scopes.RELAYOUT)
 def fold_dc_plane_t(pbar: jax.Array, nz: int) -> jax.Array:
     """Transpose of :func:`repro.real.pipeline.fold_dc_plane`: packed
     cotangent (..., Nz2) -> rfftn-shaped cotangent (..., Nz2 + 1)."""

@@ -31,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
 from repro.core import schedule as schedule_lib
+from repro.obs import scopes
 from repro.grad.adjoint import (adjoint_schedule, fold_dc_plane_t,
                                 unfold_dc_plane_t)
 
@@ -41,6 +42,7 @@ def _with_batch(spec, n: int):
     return P(*((None,) * n), *spec)
 
 
+@scopes.role(scopes.SCALE)
 def _scaled(y: jax.Array, scale) -> jax.Array:
     return y if scale is None else y * jnp.asarray(scale, y.dtype)
 

@@ -115,6 +115,7 @@ def fft4step_planes(xr: jax.Array, xi: jax.Array, sign: int = -1, *,
             plan_lib.dft_matrix(n, sign, np.complex128)))
         return tuple(pl.pallas_call(
             _dense_kernel,
+            name="croft_fft_dense",
             grid=(b // block_rows,),
             in_specs=[row((block_rows, n)), row((block_rows, n)),
                       const(w.shape)],
@@ -135,6 +136,7 @@ def fft4step_planes(xr: jax.Array, xi: jax.Array, sign: int = -1, *,
     twi = jnp.asarray(tw.imag, jnp.float32)
     yr, yi = pl.pallas_call(
         functools.partial(_fft4step_kernel, w1=w1),
+        name="croft_fft4step",
         grid=(b // block_rows,),
         in_specs=[row((block_rows, n)), row((block_rows, n)),
                   const(w2.shape), const(twr.shape), const(twi.shape)],

@@ -96,6 +96,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         bq=q_block, scale=scale)
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(b, h, sq // q_block),
         in_specs=[
             pl.BlockSpec((1, 1, q_block, d),

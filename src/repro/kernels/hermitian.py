@@ -93,6 +93,7 @@ def unpack_two_for_one_planes(cr, ci, *, block_rows: int = 0,
     out_spec = pl.BlockSpec((block_rows, nz2), lambda i: (i, 0))
     return pl.pallas_call(
         _unpack_kernel,
+        name="croft_hermitian_unpack",
         grid=grid,
         in_specs=[in_spec, in_spec],
         out_specs=[out_spec] * 4,
@@ -133,6 +134,7 @@ def hermitian_extend_planes(sar, sai, sbr, sbi, *, block_rows: int = 0,
     out_spec = pl.BlockSpec((block_rows, n), lambda i: (i, 0))
     return pl.pallas_call(
         _extend_kernel,
+        name="croft_hermitian_extend",
         grid=grid,
         in_specs=[in_spec] * 4,
         out_specs=[out_spec] * 2,

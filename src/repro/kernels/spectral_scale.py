@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import backend
+from repro.obs import scopes
 
 
 def _scale_kernel(xr_ref, xi_ref, hr_ref, hi_ref, or_ref, oi_ref, *,
@@ -42,6 +43,7 @@ def spectral_scale_planes(xr, xi, hr, hi, alpha: float = 1.0, *,
     kernel = functools.partial(_scale_kernel, alpha=alpha)
     return pl.pallas_call(
         kernel,
+        name="croft_spectral_scale",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
@@ -72,6 +74,7 @@ def spectral_scale_planes_full(xr, xi, hr, hi, alpha: float = 1.0, *,
     blk = pl.BlockSpec((block_rows, n), lambda i: (i, 0))
     return pl.pallas_call(
         kernel,
+        name="croft_spectral_scale_full",
         grid=grid,
         in_specs=[blk, blk, blk, blk],
         out_specs=[blk, blk],
@@ -80,6 +83,7 @@ def spectral_scale_planes_full(xr, xi, hr, hi, alpha: float = 1.0, *,
     )(xr, xi, hr, hi)
 
 
+@scopes.role(scopes.SCALE)
 def spectral_scale(x: jax.Array, h: jax.Array, alpha: float = 1.0, *,
                    use_pallas: bool | None = None,
                    interpret: bool | None = None) -> jax.Array:

@@ -85,6 +85,7 @@ def rotate_block_rows_planes(xr: jax.Array, xi: jax.Array, shift: jax.Array,
     shape3 = (n_blocks, block_rows, m)
     yr, yi = pl.pallas_call(
         _rotate_kernel,
+        name="croft_rotate_blocks",
         grid_spec=grid_spec,
         out_shape=backend.f32_outputs(shape3, 2, xr, xi),
         interpret=interpret,

@@ -290,30 +290,3 @@ def analyze(hlo: str) -> HloCost:
     for e in entries:
         visit(e, 1.0)
     return cost
-
-
-def analyze_compiled(compiled) -> Optional[HloCost]:
-    """:func:`analyze` on a compiled executable's post-SPMD HLO text;
-    None when the text is unavailable (some backends ship opaque
-    executables)."""
-    try:
-        return analyze(compiled.as_text())
-    except Exception:
-        return None
-
-
-def summarize(cost: Optional[HloCost]) -> dict:
-    """Flat JSON-able view of an :class:`HloCost` (span/report payload):
-    totals plus per-kind collective counts and bytes."""
-    if cost is None:
-        return {}
-    out = {
-        "hlo_flops": cost.flops,
-        "hlo_bytes": cost.bytes,
-        "hlo_collective_bytes": cost.collective_bytes,
-        "hlo_collectives": sum(e["count"] for e in cost.collectives.values()),
-    }
-    for kind, e in sorted(cost.collectives.items()):
-        out[f"hlo_{kind}_count"] = e["count"]
-        out[f"hlo_{kind}_bytes"] = e["bytes"]
-    return out

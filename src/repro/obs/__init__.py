@@ -1,20 +1,20 @@
-"""repro.obs — stage-level tracing, metrics, and overlap attribution.
+"""repro.obs — spans, metrics, and the names on the device program.
 
-Three pieces (ISSUE 7):
+Three pieces:
 
   * :mod:`repro.obs.tracer` — thread-safe span tracer with Chrome-trace
     JSON export (``chrome://tracing`` / Perfetto) and an in-process ring
     buffer; a no-op tracer is the process default so instrumentation is
     zero-cost until :func:`enable` / :func:`tracing` installs a real one.
+    Under :func:`profiler_sink` spans also land in a ``jax.profiler``
+    trace, on the device's clock.
+  * :mod:`repro.obs.scopes` — the ``jax.named_scope`` names every device
+    op of a transform carries (``croft.stage.<name>/k<i>`` and one role:
+    ``croft.dft``, ``croft.relayout``, ``croft.transpose``,
+    ``croft.scale``), which split a device trace by stage and role.
   * :mod:`repro.obs.metrics` — named counters, gauges, and log-bucketed
     histograms with quantile estimation; JSON snapshots and Prometheus
     text exposition.
-  * :mod:`repro.obs.instrument` / :mod:`repro.obs.report` — re-drive a
-    plan's schedule stage by stage with host-side timing shims, attach
-    HLO cost attribution, and join measured per-stage timings against
-    the analytic cost model (``python -m repro.obs.report trace.json``)
-    to produce the overlap-efficiency table the paper's 42–51% hiding
-    claim is about.
 """
 
 from repro.obs.tracer import (  # noqa: F401
@@ -26,6 +26,7 @@ from repro.obs.tracer import (  # noqa: F401
     disable,
     enable,
     get_tracer,
+    profiler_sink,
     set_tracer,
     tag_scope,
     tracing,
@@ -41,7 +42,7 @@ from repro.obs.metrics import (  # noqa: F401
 
 __all__ = [
     "CATEGORIES", "NOOP", "NoopTracer", "Tracer", "current_tags",
-    "disable", "enable", "get_tracer", "set_tracer", "tag_scope",
-    "tracing", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "get_registry", "set_registry",
+    "disable", "enable", "get_tracer", "profiler_sink", "set_tracer",
+    "tag_scope", "tracing", "Counter", "Gauge", "Histogram",
+    "MetricsRegistry", "get_registry", "set_registry",
 ]

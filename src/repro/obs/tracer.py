@@ -1,19 +1,23 @@
 """Span tracer: thread-safe, nestable, Chrome-trace-event export.
 
-The observability contract of the repo (ISSUE 7): every measured claim
-about *where time goes* — the paper's FFT-hides-MPI overlap story, the
-serving layer's queue/dispatch pipeline, the tuner's measurement
-traffic — flows through one tracer so a single ``trace.json`` can be
-dropped into ``chrome://tracing`` / Perfetto and joined against the
-analytic cost model by ``python -m repro.obs.report``.
+The host side of the repo's observability: the serving layer's
+queue/dispatch pipeline, the tuner's measurement traffic and the
+``Croft3D`` entry points emit spans through one tracer, so a single
+``trace.json`` can be dropped into ``chrome://tracing`` / Perfetto.
+
+Under :func:`profiler_sink` every ``span()`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name and args, so the
+span lands in the profiler's own trace (the host plane of the
+``.xplane.pb``), on the device trace's clock.  What the device did
+inside a span is read from the op scopes of :mod:`repro.obs.scopes`.
 
 Design constraints:
 
   * **zero-cost when disabled** — the default tracer is a
     :class:`NoopTracer` whose ``span()`` returns one shared null context
     manager (no allocation per call), and nothing here ever runs inside
-    ``jit`` (enabling tracing cannot change compiled HLO — pinned in
-    tests/test_obs.py);
+    ``jit`` (enabling tracing or the profiler sink cannot change compiled
+    HLO — pinned in tests/test_obs.py);
   * **thread-safe** — the serve worker, plan-cache upgrade threads, and
     client threads emit concurrently into one lock-guarded ring buffer
     (``maxlen`` bounds memory under continuous serving);
@@ -123,6 +127,44 @@ class NoopTracer:
 NOOP = NoopTracer()
 
 
+def _annotation(name: str, cat: str, args: dict):
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, cat=cat, **args)
+
+
+class ProfilerSpans(NoopTracer):
+    """What the no-op tracer becomes under :func:`profiler_sink`: spans
+    go to the profiler alone, nothing is recorded."""
+
+    def span(self, name: str, cat: str = "plan", **args):
+        merged = current_tags()
+        merged.update(args)
+        return _annotation(name, cat, merged)
+
+
+PROFILER_SPANS = ProfilerSpans()
+
+
+class _AnnotatedSpan:
+    """A recorded span that is also a profiler annotation."""
+
+    __slots__ = ("ctx", "ann")
+
+    def __init__(self, ctx, ann):
+        self.ctx = ctx
+        self.ann = ann
+
+    def __enter__(self):
+        self.ann.__enter__()
+        return self.ctx.__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            return self.ctx.__exit__(*exc)
+        finally:
+            self.ann.__exit__(*exc)
+
+
 class _SpanCtx:
     """Context manager recording one complete ("X") span on exit."""
 
@@ -175,7 +217,10 @@ class Tracer:
     def span(self, name: str, cat: str = "plan", **args):
         merged = current_tags()
         merged.update(args)
-        return _SpanCtx(self, name, cat, merged)
+        ctx = _SpanCtx(self, name, cat, merged)
+        if _sink:
+            return _AnnotatedSpan(ctx, _annotation(name, cat, dict(merged)))
+        return ctx
 
     def complete(self, name: str, cat: str, t_start: float, t_end: float,
                  args: Optional[dict] = None) -> None:
@@ -209,8 +254,8 @@ class Tracer:
             self._events.append(ev)
 
     def add_meta(self, key: str, value) -> None:
-        """Attach trace-level metadata (plan descriptions, model
-        predictions) — what ``repro.obs.report`` joins spans against."""
+        """Attach trace-level metadata (plan descriptions, run
+        parameters) to the exported trace."""
         with self._lock:
             self._meta[key] = value
 
@@ -245,6 +290,7 @@ class Tracer:
 
 _tracer: "NoopTracer | Tracer" = NOOP
 _tracer_lock = threading.Lock()
+_sink = False  # profiler_sink() is open
 
 
 def get_tracer():
@@ -252,10 +298,14 @@ def get_tracer():
     return _tracer
 
 
+def _idle():
+    return PROFILER_SPANS if _sink else NOOP
+
+
 def set_tracer(tracer) -> None:
     global _tracer
     with _tracer_lock:
-        _tracer = tracer if tracer is not None else NOOP
+        _tracer = _idle() if tracer is None or tracer is NOOP else tracer
 
 
 def enable(capacity: int = 65536) -> Tracer:
@@ -269,7 +319,30 @@ def enable(capacity: int = 65536) -> Tracer:
 
 
 def disable() -> None:
-    set_tracer(NOOP)
+    set_tracer(None)
+
+
+@contextlib.contextmanager
+def profiler_sink():
+    """Scope in which every ``span()`` also opens a
+    ``jax.profiler.TraceAnnotation`` with the span's name and args, so
+    that a ``jax.profiler`` trace holds it on the device's clock.  With
+    no recording tracer installed, spans go to the profiler alone.
+    ``complete()`` and ``instant()`` keep to the ring buffer: one is
+    recorded after the fact, the other has no extent."""
+    global _tracer, _sink
+    with _tracer_lock:
+        prev = _sink
+        _sink = True
+        if _tracer is NOOP:
+            _tracer = PROFILER_SPANS
+    try:
+        yield
+    finally:
+        with _tracer_lock:
+            _sink = prev
+            if _tracer is PROFILER_SPANS and not _sink:
+                _tracer = NOOP
 
 
 @contextlib.contextmanager
@@ -279,7 +352,7 @@ def tracing(path: Optional[str] = None, capacity: int = 65536):
     trace is saved there.
 
         with obs.tracing("trace.json") as tr:
-            plan.forward(x)           # host-side spans land in tr
+            plan.forward(x)           # the croft.forward span lands in tr
     """
     global _tracer
     with _tracer_lock:

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from repro.core import local_fft
 from repro.core.decomposition import Decomposition
 from repro.core.distributed import FFTOptions, _norm_scale
+from repro.obs import scopes
 from repro.real import packing
 from repro.real.pipeline import (build_packed_forward, build_packed_inverse,
                                  constrain_sharding, packed_irfft3d,
@@ -80,20 +81,27 @@ def local_rfft3d_packed(x: jax.Array, opts: Optional[FFTOptions] = None,
         raise ValueError(f"packed r2c unsupported here: {reason}")
     pair_axis = _choose_pair_axis(nx, ny)
     fold = nz % 2 == 0  # odd Nz has no Nyquist bin; carry all Nh bins
-    c = packing.pack_two(x, pair_axis)
-    C = local_fft.fft_1d(c, -1, -1, impl=opts.stage_impl(0),
-                         plan_cache=opts.plan_cache)
-    S = packing.unpack_two(C, pair_axis, nh=nz // 2 + 1, fold=fold,
-                           use_pallas=opts.stage_impl(0) == "pallas")
-    S = local_fft.fft_1d(S, -2, -1, impl=opts.stage_impl(1),
-                         plan_cache=opts.plan_cache)
-    S = local_fft.fft_1d(S, -3, -1, impl=opts.stage_impl(2),
-                         plan_cache=opts.plan_cache)
+    with scopes.stage("pack+z-rfft"):
+        c = packing.pack_two(x, pair_axis)
+        C = local_fft.fft_1d(c, -1, -1, impl=opts.stage_impl(0),
+                             plan_cache=opts.plan_cache)
+        S = packing.unpack_two(C, pair_axis, nh=nz // 2 + 1, fold=fold,
+                               use_pallas=opts.stage_impl(0) == "pallas")
+    with scopes.stage("y-fft"):
+        S = local_fft.fft_1d(S, -2, -1, impl=opts.stage_impl(1),
+                             plan_cache=opts.plan_cache)
+    with scopes.stage("x-fft"):
+        S = local_fft.fft_1d(S, -3, -1, impl=opts.stage_impl(2),
+                             plan_cache=opts.plan_cache)
     # the fold stays valid under the (linear) y/x transforms; unfold the
     # DC/Nyquist plane once, at the end, like the distributed pipeline
-    y = unfold_dc_plane(S) if fold else S
-    scale = _norm_scale((nx, ny, nz), -1, norm)
-    return y if scale is None else y * jnp.asarray(scale, y.dtype)
+    with scopes.stage("epilogue"):
+        y = unfold_dc_plane(S) if fold else S
+        scale = _norm_scale((nx, ny, nz), -1, norm)
+        if scale is None:
+            return y
+        with jax.named_scope(scopes.SCALE):
+            return y * jnp.asarray(scale, y.dtype)
 
 
 def local_irfft3d_packed(y: jax.Array, nz: int,
@@ -108,17 +116,22 @@ def local_irfft3d_packed(y: jax.Array, nz: int,
         raise ValueError(f"packed c2r unsupported here: {reason}")
     pair_axis = _choose_pair_axis(nx, ny)
     fold = nz % 2 == 0
-    t = fold_dc_plane(y, nz) if fold else y
-    t = local_fft.fft_1d(t, -3, +1, impl=opts.stage_impl(0),
-                         plan_cache=opts.plan_cache)
-    t = local_fft.fft_1d(t, -2, +1, impl=opts.stage_impl(1),
-                         plan_cache=opts.plan_cache)
-    C = packing.repack_halves(t, pair_axis, nz, folded=fold,
-                              use_pallas=opts.stage_impl(2) == "pallas")
-    c = local_fft.fft_1d(C, -1, +1, impl=opts.stage_impl(2),
-                         plan_cache=opts.plan_cache)
-    x = packing.split_pairs(c, pair_axis)
-    return x * jnp.asarray(_norm_scale((nx, ny, nz), +1, norm), x.dtype)
+    with scopes.stage("prologue"):
+        t = fold_dc_plane(y, nz) if fold else y
+    with scopes.stage("x-ifft"):
+        t = local_fft.fft_1d(t, -3, +1, impl=opts.stage_impl(0),
+                             plan_cache=opts.plan_cache)
+    with scopes.stage("y-ifft"):
+        t = local_fft.fft_1d(t, -2, +1, impl=opts.stage_impl(1),
+                             plan_cache=opts.plan_cache)
+    with scopes.stage("repack+z-ifft+split"):
+        C = packing.repack_halves(t, pair_axis, nz, folded=fold,
+                                  use_pallas=opts.stage_impl(2) == "pallas")
+        c = local_fft.fft_1d(C, -1, +1, impl=opts.stage_impl(2),
+                             plan_cache=opts.plan_cache)
+        x = packing.split_pairs(c, pair_axis)
+    with scopes.stage("epilogue"), jax.named_scope(scopes.SCALE):
+        return x * jnp.asarray(_norm_scale((nx, ny, nz), +1, norm), x.dtype)
 
 
 def unsupported_reason(shape: Sequence[int], mesh, decomp,

@@ -45,6 +45,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 
 def complex_dtype_for(real_dtype) -> jnp.dtype:
     """Spectrum dtype for a real input dtype (f32 -> c64, f64 -> c128)."""
@@ -62,6 +64,7 @@ def negate_freq(a: jax.Array, axis: int = -1) -> jax.Array:
     return jnp.roll(jnp.flip(a, axis), 1, axis)
 
 
+@scopes.role(scopes.RELAYOUT)
 def pack_two(x: jax.Array, pair_axis: int) -> jax.Array:
     """Real block -> complex block, halved along ``pair_axis``.
 
@@ -79,6 +82,7 @@ def pack_two(x: jax.Array, pair_axis: int) -> jax.Array:
     return jax.lax.complex(a, b)
 
 
+@scopes.role(scopes.RELAYOUT)
 def unpack_two(C: jax.Array, pair_axis: int, *, nh: Optional[int] = None,
                fold: bool = False, use_pallas: bool = False) -> jax.Array:
     """Split the FFT of a packed block into the two half spectra.
@@ -118,6 +122,7 @@ def unpack_two(C: jax.Array, pair_axis: int, *, nh: Optional[int] = None,
     return jnp.concatenate([A, B], axis=pair_axis)
 
 
+@scopes.role(scopes.RELAYOUT)
 def repack_halves(S: jax.Array, pair_axis: int, nz: int, *,
                   folded: bool = False, use_pallas: bool = False) -> jax.Array:
     """Inverse of :func:`unpack_two`: rebuild the full packed z-spectrum.
@@ -162,6 +167,7 @@ def repack_halves(S: jax.Array, pair_axis: int, nz: int, *,
     return jnp.concatenate(parts, axis=-1)
 
 
+@scopes.role(scopes.RELAYOUT)
 def split_pairs(c: jax.Array, pair_axis: int) -> jax.Array:
     """Complex block -> real block, doubled along ``pair_axis``.
 

@@ -64,6 +64,7 @@ from repro.core.decomposition import Decomposition, _mesh_axis_sizes
 from repro.core.distributed import FFTOptions, _norm_scale
 from repro.core.schedule import (ExtraComm, PackTwo, RepackHalves, Schedule,
                                  SplitPairs, Stage, UnpackTwo, layout_for)
+from repro.obs import scopes
 from repro.real import packing
 
 #: grid dim two real lines are paired along, per decomposition kind
@@ -190,6 +191,7 @@ def build_packed_inverse(decomp: Decomposition, nz: int) -> Schedule:
 # (Nz//2 + 1)-sized axis, done once per transform on a single plane.
 # ---------------------------------------------------------------------------
 
+@scopes.role(scopes.RELAYOUT)
 def unfold_dc_plane(packed: jax.Array) -> jax.Array:
     """Packed (..., Nx, Ny, Nz2) spectrum -> rfftn-style (..., Nx, Ny,
     Nz2 + 1).
@@ -222,6 +224,7 @@ def _hermitian_plane(p: jax.Array) -> jax.Array:
         packing.negate_freq(p, -1), -2)))
 
 
+@scopes.role(scopes.RELAYOUT)
 def fold_dc_plane(y: jax.Array, nz: int) -> jax.Array:
     """Inverse of :func:`unfold_dc_plane`.
 
@@ -256,6 +259,7 @@ def _with_batch_dims(spec, n: int):
     return P(*((None,) * n), *spec)
 
 
+@scopes.role(scopes.TRANSPOSE)
 def constrain_sharding(y: jax.Array, sharding: NamedSharding) -> jax.Array:
     """Reshard ``y``: a sharding constraint under tracing, a device_put
     on concrete arrays (shared by the packed pipeline and core.rfft)."""
@@ -306,7 +310,8 @@ def packed_rfft3d(x: jax.Array, mesh: Mesh, decomp: Decomposition,
         # h(kz=0) == h(kz=Nyquist) with that plane real and 2-D-even
         # (h[kx,ky] == h[-kx,-ky]); the filter's own Nyquist plane is
         # never read (and gets a zero cotangent under differentiation)
-        hp = kspace_filter[..., : x.shape[-1] // 2].astype(cdtype)
+        with jax.named_scope(scopes.RELAYOUT):
+            hp = kspace_filter[..., : x.shape[-1] // 2].astype(cdtype)
         plan = grad_vjp.packed_rfft_folded_plan(mesh, decomp, opts, scale,
                                                 nbatch, hp.ndim - 3)
         return plan(x, hp)

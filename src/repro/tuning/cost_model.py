@@ -328,12 +328,12 @@ def _searched_schedule_cost(shape: Sequence[int], cand, sched: Schedule,
     The legacy formula prices the whole schedule with one global
     ``max(busy, collective)`` — fine for homogeneous knobs, but it can
     hide a stage that *cannot* overlap (chunk-indivisible alltoall)
-    under another stage's compute, which the per-stage measurements
-    (``repro.obs.report``) show is not physical.  Searched schedules mix
-    impls and K per stage, so each stage's overlap is priced against its
-    OWN legs — the same decomposition :func:`per_stage_costs` reports —
-    and the stage times sum.  Fixed-builder candidates keep the legacy
-    combine so existing rankings and pins are bit-identical.
+    under another stage's compute, which is not physical.  Searched
+    schedules mix impls and K per stage, so each stage's overlap is
+    priced against its OWN legs — the same decomposition
+    :func:`per_stage_costs` reports — and the stage times sum.
+    Fixed-builder candidates keep the legacy combine so existing
+    rankings and pins are bit-identical.
     """
     from repro.core.schedule import _flat, stage_transpose_impl
     opts = cand.opts
@@ -430,8 +430,8 @@ def predicted_collectives(sched: Schedule, shape: Sequence[int],
 def per_stage_costs(shape: Sequence[int], cand: Candidate,
                     axis_sizes: Mapping[str, int],
                     dtype=jnp.complex64, batch: int = 1) -> list:
-    """Modeled per-stage compute/collective split — what the traced
-    per-stage timings (``repro.obs.instrument``) are joined against.
+    """Modeled per-stage compute/collective split, the stage by stage
+    counterpart of a chip trace's ``croft.stage.*`` device time.
 
     One row per schedule stage (plus one per out-of-body reshard), using
     the same conventions as :func:`analytic_cost`: FFT flops at the

@@ -7,8 +7,8 @@ The three load-bearing claims of the observability subsystem:
   * histogram quantiles are honest (pinned against numpy within the
     log-bucket growth factor; exact for explicit-bounds histograms);
   * instrumentation is zero-cost when disabled — enabling the tracer
-    must not change compiled HLO (pinned byte-for-byte in an
-    8-virtual-device subprocess).
+    and its profiler sink must not change compiled HLO (pinned
+    byte-for-byte in an 8-virtual-device subprocess).
 """
 
 import json
@@ -313,18 +313,56 @@ def test_service_stats_shape_unchanged_without_tracing():
     assert stats["requests"] == 1 and stats["pending"] == 0
 
 
-# --- zero-cost + attribution (8 virtual devices) ----------------------------
+# --- profiler sink ----------------------------------------------------------
 
-def test_hlo_identical_with_tracing_and_attribution_reports():
-    """The acceptance pin: enabling the tracer changes NOTHING in the
-    compiled HLO (byte-identical), traced execution matches production
-    output, and the report renders overlap efficiency for the
-    alltoall-K2 and ring-K1 acceptance plans."""
+def test_profiler_sink_swaps_the_noop_tracer_and_restores_it():
+    assert obs.get_tracer() is obs.NOOP
+    with obs.profiler_sink():
+        tr = obs.get_tracer()
+        assert tr is tracer_lib.PROFILER_SPANS and not tr.enabled
+        with tr.span("croft.forward", "plan", rows=2):
+            pass
+        assert tr.events() == []
+        obs.disable()                      # the sink outlives disable()
+        assert obs.get_tracer() is tracer_lib.PROFILER_SPANS
+    assert obs.get_tracer() is obs.NOOP
+
+
+def test_recording_tracer_under_the_sink_still_records(tracer):
+    with obs.profiler_sink():
+        with tracer.span("outer", "plan", k=1) as sp:
+            sp.set(done=True)
+    with tracer.span("after", "plan"):
+        pass
+    evs = {e["name"]: e for e in tracer.events()}
+    assert set(evs) == {"outer", "after"}
+    assert evs["outer"]["args"] == {"k": 1, "done": True}
+
+
+def test_croft_entries_open_spans(tracer):
+    from repro.core import Croft3D
+    plan = Croft3D((8, 8, 8), problem="r2c", strategy="packed")
+    x = np.ones((8, 8, 8), np.float32)
+    h = np.ones(plan.spectrum_shape, np.complex64)
+    plan.forward(x)
+    plan.inverse(plan.forward_filtered(x, h))
+    plan.forward_batched(np.stack([x, x]))
+    names = [e["name"] for e in tracer.events()]
+    assert names == ["croft.forward", "croft.forward_filtered",
+                     "croft.inverse", "croft.forward_batched"]
+
+
+# --- zero-cost (8 virtual devices) ------------------------------------------
+
+def test_hlo_identical_with_tracer_and_profiler_sink():
+    """The acceptance pin: a recording tracer with the profiler sink on
+    changes NOTHING in the compiled HLO (byte-identical), the traced
+    calls return the production output, and every entry opened its
+    span."""
     run_multidevice("""
-import json, numpy as np, jax, jax.numpy as jnp
+import numpy as np, jax, jax.numpy as jnp
 from repro import obs
 from repro.core import Croft3D, Decomposition, FFTOptions
-from repro.obs import instrument, report as report_lib
 from repro.tuning.measure import _random_input
 from repro.launch.mesh import make_mesh
 
@@ -338,35 +376,28 @@ plans = {
                        FFTOptions(overlap_k=1, transpose_impl="ring",
                                   output_layout="spectral")),
 }
+entries = ("forward", "inverse", "forward_filtered")
 
-# HLO pin: compile before enabling, then again with tracing live
-hlo_off = {k: p.lower_forward().compile().as_text() for k, p in plans.items()}
-tracer = obs.enable()
-summaries = {}
-for label, plan in plans.items():
-    x = _random_input((N, N, N), jnp.complex64, plan.input_sharding)
-    y, summary = instrument.trace_forward(plan, x, tracer=tracer, iters=2,
-                                          label=label)
-    np.testing.assert_allclose(np.asarray(jax.device_get(y)),
-                               np.asarray(jax.device_get(plan.forward(x))),
-                               rtol=2e-4, atol=2e-4)
-    summaries[label] = summary
-hlo_on = {k: p.lower_forward().compile().as_text() for k, p in plans.items()}
-for label in plans:
-    assert hlo_on[label] == hlo_off[label], (
-        label + ": tracing changed the compiled HLO")
+def texts():
+    return {(k, e): p.lower(e).compile().as_text()
+            for k, p in plans.items() for e in entries}
 
-for label, s in summaries.items():
-    assert s["overall"] is not None, label
-    assert 0.0 <= s["overall"]["efficiency"] <= 1.0
-    n_comm = sum(1 for row in s["stages"] if row["comm_s"] > 0)
-    assert n_comm == 2, label  # pencil: two transposed stages
-    for row in s["stages"]:
-        assert row["model"] is not None  # joined against per_stage_costs
-        assert row["hlo"].get("hlo_collectives", 0) >= 0
-
-table = report_lib.render_plan(summaries["ring-k1"])
-assert "overlap efficiency" in table and "ring-k1" in table
-obs.disable()
+hlo_off = texts()
+xs = {k: _random_input((N, N, N), jnp.complex64, p.input_sharding)
+      for k, p in plans.items()}
+want = {k: np.asarray(jax.device_get(p.forward(xs[k])))
+        for k, p in plans.items()}
+with obs.tracing() as tracer, obs.profiler_sink():
+    with jax.profiler.TraceAnnotation("outer"):
+        got = {k: np.asarray(jax.device_get(p.forward(xs[k])))
+               for k, p in plans.items()}
+    hlo_on = texts()
+for key in hlo_off:
+    assert hlo_on[key] == hlo_off[key], (
+        str(key) + ": tracing changed the compiled HLO")
+for k in plans:
+    np.testing.assert_array_equal(got[k], want[k])
+assert [e["name"] for e in tracer.events()] == ["croft.forward"] * 2
+assert obs.get_tracer() is obs.NOOP
 print("OK")
 """, n_devices=8)

@@ -134,3 +134,39 @@ def test_pencil_ring_forward_compiles_on_2x2(tpu):
     assert "tpu_custom_call" in compiled.as_text()
     local = 256 ** 3 * 8 // 4
     assert compiled.memory_analysis().argument_size_in_bytes == local
+
+
+# every kernel carries its own name into the program: the device trace
+# shows it as the custom call's instruction name
+@pytest.mark.parametrize("name", [
+    "croft_spectral_scale", "croft_spectral_scale_full",
+    "croft_hermitian_unpack", "croft_hermitian_extend", "croft_fft_dense",
+    "croft_fft4step", "croft_rotate_blocks", "flash_attention"])
+def test_kernels_carry_their_names(one_chip, name):
+    planes = lambda shape, k: _planes(one_chip, shape, k)  # noqa: E731
+    shift = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    q = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    fn, specs = {
+        "croft_spectral_scale": (
+            spectral_scale.spectral_scale_planes,
+            planes((64, 128), 2) + planes((128,), 2)),
+        "croft_spectral_scale_full": (
+            spectral_scale.spectral_scale_planes_full,
+            planes((64, 128), 4)),
+        "croft_hermitian_unpack": (hermitian.unpack_two_for_one_planes,
+                                   planes((64, 128), 2)),
+        "croft_hermitian_extend": (hermitian.hermitian_extend_planes,
+                                   planes((64, 64), 4)),
+        "croft_fft_dense": (lambda a, b: fft_matmul.fft4step_planes(a, b),
+                            planes((64, 64), 2)),
+        "croft_fft4step": (lambda a, b: fft_matmul.fft4step_planes(a, b),
+                           planes((64, 1024), 2)),
+        "croft_rotate_blocks": (
+            lambda a, b, s: transpose_pack.rotate_block_rows_planes(
+                a, b, s, 2), planes((64, 128), 2) + [shift]),
+        "flash_attention": (flash_attention.flash_attention, [q, q, q]),
+    }[name]
+    text = jax.jit(fn).lower(*specs).as_text()
+    assert "tpu_custom_call" in text
+    assert f'kernel_name = "{name}"' in text
