@@ -294,9 +294,11 @@ def phase_kernels(*, n: int = 1024, rows: int = 4096) -> dict:
 
     with CompileLog() as log:
         x = random_field((rows, n), 1)
+        xp = np.stack([x.real, x.imag])  # the executor's stacked planes
         run("rotate_block_rows", lambda a: transpose_pack.rotate_blocks(
-            a, 0, 1, 2, use_pallas=True), (jnp.asarray(x),),
-            np.concatenate([x[rows // 2:], x[:rows // 2]]), TOL_ELEMENTWISE)
+            a, 1, 1, 2, use_pallas=True), (jnp.asarray(xp),),
+            np.concatenate([xp[:, rows // 2:], xp[:, :rows // 2]], axis=1),
+            TOL_ELEMENTWISE)
         c = jnp.asarray(random_field((rows // 2, 2, n), 2))
         half = packing.unpack_two(c, 1, fold=True, use_pallas=False)
         run("hermitian_unpack", lambda a: packing.unpack_two(

@@ -287,10 +287,12 @@ class Croft3D:
     # -- batched dispatch (the serving path) ---------------------------------
     #
     # One executable per (plan, batch-size-bucket) moving B stacked fields
-    # through the SAME collective count as B=1: the packed r2c pipeline
-    # takes leading batch axes natively (its executor offsets every axis
-    # index by the batch rank), everything else vmaps — under vmap the
-    # per-stage all_to_alls batch into single collectives.  The c2c
+    # through the SAME collective count as B=1.  c2c and the packed r2c
+    # pipeline take leading batch axes natively: the executor offsets
+    # every axis index by the batch rank and runs each field's DFT as one
+    # batch entry of the contraction a lone field runs, so a field comes
+    # out of a batch as it does alone.  The rest vmaps (under vmap the
+    # per-stage all_to_alls batch into single collectives).  The c2c
     # entries donate the stacked input buffer (complex in, complex out,
     # same shape: XLA aliases it for the first stage's scratch).
 
@@ -302,41 +304,26 @@ class Croft3D:
         fn = self._batched.get(kind)
         if fn is not None:
             return fn
-        native_packed = self.problem == "r2c" and self.strategy == "packed"
-        donate = (0,) if self.problem == "c2c" else ()
+        c2c = self.problem == "c2c"
+        native = c2c or self.strategy == "packed"
+        donate = (0,) if c2c else ()
         if kind == "forward":
-            if native_packed:
-                from repro.core import rfft
-                strat = self.strategy
+            one = self._fwd
 
-                def croft_forward_batched(v):
-                    return rfft.rfft3d(v, self.mesh, self.decomp, self.opts,
-                                       strategy=strat)
-            else:
-                one = self._fwd
-
-                def croft_forward_batched(v):
-                    return jax.vmap(one)(v)
+            def croft_forward_batched(v):
+                return one(v) if native else jax.vmap(one)(v)
             fn = jax.jit(croft_forward_batched, donate_argnums=donate)
         elif kind == "inverse":
-            if native_packed:
-                from repro.core import rfft
-                strat, nz = self.strategy, self.shape[-1]
+            one = self._inv
 
-                def croft_inverse_batched(v):
-                    return rfft.irfft3d(v, nz, self.mesh, self.decomp,
-                                        self.opts, strategy=strat)
-            else:
-                one = self._inv
-
-                def croft_inverse_batched(v):
-                    return jax.vmap(one)(v)
+            def croft_inverse_batched(v):
+                return one(v) if native else jax.vmap(one)(v)
             fn = jax.jit(croft_inverse_batched, donate_argnums=donate)
         elif kind == "filtered":
             one = self._filtered_fn()
 
             def croft_forward_filtered_batched(v, hh):
-                return jax.vmap(one)(v, hh)
+                return one(v, hh) if c2c else jax.vmap(one)(v, hh)
             fn = jax.jit(croft_forward_filtered_batched,
                          donate_argnums=donate)
         else:

@@ -64,7 +64,6 @@ AxisName = Union[str, tuple]
 # addressable here (models/ and older call sites import them from this module)
 _axis_size = schedule_lib._axis_size
 _all_to_all = schedule_lib._all_to_all
-_fft_along = schedule_lib._fft_along
 
 
 @dataclasses.dataclass(frozen=True)
@@ -212,7 +211,9 @@ def _stage(blk: jax.Array, *, fft_axis: Optional[int], comm_axis: Optional[AxisN
     st = schedule_lib.Stage("ad-hoc", fft_axis=fft_axis, comm_axis=comm_axis,
                             split_axis=split_axis, concat_axis=concat_axis,
                             chunk_axis=chunk_axis, impl_stage=stage)
-    return schedule_lib.run_stage(blk, st, sign, opts)
+    out = schedule_lib.run_stage(local_fft.to_planes(blk), st, sign, opts,
+                                 off=1)
+    return local_fft.from_planes(out)
 
 
 # ---------------------------------------------------------------------------
@@ -275,14 +276,22 @@ def scheduled_fft3d(x: jax.Array, mesh: Mesh,
     """
     if opts is None:
         opts = FFTOptions()
-    if x.ndim != 3:
-        raise ValueError("scheduled_fft3d expects a rank-3 (Nx,Ny,Nz) array; "
-                         "vmap for batches")
+    if x.ndim < 3:
+        raise ValueError("scheduled_fft3d expects a (..., Nx, Ny, Nz) array")
     scale = _norm_scale(x.shape, sched.sign, norm)
+    return _run_plan(x, mesh, sched, opts, scale, kspace_filter)
+
+
+def _run_plan(x, mesh, sched, opts, scale, kspace_filter):
+    """The plan-cached, vjp-routed run of ``sched`` on ``x``, whose axes
+    before the last three are independent fields carried through the
+    schedule (one collective per transpose for all of them)."""
     from repro.grad import vjp as grad_vjp
+    nbatch = x.ndim - 3
     if kspace_filter is None:
-        return grad_vjp.linear_plan(mesh, sched, opts, scale).apply(x)
-    plan = grad_vjp.filtered_plan(mesh, sched, opts, scale)
+        return grad_vjp.linear_plan(mesh, sched, opts, scale,
+                                    nbatch).apply(x)
+    plan = grad_vjp.filtered_plan(mesh, sched, opts, scale, nbatch)
     return plan(x, kspace_filter.astype(x.dtype))
 
 
@@ -290,7 +299,8 @@ def distributed_fft3d(x: jax.Array, mesh: Mesh, decomp: Decomposition,
                       sign: int = -1, opts: Optional[FFTOptions] = None,
                       norm: Optional[str] = None,
                       kspace_filter: Optional[jax.Array] = None) -> jax.Array:
-    """3-D FFT of a globally-sharded (Nx, Ny, Nz) array.
+    """3-D FFT of a globally-sharded (..., Nx, Ny, Nz) array; leading axes
+    are independent fields, unsharded, carried natively through one run.
 
     Builds the decomposition's :class:`~repro.core.schedule.Schedule` and
     runs it under ``shard_map``; in/out shardings come from the schedule's
@@ -300,9 +310,8 @@ def distributed_fft3d(x: jax.Array, mesh: Mesh, decomp: Decomposition,
     """
     if opts is None:
         opts = FFTOptions()
-    if x.ndim != 3:
-        raise ValueError("distributed_fft3d expects a rank-3 (Nx,Ny,Nz) array; "
-                         "vmap for batches")
+    if x.ndim < 3:
+        raise ValueError("distributed_fft3d expects a (..., Nx, Ny, Nz) array")
     decomp.validate(x.shape, mesh, opts.overlap_k, opts.transpose_impl)
 
     sched = build_schedule(decomp, opts, sign)
@@ -313,11 +322,7 @@ def distributed_fft3d(x: jax.Array, mesh: Mesh, decomp: Decomposition,
     # route through repro.grad so jax.grad runs the adjoint schedule
     # instead of XLA differentiating the shard_map body; primal ops are
     # identical to running the schedule directly
-    from repro.grad import vjp as grad_vjp
-    if kspace_filter is None:
-        return grad_vjp.linear_plan(mesh, sched, opts, scale).apply(x)
-    plan = grad_vjp.filtered_plan(mesh, sched, opts, scale)
-    return plan(x, kspace_filter.astype(x.dtype))
+    return _run_plan(x, mesh, sched, opts, scale, kspace_filter)
 
 
 def fft3d(x, mesh=None, decomp=None, opts: Optional[FFTOptions] = None,

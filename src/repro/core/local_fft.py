@@ -13,6 +13,11 @@ equivalent is the four-step (Bailey) factorization applied as MXU matmuls
 All operate along the *last* axis; callers move axes.  Forward sign=-1,
 inverse sign=+1 unnormalized (normalization applied at the 3-D level, eq. (2)
 of the paper).
+
+The schedule executor (``core/schedule.run_schedule``) carries its block
+as stacked real/imaginary planes instead (:func:`to_planes`), and runs
+``matmul`` stages with :func:`fft_planes`: the same four-step, one real
+contraction per stage along the axis where it lies.
 """
 
 from __future__ import annotations
@@ -84,6 +89,92 @@ def fft_matmul(x: jax.Array, sign: int = -1, *, plan_cache: bool = True,
     with jax.named_scope(scopes.RELAYOUT):
         z = jnp.swapaxes(z, -1, -2)
         return z.reshape(batch + (n,))
+
+
+@scopes.role(scopes.RELAYOUT)
+def to_planes(x: jax.Array) -> jax.Array:
+    """Complex (...) -> real planes (2, ...): ``[Re x, Im x]`` stacked on
+    a new leading axis.  A real array gets a plane axis of size 1."""
+    if jnp.iscomplexobj(x):
+        return jnp.stack([jnp.real(x), jnp.imag(x)])
+    return x[None]
+
+
+@scopes.role(scopes.RELAYOUT)
+def from_planes(p: jax.Array) -> jax.Array:
+    """Inverse of :func:`to_planes`."""
+    if p.shape[0] == 1:
+        return p[0]
+    return jax.lax.complex(p[0], p[1])
+
+
+@scopes.role(scopes.DFT)
+def _contract(spec: str, p: jax.Array, w: jax.Array,
+              batch: str = "") -> jax.Array:
+    """``einsum(spec)`` as one dot_general in its natural output order
+    (batch, lhs free, rhs free: W's output dims minor), then the
+    transpose to ``spec``'s order, which the TPU compiler folds into the
+    dot's layout.  ``batch`` names leading dims of ``p`` (after the
+    planes) that become dot batch dims, W broadcast over them.  So the
+    dot's rows are one field's pencils, W's columns stay minor, and a
+    K-chunk or a batch of fields changes only the row count or the batch
+    count: the CPU backend, which picks its GEMM by size, then rounds
+    each chunk and each field as it rounds the whole or the one field."""
+    ins, out = spec.split("->")
+    lhs, rhs = ins.split(",")
+    if batch:
+        w = jnp.broadcast_to(w, p.shape[1:1 + len(batch)] + w.shape)
+        rhs = batch + rhs
+    lead = [a for a in rhs if a in lhs and a in out]
+    nat = "".join(lead + [a for a in lhs if a in out and a not in lead]
+                  + [a for a in rhs if a in out and a not in lead])
+    y = jnp.einsum(f"{lhs},{rhs}->{nat}", p, w,
+                   precision=jax.lax.Precision.HIGHEST)
+    return jnp.transpose(y, [nat.index(a) for a in out])
+
+
+def fft_planes(p: jax.Array, axis: int, sign: int = -1, *, nbatch: int = 0,
+               plan_cache: bool = True) -> jax.Array:
+    """Four-step FFT along ``axis`` of stacked planes ``p`` (2, ...).
+
+    Each stage is one real contraction of (plane, input index) against a
+    stacked-real matrix (``FFTPlan.planes``), on the axis where it lies:
+    no axis moves.  With ``n = n1 * n2`` (``plan.split_factors``) the
+    axis is viewed as (j1, j2), ``j = n2*j1 + j2``:
+
+    stage 1  contract (plane, j1) with the n1-point DFT, the twiddles of
+             each j2 folded into its matrix: a dot batched over j2
+    stage 2  contract (plane, j2) with the n2-point DFT, output laid out
+             (k2, k1), so ``k = k1 + n1*k2`` is natural order
+
+    ``n <= plan.MAX_RADIX`` is one contraction; ``n2 > MAX_RADIX``
+    recurses along j2.  The ``nbatch`` dims after the planes are independent
+    fields (the executor's leading batch axes): dot batch dims.
+    """
+    n = p.shape[axis]
+    cname = "complex128" if p.dtype == jnp.float64 else "complex64"
+    plan = plan_lib.make_plan(n, sign, cname)
+    with jax.named_scope(scopes.DFT):
+        w1, w2 = plan.planes_jnp(rematerialize=not plan_cache)
+    lead = "ABCDEFGHIJKLMNOPQRSTUVWXYZ"[:p.ndim - 1]  # dims past the planes
+    pre, post, bat = lead[:axis - 1], lead[axis:], lead[:nbatch]
+    if plan.n2 == 1:
+        return _contract(f"c{pre}j{post},cjdk->d{pre}k{post}", p, w1, bat)
+    n1, n2 = plan.n1, plan.n2
+    with jax.named_scope(scopes.RELAYOUT):
+        p = p.reshape(p.shape[:axis] + (n1, n2) + p.shape[axis + 1:])
+    # stage 1: (plane, j1) -> (plane, k1), batched over j2
+    y = _contract(f"c{pre}jt{post},tcjdk->d{pre}kt{post}", p, w1, bat)
+    if w2 is not None:
+        # stage 2: (plane, j2) -> (plane, k2), laid out (k2, k1)
+        z = _contract(f"c{pre}kt{post},ctds->d{pre}sk{post}", y, w2, bat)
+    else:
+        z = fft_planes(y, axis + 1, sign, nbatch=nbatch,
+                       plan_cache=plan_cache)
+        with jax.named_scope(scopes.RELAYOUT):
+            z = jnp.swapaxes(z, axis, axis + 1)
+    with jax.named_scope(scopes.RELAYOUT):
+        return z.reshape(z.shape[:axis] + (n,) + z.shape[axis + 2:])
 
 
 @scopes.role(scopes.DFT)

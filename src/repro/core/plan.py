@@ -22,8 +22,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# Largest DFT applied as a single matmul.  64 keeps the stacked-real complex
-# matmul at exactly 128x128 — one MXU tile on TPU.
+# Largest DFT applied as a single matmul.  In the schedule executor's
+# stacked real/imag planes form (``FFTPlan.planes``) a 64-point stage is
+# one real 128x128 contraction, one MXU tile on TPU; the complex einsums
+# of ``local_fft.fft_matmul`` (the local paths) never use that form.
 MAX_RADIX = 64
 
 
@@ -63,6 +65,34 @@ def twiddle_matrix(n1: int, n2: int, sign: int, dtype=np.complex64) -> np.ndarra
     k1 = np.arange(n1)
     j2 = np.arange(n2)
     return np.exp(sign * 2j * np.pi * np.outer(j2, k1) / (n1 * n2)).astype(dtype)
+
+
+def planes_matrix(wr, wi, xp=np):
+    """Real and imaginary parts of complex matrices (..., n, m) -> the
+    stacked-real (..., 2, n, 2, m) operator ``W[c, j, d, k]`` that maps
+    stacked planes ``x[c, j]`` (c = 0 real, 1 imaginary) to ``y[d, k]``:
+    ``yr = xr @ wr - xi @ wi``, ``yi = xr @ wi + xi @ wr``.  ``xp`` is
+    numpy (planned constants) or jax.numpy (rebuilt at run time)."""
+    return xp.stack([xp.stack([wr, wi], -2), xp.stack([-wi, wr], -2)], -4)
+
+
+def planes_angles(n1: int, n2: int, sign: int, xp=np, ftype=np.float64):
+    """Phase angles of the planes four-step's stage matrices, ``2*pi*sign``
+    times: stage 1 is the n1-point DFT with the twiddles of each j2
+    folded in, ``A1[j2, j1, k1] = j1*k1/n1 + j2*k1/(n1*n2)`` (shape
+    (n2, n1, n1)), stage 2 the n2-point DFT ``A2[j2, k2] = j2*k2/n2``.
+    With ``n2 == 1`` stage 1 is the whole DFT, shape (n1, n1).  Integer
+    products are reduced modulo the length before the division, so the
+    phases stay exact in ``ftype``."""
+    j1 = xp.arange(n1)
+    a1 = (xp.outer(j1, j1) % n1).astype(ftype) / n1
+    if n2 == 1:
+        return 2 * np.pi * sign * a1, None
+    j2 = xp.arange(n2)
+    tw = xp.outer(j2, j1).astype(ftype) / (n1 * n2)          # [j2, k1]
+    a2 = (xp.outer(j2, j2) % n2).astype(ftype) / n2
+    return (2 * np.pi * sign * (a1[None] + tw[:, None, :]),
+            2 * np.pi * sign * a2)
 
 
 def stacked_real(w: np.ndarray) -> np.ndarray:
@@ -123,6 +153,34 @@ class FFTPlan:
         else:
             tw = None
         return w1, w2, tw
+
+    def _planes(self, xp, ftype):
+        a1, a2 = planes_angles(self.n1, self.n2, self.sign, xp, ftype)
+        p1 = planes_matrix(xp.cos(a1), xp.sin(a1), xp)
+        if self.w2 is None:
+            return p1, None
+        return p1, planes_matrix(xp.cos(a2), xp.sin(a2), xp)
+
+    @functools.cached_property
+    def planes(self):
+        """(p1, p2): the stacked-real stage matrices of the planes
+        four-step (:func:`planes_matrix` of :func:`planes_angles`), built
+        in float64 and rounded once to the plan's real dtype.  ``p1`` is
+        (n2, 2, n1, 2, n1), the twiddles folded in, or (2, n, 2, n) when
+        ``n2 == 1``; ``p2`` is (2, n2, 2, n2), or None with one stage or
+        ``n2 > max_radix`` (``local_fft.fft_planes`` then recurses)."""
+        rtype = np.finfo(self.dtype).dtype
+        return tuple(None if p is None else p.astype(rtype)
+                     for p in self._planes(np, np.float64))
+
+    def planes_jnp(self, rematerialize: bool = False):
+        """:attr:`planes` as jnp arrays; with ``rematerialize=True`` they
+        are recomputed with runtime ops on every call (the "multiple
+        plans" mode of :meth:`constants_jnp`)."""
+        if rematerialize:
+            return self._planes(jnp, np.finfo(self.dtype).dtype)
+        return tuple(None if p is None else jnp.asarray(p)
+                     for p in self.planes)
 
 
 @functools.lru_cache(maxsize=256)

@@ -27,6 +27,13 @@ module does the same for the JAX port:
                  chunk-indivisible fallback (``effective_k``), per-stage
                  ``local_impl`` selection, and batch-axis offsetting
                  (leading unsharded batch dims shift every axis index).
+                 It carries the block as stacked real/imaginary planes
+                 (``local_fft.to_planes``: a leading plane axis of size
+                 2, or 1 for a real block), so a transpose moves both
+                 planes in one collective and a ``matmul`` FFT stage is
+                 one real contraction (``local_fft.fft_planes``).  Stage
+                 ops take that form; an op with no planes form converts
+                 at the op (:func:`complex_form`).
 
 Builders are pure functions ``Decomposition x problem x layout ->
 Schedule``: :func:`build_c2c` here covers every complex pipeline
@@ -40,6 +47,7 @@ scoring and execution can never drift apart.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Sequence, Union
 
@@ -197,7 +205,9 @@ def layout_for(decomp, which: str = "natural", real: bool = False) -> Layout:
 class StageOp:
     """Protocol for prologue/epilogue ops.
 
-    ``apply`` runs inside the executor (per K-chunk for chunked stages);
+    ``apply`` runs inside the executor (per K-chunk for chunked stages)
+    on its planes block, axis indices offset by ``off`` (the plane axis
+    and any batch axes);
     ``transform`` propagates the symbolic layout; ``describe`` renders the
     op for golden snapshots.  Heavy imports happen lazily inside ``apply``
     so the IR stays importable from anywhere (core <-> real <-> kernels).
@@ -213,6 +223,17 @@ class StageOp:
         return type(self).__name__
 
 
+def complex_form(apply):
+    """Decorator for the ``apply`` of a stage op written for complex (or
+    plain real) blocks: the executor's planes convert to that form at the
+    op and back after it, under ``croft.relayout``."""
+    @functools.wraps(apply)
+    def on_planes(self, blk, opts, ctx, off):
+        out = apply(self, local_fft.from_planes(blk), opts, ctx, off - 1)
+        return local_fft.to_planes(out)
+    return on_planes
+
+
 @dataclasses.dataclass(frozen=True)
 class PackTwo(StageOp):
     """Pair two real pencils along ``pair_axis`` into one complex block."""
@@ -221,7 +242,7 @@ class PackTwo(StageOp):
 
     def apply(self, blk, opts, ctx, off):
         from repro.real import packing
-        return packing.pack_two(blk, self.pair_axis + off)
+        return packing.pack_two_planes(blk, self.pair_axis + off)
 
     def transform(self, layout):
         if not layout.real:
@@ -245,8 +266,8 @@ class UnpackTwo(StageOp):
     def apply(self, blk, opts, ctx, off):
         from repro.real import packing
         use_pallas = opts.stage_impl(self.impl_stage) == "pallas"
-        return packing.unpack_two(blk, self.pair_axis + off, fold=True,
-                                  use_pallas=use_pallas)
+        return packing.unpack_two_planes(blk, self.pair_axis + off,
+                                         use_pallas=use_pallas)
 
     def transform(self, layout):
         return layout.with_den(self.pair_axis, div=2).with_den(
@@ -268,8 +289,8 @@ class RepackHalves(StageOp):
     def apply(self, blk, opts, ctx, off):
         from repro.real import packing
         use_pallas = opts.stage_impl(self.impl_stage) == "pallas"
-        return packing.repack_halves(blk, self.pair_axis + off, self.nz,
-                                     folded=True, use_pallas=use_pallas)
+        return packing.repack_halves_planes(blk, self.pair_axis + off,
+                                            self.nz, use_pallas=use_pallas)
 
     def transform(self, layout):
         return layout.with_den(self.pair_axis, mul=2).with_den(
@@ -287,7 +308,7 @@ class SplitPairs(StageOp):
 
     def apply(self, blk, opts, ctx, off):
         from repro.real import packing
-        return packing.split_pairs(blk, self.pair_axis + off)
+        return packing.split_pairs_planes(blk, self.pair_axis + off)
 
     def transform(self, layout):
         if layout.real:
@@ -317,7 +338,7 @@ class SpectralScale(StageOp):
                 f"schedule epilogue needs operand {self.key!r}; pass it via "
                 "run_schedule(..., operands={...})")
         from repro.kernels import spectral_scale as ss
-        return ss.spectral_scale(blk, ctx[self.key], self.alpha)
+        return ss.spectral_scale_stacked(blk, ctx[self.key], self.alpha)
 
     def describe(self):
         return f"kscale[{self.key}]"
@@ -537,9 +558,17 @@ class Schedule:
 # ---------------------------------------------------------------------------
 
 def _fft_along(blk: jax.Array, axis: int, sign: int, opts,
-               stage: int = 0) -> jax.Array:
-    return local_fft.fft_1d(blk, axis, sign, impl=opts.stage_impl(stage),
-                            plan_cache=opts.plan_cache)
+               stage: int = 0, nbatch: int = 0) -> jax.Array:
+    """1-D FFT along ``axis`` of a planes block with ``nbatch`` leading
+    batch axes: the planes four-step for ``matmul``; the other impls
+    convert at the op."""
+    impl = opts.stage_impl(stage)
+    if impl == "matmul":
+        return local_fft.fft_planes(blk, axis, sign, nbatch=nbatch,
+                                    plan_cache=opts.plan_cache)
+    y = local_fft.fft_1d(local_fft.from_planes(blk), axis - 1, sign,
+                         impl=impl, plan_cache=opts.plan_cache)
+    return local_fft.to_planes(y)
 
 
 @scopes.role(scopes.RELAYOUT)
@@ -650,7 +679,7 @@ def _all_to_all(blk: jax.Array, axis: AxisName, split_axis: int,
     return _pairwise_transpose(blk, axis, split_axis, concat_axis)
 
 
-def stage_pre(blk: jax.Array, st: Stage, sign: int, opts, off: int = 0,
+def stage_pre(blk: jax.Array, st: Stage, sign: int, opts, off: int = 1,
               ctx=None) -> jax.Array:
     """The compute leg of one stage: prologue ops -> local FFT ->
     epilogue ops, on one (chunk of a) local block."""
@@ -658,13 +687,14 @@ def stage_pre(blk: jax.Array, st: Stage, sign: int, opts, off: int = 0,
     for op in st.prologue:
         blk = op.apply(blk, opts, ctx, off)
     if st.fft_axis is not None:
-        blk = _fft_along(blk, st.fft_axis + off, sign, opts, st.impl_stage)
+        blk = _fft_along(blk, st.fft_axis + off, sign, opts, st.impl_stage,
+                         off - 1)
     for op in st.epilogue:
         blk = op.apply(blk, opts, ctx, off)
     return blk
 
 
-def stage_comm(blk: jax.Array, st: Stage, opts, off: int = 0) -> jax.Array:
+def stage_comm(blk: jax.Array, st: Stage, opts, off: int = 1) -> jax.Array:
     """The collective leg of one stage (the global transpose); the
     counterpart of :func:`stage_pre`."""
     return _all_to_all(blk, st.comm_axis, st.split_axis + off,
@@ -682,11 +712,12 @@ def stage_category(st: Stage) -> str:
     return "unpack" if st.epilogue else "epilogue"
 
 
-def run_stage(blk: jax.Array, st: Stage, sign: int, opts, off: int = 0,
+def run_stage(blk: jax.Array, st: Stage, sign: int, opts, off: int = 1,
               ctx=None) -> jax.Array:
-    """Execute one stage on a local block (axis indices offset by ``off``
-    for leading batch dims).  Owns the K-chunked overlap and the silent
-    fallback to one chunk when ``chunk_axis`` is not divisible by K.
+    """Execute one stage on a local planes block (axis indices offset by
+    ``off``: the plane axis and any leading batch dims).  Owns the
+    K-chunked overlap and the silent fallback to one chunk when
+    ``chunk_axis`` is not divisible by K.
 
     With K >= 2 chunks the stage runs as a depth-1 *software pipeline*
     (``opts.stage_overlap``: "pipelined", the default): chunk i+1's
@@ -740,10 +771,16 @@ def run_schedule(blk: jax.Array, sched: Schedule, opts,
                  operands=None) -> jax.Array:
     """Execute a schedule on a local (shard_map) block.
 
-    Leading batch axes are carried along unsharded: every axis index in
-    the schedule is offset by ``blk.ndim - 3``.  ``operands`` supplies
-    named blocks to ops that need them (e.g. the fused k-space filter).
+    The block runs as stacked real/imaginary planes
+    (``local_fft.to_planes``), turned back into a complex (or real)
+    block only at exit.  Leading batch axes are carried along unsharded:
+    every axis index in the schedule is offset by the plane axis and
+    the batch rank, ``blk.ndim - 3`` of the planes.  ``operands``
+    supplies named blocks to ops that need them (e.g. the fused k-space
+    filter).
     """
+    with scopes.stage(sched.stages[0].name):
+        blk = local_fft.to_planes(blk)
     off = blk.ndim - 3
     ctx = dict(operands or {})
     for st in sched.stages:
@@ -751,6 +788,7 @@ def run_schedule(blk: jax.Array, sched: Schedule, opts,
     with scopes.stage("epilogue"):
         for op in sched.epilogue:
             blk = op.apply(blk, opts, ctx, off)
+        blk = local_fft.from_planes(blk)
     # Fault plane: trace-time output poisoning.  ``corrupt`` is decided
     # while tracing, so an unarmed (or unmatched) injector contributes
     # zero ops — the compiled HLO is byte-identical to a build with no

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 
 from repro.core.schedule import (_DIMS, PackTwo, RepackHalves, Schedule,
                                  ScheduleError, SpectralScale, SplitPairs,
-                                 Stage, StageOp, UnpackTwo)
+                                 Stage, StageOp, UnpackTwo, complex_form)
 from repro.obs import scopes
 from repro.real import packing
 
@@ -63,6 +63,7 @@ class PackTwoT(StageOp):
 
     pair_axis: int
 
+    @complex_form
     @scopes.role(scopes.RELAYOUT)
     def apply(self, blk, opts, ctx, off):
         ax = self.pair_axis + off
@@ -85,6 +86,7 @@ class SplitPairsT(StageOp):
 
     pair_axis: int
 
+    @complex_form
     @scopes.role(scopes.RELAYOUT)
     def apply(self, blk, opts, ctx, off):
         ax = self.pair_axis + off
@@ -123,6 +125,7 @@ class UnpackTwoT(StageOp):
     z_axis: int = 2
     impl_stage: int = 0
 
+    @complex_form
     @scopes.role(scopes.RELAYOUT)
     def apply(self, blk, opts, ctx, off):
         ax = self.pair_axis + off
@@ -162,6 +165,7 @@ class RepackHalvesT(StageOp):
     z_axis: int = 2
     impl_stage: int = 2
 
+    @complex_form
     @scopes.role(scopes.RELAYOUT)
     def apply(self, blk, opts, ctx, off):
         ax = self.pair_axis + off

@@ -111,3 +111,26 @@ def spectral_scale(x: jax.Array, h: jax.Array, alpha: float = 1.0, *,
     if alpha != 1.0:
         y = y * jnp.asarray(alpha, y.dtype)
     return y
+
+
+@scopes.role(scopes.SCALE)
+def spectral_scale_stacked(p: jax.Array, h: jax.Array, alpha: float = 1.0, *,
+                           use_pallas: bool | None = None,
+                           interpret: bool | None = None) -> jax.Array:
+    """:func:`spectral_scale` on stacked planes ``p`` (2, ...) (the
+    schedule executor's form); ``h`` is complex and broadcasts against
+    one plane.  Same-shape f32 operands take the Pallas plane kernel on
+    TPU (or with ``use_pallas=True``)."""
+    if use_pallas is None:
+        use_pallas = backend.on_tpu()
+    hr, hi = jnp.real(h), jnp.imag(h)
+    if use_pallas and p.dtype == jnp.float32 and h.shape == p.shape[1:]:
+        b, n = math.prod(p.shape[1:-1]), p.shape[-1]
+        yr, yi = spectral_scale_planes_full(
+            p[0].reshape(b, n), p[1].reshape(b, n), hr.reshape(b, n),
+            hi.reshape(b, n), alpha, interpret=interpret)
+        return jnp.stack([yr, yi]).reshape(p.shape)
+    xr, xi = p[0], p[1]
+    if alpha != 1.0:
+        xr, xi = xr * alpha, xi * alpha
+    return jnp.stack([xr * hr - xi * hi, xr * hi + xi * hr])

@@ -21,12 +21,13 @@ each cost exactly one read + one write of the block:
 
 Kernels follow the repo convention (``kernels/hermitian.py``): f32 plane
 kernels, row-blocked grid, compiled on TPU and interpret mode elsewhere;
-complex64 rides as separate real/imag planes.  Off-TPU the same data
-movements lower to the forms XLA CPU/GPU copy fastest (raced
-head-to-head on the CI host): a static-slice ``lax.switch`` pack, an
-in-place ``dynamic_update_slice`` unpack, and a doubled-buffer dynamic
-slice for :func:`rotate_blocks` itself — never ``jnp.roll``, whose
-traced-shift form lowers to a gather.
+the blocks are the schedule executor's real/imaginary planes stacked on
+axis 0 (``local_fft.to_planes``), which the kernel takes as they are.
+Off-TPU the same data movements lower to the forms XLA CPU/GPU copy
+fastest (raced head-to-head on the CI host): a static-slice
+``lax.switch`` pack, an in-place ``dynamic_update_slice`` unpack, and a
+doubled-buffer dynamic slice for :func:`rotate_blocks` itself — never
+``jnp.roll``, whose traced-shift form lowers to a gather.
 """
 
 from __future__ import annotations
@@ -41,56 +42,56 @@ from jax.experimental import pallas as pl
 from repro.kernels import backend
 
 
-def _rotate_kernel(shift_ref, xr_ref, xi_ref, or_ref, oi_ref):
+def _rotate_kernel(shift_ref, x_ref, o_ref):
     """Pure tile copy: the rotation lives entirely in the index maps."""
     del shift_ref
-    or_ref[...] = xr_ref[...]
-    oi_ref[...] = xi_ref[...]
+    o_ref[...] = x_ref[...]
 
 
-def rotate_block_rows_planes(xr: jax.Array, xi: jax.Array, shift: jax.Array,
-                             n_blocks: int, *,
-                             interpret: Optional[bool] = None):
-    """(R, M) f32 planes -> planes with the ``n_blocks`` row-blocks
-    cyclically rotated by ``shift`` blocks (out block i = in block
-    (i + shift) % n_blocks).  ``shift`` is a shape-(1,) int32 array and
-    may be traced (the rank index inside ``shard_map``).
+def rotate_block_rows_planes(x: jax.Array, shift: jax.Array, n_blocks: int,
+                             *, interpret: Optional[bool] = None):
+    """(P, R, M) f32 planes stacked on axis 0 -> the same with each
+    plane's ``n_blocks`` row-blocks cyclically rotated by ``shift``
+    blocks (out block i = in block (i + shift) % n_blocks).  ``shift`` is
+    a shape-(1,) int32 array and may be traced (the rank index inside
+    ``shard_map``).
 
-    The planes are viewed as (n_blocks, R / n_blocks, M) and each
+    The planes are viewed as (P, n_blocks, R / n_blocks, M) and each
     row-block is tiled over its rows and columns, so one window stays a
     few MiB at any block size (a whole row-block of a 1024^3 pencil is
-    256 MiB, far past VMEM).  The shift rides as a *scalar-prefetch*
-    operand consumed by the input index map — grid step (i, j, k)
-    fetches tile (j, k) of block ``(i + shift) % n_blocks`` — so the
-    kernel body is a pure copy with no data-dependent indexing."""
+    256 MiB, far past VMEM); a window holds that tile of every plane.
+    The shift rides as a *scalar-prefetch* operand consumed by the input
+    index map — grid step (i, j, k) fetches tile (j, k) of block
+    ``(i + shift) % n_blocks`` — so the kernel body is a pure copy with
+    no data-dependent indexing."""
     interpret = backend.resolve_interpret(interpret)
-    r, m = xr.shape
+    planes, r, m = x.shape
     if r % n_blocks:
         raise ValueError(f"{r} rows not divisible into {n_blocks} blocks")
     block_rows = r // n_blocks
-    # four windows: two input planes, two output planes
+    # two windows (input, output) of every plane
     tile_rows = backend.pick_block_rows(block_rows,
-                                        min(m, 16 * backend.LANES), 4)
-    tile_cols = backend.pick_block_cols(m, tile_rows, 4)
-    tile = (pl.Squeezed(), tile_rows, tile_cols)
+                                        min(m, 16 * backend.LANES),
+                                        2 * planes)
+    tile_cols = backend.pick_block_cols(m, tile_rows, 2 * planes)
+    tile = (planes, pl.Squeezed(), tile_rows, tile_cols)
     from jax.experimental.pallas import tpu as pltpu
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_blocks, block_rows // tile_rows, m // tile_cols),
         in_specs=[pl.BlockSpec(
-            tile, lambda i, j, k, s_ref: ((i + s_ref[0]) % n_blocks, j, k))
-        ] * 2,
-        out_specs=[pl.BlockSpec(tile, lambda i, j, k, s_ref: (i, j, k))] * 2,
+            tile, lambda i, j, k, s_ref: (0, (i + s_ref[0]) % n_blocks, j, k))],
+        out_specs=[pl.BlockSpec(tile, lambda i, j, k, s_ref: (0, i, j, k))],
     )
-    shape3 = (n_blocks, block_rows, m)
-    yr, yi = pl.pallas_call(
+    shape4 = (planes, n_blocks, block_rows, m)
+    (y,) = pl.pallas_call(
         _rotate_kernel,
         name="croft_rotate_blocks",
         grid_spec=grid_spec,
-        out_shape=backend.f32_outputs(shape3, 2, xr, xi),
+        out_shape=backend.f32_outputs(shape4, 1, x),
         interpret=interpret,
-    )(shift, xr.reshape(shape3), xi.reshape(shape3))
-    return yr.reshape(r, m), yi.reshape(r, m)
+    )(shift, x.reshape(shape4))
+    return y.reshape(planes, r, m)
 
 
 def rotate_blocks(x: jax.Array, axis: int, shift, n_blocks: int, *,
@@ -100,11 +101,16 @@ def rotate_blocks(x: jax.Array, axis: int, shift, n_blocks: int, *,
     ``axis`` by ``shift`` blocks (block i of the result is block
     (i + shift) % n_blocks of the input).  ``shift`` may be traced.
 
-    This is the fused pack/unpack primitive of the ring and pairwise
-    transposes; ``use_pallas=None`` follows the repo convention (Pallas
-    on TPU, plain jnp elsewhere — the fallback is a doubled-buffer
-    dynamic slice, all contiguous copies).
+    ``x`` is a block in the schedule executor's form: planes stacked on
+    axis 0 (``local_fft.to_planes``), which never rotates.  This is the
+    fused pack/unpack primitive of the ring and pairwise transposes;
+    ``use_pallas=None`` follows the repo convention (Pallas on TPU,
+    plain jnp elsewhere — the fallback is a doubled-buffer dynamic
+    slice, all contiguous copies).  The kernel takes f32 planes; other
+    dtypes take the fallback.
     """
+    if axis < 1:
+        raise ValueError("axis 0 holds the planes; rotate another axis")
     if n_blocks == 1:
         return x
     extent = x.shape[axis]
@@ -114,7 +120,7 @@ def rotate_blocks(x: jax.Array, axis: int, shift, n_blocks: int, *,
     block = extent // n_blocks
     if use_pallas is None:
         use_pallas = backend.on_tpu()
-    if not use_pallas or x.dtype != jnp.complex64:
+    if not use_pallas or x.dtype != jnp.float32:
         # NOT jnp.roll: a *traced* shift makes roll lower to a gather
         # over the axis (index arithmetic per element).  Doubling the
         # array and taking one dynamic slice keeps every byte moved by
@@ -123,16 +129,11 @@ def rotate_blocks(x: jax.Array, axis: int, shift, n_blocks: int, *,
         start = jnp.mod(jnp.asarray(shift, jnp.int32), n_blocks) * block
         doubled = jnp.concatenate([x, x], axis=axis)
         return jax.lax.dynamic_slice_in_dim(doubled, start, extent, axis)
-    moved = jnp.moveaxis(x, axis, 0)
-    rows = moved.shape[0]
-    cols = math.prod(moved.shape[1:])
-    xr = jnp.real(moved).reshape(rows, cols)
-    xi = jnp.imag(moved).reshape(rows, cols)
     s = jnp.mod(jnp.asarray(shift, jnp.int32), n_blocks).reshape(1)
-    yr, yi = rotate_block_rows_planes(xr, xi, s, n_blocks,
-                                      interpret=interpret)
-    y = jax.lax.complex(yr, yi).reshape(moved.shape)
-    return jnp.moveaxis(y, 0, axis)
+    moved = jnp.moveaxis(x, axis, 1)
+    y = rotate_block_rows_planes(moved.reshape(x.shape[0], extent, -1), s,
+                                 n_blocks, interpret=interpret)
+    return jnp.moveaxis(y.reshape(moved.shape), 1, axis)
 
 
 def unpack_pieces(pieces: list, axis: int, shift, *,
@@ -155,7 +156,7 @@ def unpack_pieces(pieces: list, axis: int, shift, *,
         return pieces[0]
     if use_pallas is None:
         use_pallas = backend.on_tpu()
-    if use_pallas and pieces[0].dtype == jnp.complex64:
+    if use_pallas and pieces[0].dtype == jnp.float32:
         return rotate_blocks(jnp.concatenate(pieces, axis=axis), axis,
                              shift, p, use_pallas=use_pallas)
     block = pieces[0].shape[axis]
@@ -189,7 +190,7 @@ def pack_pieces(blk: jax.Array, axis: int, idx, n_blocks: int, *,
     block = extent // n_blocks
     if use_pallas is None:
         use_pallas = backend.on_tpu()
-    if use_pallas and blk.dtype == jnp.complex64:
+    if use_pallas and blk.dtype == jnp.float32:
         packed = rotate_blocks(blk, axis, idx, n_blocks,
                                use_pallas=use_pallas)
         return jnp.split(packed, n_blocks, axis=axis)

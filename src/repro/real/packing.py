@@ -178,6 +178,69 @@ def split_pairs(c: jax.Array, pair_axis: int) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# the same steps on the schedule executor's stacked planes (a leading
+# plane axis: 2 = real/imaginary of a complex block, 1 = a real block;
+# ``local_fft.to_planes``).  Axis indices count the plane axis.
+# ---------------------------------------------------------------------------
+
+@scopes.role(scopes.RELAYOUT)
+def pack_two_planes(x: jax.Array, pair_axis: int) -> jax.Array:
+    """:func:`pack_two` on planes: real (1, ...) -> complex (2, ...), the
+    two halves along ``pair_axis`` becoming the two planes."""
+    m = x.shape[pair_axis]
+    if m % 2:
+        raise ValueError(f"pair axis extent {m} must be even to pack two-for-one")
+    return jnp.concatenate(
+        [jax.lax.slice_in_dim(x, 0, m // 2, axis=pair_axis),
+         jax.lax.slice_in_dim(x, m // 2, m, axis=pair_axis)], axis=0)
+
+
+@scopes.role(scopes.RELAYOUT)
+def split_pairs_planes(p: jax.Array, pair_axis: int) -> jax.Array:
+    """:func:`split_pairs` on planes: complex (2, ...) -> real (1, ...)."""
+    return jnp.concatenate([p[:1], p[1:]], axis=pair_axis)
+
+
+def _rows(p: jax.Array) -> tuple:
+    """A planes block's two planes as (rows, bins) f32 arrays."""
+    rows = math.prod(p.shape[1:-1])
+    return p[0].reshape(rows, p.shape[-1]), p[1].reshape(rows, p.shape[-1])
+
+
+def unpack_two_planes(p: jax.Array, pair_axis: int, *,
+                      use_pallas: bool = False) -> jax.Array:
+    """The folded :func:`unpack_two` on planes; the Pallas kernel reads
+    and writes planes, the jnp form converts at the op."""
+    from repro.core.local_fft import from_planes, to_planes
+    if not (use_pallas and p.dtype == jnp.float32):
+        return to_planes(unpack_two(from_planes(p), pair_axis - 1, fold=True))
+    from repro.kernels import hermitian
+    with jax.named_scope(scopes.RELAYOUT):
+        ar, ai, br, bi = hermitian.unpack_two_for_one_planes(*_rows(p))
+        half = p.shape[:-1] + (p.shape[-1] // 2,)
+        return jnp.concatenate([jnp.stack([ar, ai]).reshape(half),
+                                jnp.stack([br, bi]).reshape(half)],
+                               axis=pair_axis)
+
+
+def repack_halves_planes(p: jax.Array, pair_axis: int, nz: int, *,
+                         use_pallas: bool = False) -> jax.Array:
+    """The folded :func:`repack_halves` on planes; the Pallas kernel
+    reads and writes planes, the jnp form converts at the op."""
+    from repro.core.local_fft import from_planes, to_planes
+    if not (use_pallas and p.dtype == jnp.float32):
+        return to_planes(repack_halves(from_planes(p), pair_axis - 1, nz,
+                                       folded=True))
+    from repro.kernels import hermitian
+    with jax.named_scope(scopes.RELAYOUT):
+        m = p.shape[pair_axis]
+        sa = jax.lax.slice_in_dim(p, 0, m // 2, axis=pair_axis)
+        sb = jax.lax.slice_in_dim(p, m // 2, m, axis=pair_axis)
+        cr, ci = hermitian.hermitian_extend_planes(*_rows(sa), *_rows(sb))
+        return jnp.stack([cr, ci]).reshape(sa.shape[:-1] + (nz,))
+
+
+# ---------------------------------------------------------------------------
 # Pallas dispatch: flatten to (rows, bins) f32 planes, run the fused
 # kernel, restore shape/dtype.  complex64 only (kernels are f32-plane
 # kernels, matching kernels/spectral_scale.py).

@@ -152,6 +152,33 @@ def test_hermitian_kernels_match_reference(n, rng):
     np.testing.assert_allclose(np.asarray(ker2), np.asarray(ref2), atol=1e-6)
 
 
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_planes_packing_steps_match_complex_forms(use_pallas, rng):
+    """The schedule executor's planes forms of the packing steps (plane
+    axis 0 ahead of the pair axis) equal the complex forms, through the
+    Pallas plane kernels (interpreted here) and through the conversion
+    at the op."""
+    from repro.core.local_fft import from_planes, to_planes
+    n = 16
+    x = rng.randn(2, 6, n).astype(np.float32)           # pair axis 1
+    c = packing.pack_two(jnp.asarray(x), 1)
+    pc = packing.pack_two_planes(to_planes(jnp.asarray(x)), 2)
+    np.testing.assert_array_equal(np.asarray(from_planes(pc)), np.asarray(c))
+    C = jnp.fft.fft(c, axis=-1)
+    S = packing.unpack_two(C, 1, fold=True)
+    ps = packing.unpack_two_planes(to_planes(C), 2, use_pallas=use_pallas)
+    np.testing.assert_allclose(np.asarray(from_planes(ps)), np.asarray(S),
+                               atol=1e-6)
+    C2 = packing.repack_halves(S, 1, n, folded=True)
+    pc2 = packing.repack_halves_planes(to_planes(S), 2, n,
+                                       use_pallas=use_pallas)
+    np.testing.assert_allclose(np.asarray(from_planes(pc2)), np.asarray(C2),
+                               atol=1e-6)
+    xb = packing.split_pairs_planes(to_planes(c), 2)
+    assert xb.shape == (1,) + x.shape
+    np.testing.assert_array_equal(np.asarray(from_planes(xb)), x)
+
+
 def test_pallas_impl_end_to_end(rng):
     x = rng.randn(8, 8, 16).astype(np.float32)
     opts = FFTOptions(local_impl="pallas")

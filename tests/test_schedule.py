@@ -453,44 +453,52 @@ print("OK transpose impls bitwise identical")
 
 
 def test_transpose_pack_kernels(rng):
-    """rotate_blocks / pack_pieces / unpack_pieces: jnp fallback and the
-    Pallas plane kernel agree with the roll reference, traced and
-    concrete, and pack -> unpack round-trips the ring's permutation."""
+    """rotate_blocks / pack_pieces / unpack_pieces on the executor's
+    stacked planes: jnp fallback and the Pallas plane kernel agree with
+    the roll reference, traced and concrete, never move the plane axis,
+    and pack -> unpack round-trips the ring's permutation."""
     import jax
     import jax.numpy as jnp
+    from repro.core.local_fft import to_planes
     from repro.kernels import transpose_pack as tp
 
     x = (rng.randn(4, 24, 5) + 1j * rng.randn(4, 24, 5)).astype(np.complex64)
+    x = np.asarray(to_planes(jnp.asarray(x)))            # (2, 4, 24, 5)
     p = 8
     for shift in (0, 1, 3, -2, 11):
-        ref = np.roll(x, -(shift % p) * 3, axis=1)
-        got = np.asarray(tp.rotate_blocks(jnp.asarray(x), 1, shift, p,
+        ref = np.roll(x, -(shift % p) * 3, axis=2)
+        got = np.asarray(tp.rotate_blocks(jnp.asarray(x), 2, shift, p,
                                           use_pallas=False))
         np.testing.assert_array_equal(got, ref)
-        ker = np.asarray(tp.rotate_blocks(jnp.asarray(x), 1, shift, p,
+        ker = np.asarray(tp.rotate_blocks(jnp.asarray(x), 2, shift, p,
                                           use_pallas=True, interpret=True))
         np.testing.assert_array_equal(ker, ref)
     # traced shift (what shard_map's axis_index produces)
-    f = jax.jit(lambda a, s: tp.rotate_blocks(a, 1, s, p, use_pallas=False))
+    f = jax.jit(lambda a, s: tp.rotate_blocks(a, 2, s, p, use_pallas=False))
     got = np.asarray(f(jnp.asarray(x), jnp.asarray(2)))
-    np.testing.assert_array_equal(got, np.roll(x, -6, axis=1))
+    np.testing.assert_array_equal(got, np.roll(x, -6, axis=2))
 
     # pack: piece s is the block bound for rank (idx + s) % p
     for idx in (0, 2, 7):
-        pieces = tp.pack_pieces(jnp.asarray(x), 1, idx, p)
-        assert len(pieces) == p
-        for s, piece in enumerate(pieces):
-            d = (idx + s) % p
-            np.testing.assert_array_equal(np.asarray(piece),
-                                          x[:, d * 3:(d + 1) * 3])
-        # unpack: result block i = pieces[(i + shift) % p]
-        out = np.asarray(tp.unpack_pieces(pieces, 1, -idx))
-        rot = np.asarray(tp.rotate_blocks(jnp.concatenate(pieces, 1), 1,
-                                          -idx, p, use_pallas=False))
-        np.testing.assert_array_equal(out, rot)
+        for use_pallas in (False, True):
+            pieces = tp.pack_pieces(jnp.asarray(x), 2, idx, p,
+                                    use_pallas=use_pallas)
+            assert len(pieces) == p
+            for s, piece in enumerate(pieces):
+                d = (idx + s) % p
+                np.testing.assert_array_equal(np.asarray(piece),
+                                              x[:, :, d * 3:(d + 1) * 3])
+            # unpack: result block i = pieces[(i + shift) % p]
+            out = np.asarray(tp.unpack_pieces(pieces, 2, -idx,
+                                              use_pallas=use_pallas))
+            rot = np.asarray(tp.rotate_blocks(jnp.concatenate(pieces, 2), 2,
+                                              -idx, p, use_pallas=False))
+            np.testing.assert_array_equal(out, rot)
 
     with pytest.raises(ValueError):
-        tp.rotate_blocks(jnp.asarray(x), 1, 1, 7)  # 24 % 7 != 0
+        tp.rotate_blocks(jnp.asarray(x), 2, 1, 7)  # 24 % 7 != 0
+    with pytest.raises(ValueError, match="planes"):
+        tp.rotate_blocks(jnp.asarray(x), 0, 1, 2)
 
 
 def test_fftoptions_overlap_knobs():
@@ -578,6 +586,24 @@ def test_with_epilogue_structure():
         schedule_lib.SpectralScale().apply(jnp.ones((2, 2, 2),
                                                     jnp.complex64),
                                            FFTOptions(), {}, 0)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_spectral_scale_on_planes_matches_reference(use_pallas, rng):
+    """The schedule epilogue's planes form: same-shape filters (the
+    Pallas plane kernel, interpreted here, or jnp) and a filter
+    broadcast over a leading batch axis (jnp)."""
+    from repro.core.local_fft import from_planes, to_planes
+    from repro.kernels.spectral_scale import spectral_scale_stacked
+    x = (rng.randn(2, 4, 4, 8) + 1j * rng.randn(2, 4, 4, 8)).astype(
+        np.complex64)
+    h = (rng.randn(2, 4, 4, 8) + 1j * rng.randn(2, 4, 4, 8)).astype(
+        np.complex64)
+    for hh in (h, h[0]):
+        got = from_planes(spectral_scale_stacked(
+            to_planes(jnp.asarray(x)), jnp.asarray(hh), 0.5,
+            use_pallas=use_pallas, interpret=True))
+        np.testing.assert_allclose(np.asarray(got), 0.5 * x * hh, atol=1e-6)
 
 
 def test_spectral_scale_helper_matches_reference(rng):

@@ -70,14 +70,15 @@ def _compile(fn, *specs) -> str:
     return text
 
 
-# 1024^3 on 2x2: the ring transpose rotates (1024, 512 * 512 / K) planes
-# of the local pencil in two row-blocks; one row-block is 256 MiB at K=1
+# 1024^3 on 2x2: the ring transpose rotates the two stacked (1024,
+# 512 * 512 / K) planes of the local pencil in two row-blocks; one
+# row-block of one plane is 256 MiB at K=1
 @pytest.mark.parametrize("k", [1, 2])
 def test_rotate_block_rows_compiles_at_croft1024_block(one_chip, k):
-    shape = (1024, 512 * 512 // k)
+    shape = (2, 1024, 512 * 512 // k)
     shift = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
-    _compile(lambda a, b, s: transpose_pack.rotate_block_rows_planes(
-        a, b, s, 2), *_planes(one_chip, shape, 2), shift)
+    _compile(lambda a, s: transpose_pack.rotate_block_rows_planes(a, s, 2),
+             *_planes(one_chip, shape, 1), shift)
 
 
 # 512^3 rows: the c2c spectrum (n = 512) and the r2c half spectrum (257)
@@ -136,6 +137,26 @@ def test_pencil_ring_forward_compiles_on_2x2(tpu):
     assert compiled.memory_analysis().argument_size_in_bytes == local
 
 
+def test_croft1024_forward_runs_on_planes(tpu):
+    """croft-1024's forward on the 2x2 (the benchmark cell's program).
+    The executor carries stacked real/imag planes, so each chunk's
+    transpose is one all-to-all for both planes (4 transposing stages x
+    K=2 chunks), and each 1024 = 32 x 32 FFT is two real contractions
+    (the x and y FFTs per chunk, the z FFT once: 10).  The parent read
+    and wrote 167.5 GB a chip in 30 convolutions and 16 all-to-alls."""
+    import re
+    mesh = Mesh(np.array(tpu.devices).reshape(2, 2), ("y", "z"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    plan = Croft3D((1024,) * 3, mesh, Decomposition("pencil", ("y", "z")),
+                   FFTOptions())
+    compiled = plan.lower_forward().compile()
+    ops = re.findall(r"= \S+ ([\w\-]+)\(", compiled.as_text())
+    assert ops.count("all-to-all") == 8
+    assert ops.count("convolution") == 10
+    assert compiled.cost_analysis()["bytes accessed"] <= 125e9
+    assert compiled.memory_analysis().temp_size_in_bytes <= 3.5 * 2 ** 30
+
+
 # every kernel carries its own name into the program: the device trace
 # shows it as the custom call's instruction name
 @pytest.mark.parametrize("name", [
@@ -163,8 +184,8 @@ def test_kernels_carry_their_names(one_chip, name):
         "croft_fft4step": (lambda a, b: fft_matmul.fft4step_planes(a, b),
                            planes((64, 1024), 2)),
         "croft_rotate_blocks": (
-            lambda a, b, s: transpose_pack.rotate_block_rows_planes(
-                a, b, s, 2), planes((64, 128), 2) + [shift]),
+            lambda a, s: transpose_pack.rotate_block_rows_planes(a, s, 2),
+            planes((2, 64, 128), 1) + [shift]),
         "flash_attention": (flash_attention.flash_attention, [q, q, q]),
     }[name]
     text = jax.jit(fn).lower(*specs).as_text()
