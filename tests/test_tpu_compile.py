@@ -22,7 +22,8 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.core import Croft3D, Decomposition, FFTOptions
 from repro.kernels import backend, fft_matmul, flash_attention, hermitian
-from repro.kernels import spectral_scale, transpose_pack
+from repro.kernels import ns_update, spectral_scale, transpose_pack
+from repro.solvers.navier_stokes import NavierStokes
 
 F32 = jnp.float32
 
@@ -157,12 +158,33 @@ def test_croft1024_forward_runs_on_planes(tpu):
     assert compiled.memory_analysis().temp_size_in_bytes <= 3.5 * 2 ** 30
 
 
+def test_ns_substage_fits_one_chip_at_512(one_chip):
+    """dns-512's program: one RK4 substage of a 512^3 pseudo-spectral DNS
+    (6 c2r and 3 r2c through the packed local plan, the fused update
+    kernel) with its three (3, 512, 512, 257) complex64 state stacks
+    donated.  It compiled to 4.52 GiB of arguments and 8.26 GiB of temp
+    (12.8 of the chip's 16 GiB)."""
+    import re
+    plan = Croft3D((512,) * 3, None, problem="r2c", strategy="packed")
+    ns = NavierStokes(plan.forward, plan.inverse, plan.shape, nu=1e-3,
+                      dt=1e-3)
+    compiled = ns.lower(one_chip).compile()
+    assert re.search(r"%croft_ns_update[.\d]* = .*custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"',
+                     compiled.as_text())
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14 * 2 ** 30
+    # the state is donated: each of the three stacks is written in place
+    assert mem.alias_size_in_bytes == 3 * 3 * 512 * 512 * 257 * 8
+
+
 # every kernel carries its own name into the program: the device trace
 # shows it as the custom call's instruction name
 @pytest.mark.parametrize("name", [
     "croft_spectral_scale", "croft_spectral_scale_full",
     "croft_hermitian_unpack", "croft_hermitian_extend", "croft_fft_dense",
-    "croft_fft4step", "croft_rotate_blocks", "flash_attention"])
+    "croft_fft4step", "croft_rotate_blocks", "croft_ns_update",
+    "flash_attention"])
 def test_kernels_carry_their_names(one_chip, name):
     planes = lambda shape, k: _planes(one_chip, shape, k)  # noqa: E731
     shift = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
@@ -186,6 +208,11 @@ def test_kernels_carry_their_names(one_chip, name):
         "croft_rotate_blocks": (
             lambda a, s: transpose_pack.rotate_block_rows_planes(a, s, 2),
             planes((2, 64, 128), 1) + [shift]),
+        "croft_ns_update": (
+            lambda c, *p: ns_update.ns_update_planes(
+                c, *p, shape=(64, 128, 16), nu=1e-3),
+            [jax.ShapeDtypeStruct((4,), F32, sharding=one_chip)]
+            + planes((2, 3, 9, 64, 128), 4)),
         "flash_attention": (flash_attention.flash_attention, [q, q, q]),
     }[name]
     text = jax.jit(fn).lower(*specs).as_text()
