@@ -300,12 +300,14 @@ def phase_kernels(*, n: int = 1024, rows: int = 4096) -> dict:
             np.concatenate([xp[:, rows // 2:], xp[:, :rows // 2]], axis=1),
             TOL_ELEMENTWISE)
         c = jnp.asarray(random_field((rows // 2, 2, n), 2))
-        half = packing.unpack_two(c, 1, fold=True, use_pallas=False)
-        run("hermitian_unpack", lambda a: packing.unpack_two(
-            a, 1, fold=True, use_pallas=True), (c,), half, TOL_ELEMENTWISE)
-        run("hermitian_extend", lambda a: packing.repack_halves(
-            a, 1, n, folded=True, use_pallas=True), (half,),
-            packing.repack_halves(half, 1, n, folded=True, use_pallas=False),
+        half = packing.unpack_two(c, 1, fold=True)
+        planes = lambda v: np.stack([np.real(v), np.imag(v)])
+        run("hermitian_unpack", lambda a: packing.unpack_two_planes(
+            a, 2, use_pallas=True), (jnp.asarray(planes(c)),),
+            planes(half), TOL_ELEMENTWISE)
+        run("hermitian_extend", lambda a: packing.repack_halves_planes(
+            a, 2, n, use_pallas=True), (jnp.asarray(planes(half)),),
+            planes(packing.repack_halves(half, 1, n, folded=True)),
             TOL_ELEMENTWISE)
         for m in sorted({64, 256, n}):
             xm = random_field((rows, m), 3)

@@ -14,10 +14,13 @@ All operate along the *last* axis; callers move axes.  Forward sign=-1,
 inverse sign=+1 unnormalized (normalization applied at the 3-D level, eq. (2)
 of the paper).
 
-The schedule executor (``core/schedule.run_schedule``) carries its block
-as stacked real/imaginary planes instead (:func:`to_planes`), and runs
-``matmul`` stages with :func:`fft_planes`: the same four-step, one real
-contraction per stage along the axis where it lies.
+The transforms themselves (:func:`fft3d_local`, the packed real paths
+of ``repro.real`` and the schedule executor, ``core/schedule.run_schedule``)
+carry their block as stacked real/imaginary planes instead
+(:func:`to_planes`) and run each axis with :func:`fft_along`: for
+``matmul`` the planes four-step :func:`fft_planes`, one real contraction
+per stage along the axis where it lies; the other implementations
+convert at the op.
 """
 
 from __future__ import annotations
@@ -149,13 +152,16 @@ def fft_planes(p: jax.Array, axis: int, sign: int = -1, *, nbatch: int = 0,
 
     ``n <= plan.MAX_RADIX`` is one contraction; ``n2 > MAX_RADIX``
     recurses along j2.  The ``nbatch`` dims after the planes are independent
-    fields (the executor's leading batch axes): dot batch dims.
+    fields (leading batch axes): dot batch dims.  A real block (one
+    plane) contracts with stage 1's real rows only.
     """
     n = p.shape[axis]
     cname = "complex128" if p.dtype == jnp.float64 else "complex64"
     plan = plan_lib.make_plan(n, sign, cname)
     with jax.named_scope(scopes.DFT):
         w1, w2 = plan.planes_jnp(rematerialize=not plan_cache)
+        if p.shape[0] == 1:  # a real block: no imaginary plane to contract
+            w1 = w1[..., :1, :, :, :]
     lead = "ABCDEFGHIJKLMNOPQRSTUVWXYZ"[:p.ndim - 1]  # dims past the planes
     pre, post, bat = lead[:axis - 1], lead[axis:], lead[:nbatch]
     if plan.n2 == 1:
@@ -217,7 +223,8 @@ _IMPLS = {"matmul": fft_matmul, "stockham": fft_stockham, "xla": fft_xla}
 
 def fft_1d(x: jax.Array, axis: int, sign: int = -1, *, impl: str = "matmul",
            plan_cache: bool = True) -> jax.Array:
-    """1-D FFT along ``axis`` with the chosen implementation."""
+    """1-D FFT along ``axis`` of a complex block with the chosen
+    implementation (moved to the last axis and back)."""
     if impl == "pallas":
         from repro.kernels import ops as kernel_ops  # lazy: optional dep path
         fn = scopes.role(scopes.DFT)(
@@ -234,25 +241,45 @@ def fft_1d(x: jax.Array, axis: int, sign: int = -1, *, impl: str = "matmul",
         return jnp.moveaxis(y, -1, axis)
 
 
+def fft_along(p: jax.Array, axis: int, sign: int = -1, *,
+              impl: str = "matmul", nbatch: int = 0,
+              plan_cache: bool = True) -> jax.Array:
+    """1-D FFT along ``axis`` of planes ``p`` with ``nbatch`` leading
+    batch axes: the planes four-step for ``matmul``; the other
+    implementations convert at the op."""
+    if impl == "matmul":
+        return fft_planes(p, axis, sign, nbatch=nbatch, plan_cache=plan_cache)
+    y = fft_1d(from_planes(p), axis - 1, sign, impl=impl,
+               plan_cache=plan_cache)
+    return to_planes(y)
+
+
 def fft3d_local(x: jax.Array, sign: int = -1, *, impl="matmul",
                 plan_cache: bool = True, norm: Optional[str] = None) -> jax.Array:
     """Single-device 3-D FFT over the last three axes (x, y, z order).
 
-    ``impl`` may be a 3-tuple of implementations, one per axis in
-    transform order (x, y, z) — the per-stage form of
-    ``FFTOptions.local_impl``.
+    Runs on planes from entry to exit; leading axes are independent
+    fields (dot batch dims).  ``impl`` may be a 3-tuple of
+    implementations, one per axis in transform order (x, y, z) — the
+    per-stage form of ``FFTOptions.local_impl``.
     """
     assert x.ndim >= 3
-    for stage, ax in enumerate((-3, -2, -1)):
+    with scopes.stage("x-fft"):
+        p = to_planes(x)
+    for stage in range(3):
         stage_impl = impl[stage] if isinstance(impl, (tuple, list)) else impl
         with scopes.stage("xyz"[stage] + "-fft"):
-            x = fft_1d(x, ax, sign, impl=stage_impl, plan_cache=plan_cache)
-    return apply_norm(x, sign, norm)
+            p = fft_along(p, p.ndim - 3 + stage, sign, impl=stage_impl,
+                          nbatch=p.ndim - 4, plan_cache=plan_cache)
+    p = apply_norm(p, sign, norm)
+    with scopes.stage("z-fft"):
+        return from_planes(p)
 
 
 @scopes.role(scopes.SCALE)
 def apply_norm(x: jax.Array, sign: int, norm: Optional[str]) -> jax.Array:
-    """Paper convention (eq. 2): forward unnormalized, inverse 1/(NxNyNz)."""
+    """Paper convention (eq. 2): forward unnormalized, inverse 1/(NxNyNz);
+    ``x`` may be a block or its planes (the last three axes are x, y, z)."""
     nxyz = x.shape[-3] * x.shape[-2] * x.shape[-1]
     if norm is None or norm == "backward":
         return x / nxyz if sign == +1 else x

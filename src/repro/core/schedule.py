@@ -557,20 +557,6 @@ class Schedule:
 # the executor
 # ---------------------------------------------------------------------------
 
-def _fft_along(blk: jax.Array, axis: int, sign: int, opts,
-               stage: int = 0, nbatch: int = 0) -> jax.Array:
-    """1-D FFT along ``axis`` of a planes block with ``nbatch`` leading
-    batch axes: the planes four-step for ``matmul``; the other impls
-    convert at the op."""
-    impl = opts.stage_impl(stage)
-    if impl == "matmul":
-        return local_fft.fft_planes(blk, axis, sign, nbatch=nbatch,
-                                    plan_cache=opts.plan_cache)
-    y = local_fft.fft_1d(local_fft.from_planes(blk), axis - 1, sign,
-                         impl=impl, plan_cache=opts.plan_cache)
-    return local_fft.to_planes(y)
-
-
 @scopes.role(scopes.RELAYOUT)
 def _pack_pieces(blk: jax.Array, axis: AxisName, split_axis: int) -> list:
     """Rotated-block pack shared by the ring and pairwise transposes.
@@ -687,8 +673,9 @@ def stage_pre(blk: jax.Array, st: Stage, sign: int, opts, off: int = 1,
     for op in st.prologue:
         blk = op.apply(blk, opts, ctx, off)
     if st.fft_axis is not None:
-        blk = _fft_along(blk, st.fft_axis + off, sign, opts, st.impl_stage,
-                         off - 1)
+        blk = local_fft.fft_along(blk, st.fft_axis + off, sign,
+                                  impl=opts.stage_impl(st.impl_stage),
+                                  nbatch=off - 1, plan_cache=opts.plan_cache)
     for op in st.epilogue:
         blk = op.apply(blk, opts, ctx, off)
     return blk

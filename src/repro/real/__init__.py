@@ -42,7 +42,8 @@ from repro.real.pipeline import (build_packed_forward, build_packed_inverse,
                                  constrain_sharding, packed_irfft3d,
                                  packed_rfft3d, packed_unsupported_reason,
                                  real_input_spec, unfold_dc_plane,
-                                 fold_dc_plane)
+                                 fold_dc_plane, fold_dc_plane_planes,
+                                 unfold_dc_plane_planes)
 
 STRATEGIES = ("auto", "packed", "embed")
 
@@ -70,8 +71,11 @@ def local_rfft3d_packed(x: jax.Array, opts: Optional[FFTOptions] = None,
                         norm: Optional[str] = None) -> jax.Array:
     """Single-device packed r2c: real (..., Nx, Ny, Nz) -> (..., Nx, Ny, Nh).
 
-    Works for odd Nz too (the fold-free two-for-one keeps all Nh bins —
-    there is no shard alignment to preserve on one device).
+    Runs on stacked real/imaginary planes (``local_fft.to_planes``) from
+    the real input to the one conversion at exit; leading axes are
+    independent fields (dot batch dims).  Works for odd Nz too (the
+    fold-free two-for-one keeps all Nh bins — there is no shard
+    alignment to preserve on one device).
     """
     if opts is None:
         opts = FFTOptions()
@@ -81,33 +85,42 @@ def local_rfft3d_packed(x: jax.Array, opts: Optional[FFTOptions] = None,
         raise ValueError(f"packed r2c unsupported here: {reason}")
     pair_axis = _choose_pair_axis(nx, ny)
     fold = nz % 2 == 0  # odd Nz has no Nyquist bin; carry all Nh bins
+    nd, nbatch = x.ndim + 1, x.ndim - 3  # planes rank, leading fields
+
+    def fft(p, axis, stage):
+        return local_fft.fft_along(p, axis, -1, impl=opts.stage_impl(stage),
+                                   nbatch=nbatch, plan_cache=opts.plan_cache)
+
     with scopes.stage("pack+z-rfft"):
-        c = packing.pack_two(x, pair_axis)
-        C = local_fft.fft_1d(c, -1, -1, impl=opts.stage_impl(0),
-                             plan_cache=opts.plan_cache)
-        S = packing.unpack_two(C, pair_axis, nh=nz // 2 + 1, fold=fold,
-                               use_pallas=opts.stage_impl(0) == "pallas")
+        p = packing.pack_two_planes(x[None], pair_axis)
+        p = fft(p, nd - 1, 0)
+        p = packing.unpack_two_planes(
+            p, pair_axis, nh=nz // 2 + 1, fold=fold,
+            use_pallas=opts.stage_impl(0) == "pallas")
     with scopes.stage("y-fft"):
-        S = local_fft.fft_1d(S, -2, -1, impl=opts.stage_impl(1),
-                             plan_cache=opts.plan_cache)
+        p = fft(p, nd - 2, 1)
     with scopes.stage("x-fft"):
-        S = local_fft.fft_1d(S, -3, -1, impl=opts.stage_impl(2),
-                             plan_cache=opts.plan_cache)
+        p = fft(p, nd - 3, 2)
     # the fold stays valid under the (linear) y/x transforms; unfold the
     # DC/Nyquist plane once, at the end, like the distributed pipeline
     with scopes.stage("epilogue"):
-        y = unfold_dc_plane(S) if fold else S
+        if fold:
+            p = unfold_dc_plane_planes(p)
         scale = _norm_scale((nx, ny, nz), -1, norm)
-        if scale is None:
-            return y
-        with jax.named_scope(scopes.SCALE):
-            return y * jnp.asarray(scale, y.dtype)
+        if scale is not None:
+            with jax.named_scope(scopes.SCALE):
+                p = p * jnp.asarray(scale, p.dtype)
+        return local_fft.from_planes(p)
 
 
 def local_irfft3d_packed(y: jax.Array, nz: int,
                          opts: Optional[FFTOptions] = None,
                          norm: Optional[str] = None) -> jax.Array:
-    """Single-device packed c2r: (..., Nx, Ny, Nh) -> real (..., Nx, Ny, Nz)."""
+    """Single-device packed c2r: (..., Nx, Ny, Nh) -> real (..., Nx, Ny, Nz).
+
+    Converts to planes once, at entry, and ends on the real output with
+    no conversion back (the mirror of :func:`local_rfft3d_packed`).
+    """
     if opts is None:
         opts = FFTOptions()
     nx, ny = y.shape[-3], y.shape[-2]
@@ -116,22 +129,31 @@ def local_irfft3d_packed(y: jax.Array, nz: int,
         raise ValueError(f"packed c2r unsupported here: {reason}")
     pair_axis = _choose_pair_axis(nx, ny)
     fold = nz % 2 == 0
+    nd, nbatch = y.ndim + 1, y.ndim - 3
+
+    def ifft(p, axis, stage):
+        return local_fft.fft_along(p, axis, +1, impl=opts.stage_impl(stage),
+                                   nbatch=nbatch, plan_cache=opts.plan_cache)
+
     with scopes.stage("prologue"):
-        t = fold_dc_plane(y, nz) if fold else y
+        p = local_fft.to_planes(y)
+        if fold:
+            p = fold_dc_plane_planes(p, nz)
     with scopes.stage("x-ifft"):
-        t = local_fft.fft_1d(t, -3, +1, impl=opts.stage_impl(0),
-                             plan_cache=opts.plan_cache)
+        p = ifft(p, nd - 3, 0)
     with scopes.stage("y-ifft"):
-        t = local_fft.fft_1d(t, -2, +1, impl=opts.stage_impl(1),
-                             plan_cache=opts.plan_cache)
+        p = ifft(p, nd - 2, 1)
     with scopes.stage("repack+z-ifft+split"):
-        C = packing.repack_halves(t, pair_axis, nz, folded=fold,
-                                  use_pallas=opts.stage_impl(2) == "pallas")
-        c = local_fft.fft_1d(C, -1, +1, impl=opts.stage_impl(2),
-                             plan_cache=opts.plan_cache)
-        x = packing.split_pairs(c, pair_axis)
+        p = packing.repack_halves_planes(
+            p, pair_axis, nz, folded=fold,
+            use_pallas=opts.stage_impl(2) == "pallas")
+        p = ifft(p, nd - 1, 2)
+        x = packing.split_pairs_planes(p, pair_axis)[0]
+    scale = _norm_scale((nx, ny, nz), +1, norm)
+    if scale is None:
+        return x
     with scopes.stage("epilogue"), jax.named_scope(scopes.SCALE):
-        return x * jnp.asarray(_norm_scale((nx, ny, nz), +1, norm), x.dtype)
+        return x * jnp.asarray(scale, x.dtype)
 
 
 def unsupported_reason(shape: Sequence[int], mesh, decomp,

@@ -24,9 +24,11 @@ are real (x, y)-planes, the folded bin stays a valid two-for-one packing
 under the later y/x FFTs and is unfolded once, at the end, by a single
 (Nx, Ny)-plane Hermitian reconstruction (``pipeline.unfold_dc_plane``).
 
-All functions are pure jnp (they trace inside ``shard_map`` bodies);
-``use_pallas=True`` routes the hot unpack / Hermitian-extend steps
-through the fused Pallas kernels in ``repro.kernels.hermitian``.
+All functions are pure jnp (they trace inside ``shard_map`` bodies).
+The transforms run the planes forms at the end of this module; the
+complex forms are their references.  ``use_pallas=True`` routes the
+planes forms' folded unpack / Hermitian extend through the fused Pallas
+kernels in ``repro.kernels.hermitian``.
 
 Everything here is batch-transparent: the spectrum axis is always the
 *last* axis and the pair axis an explicit (batch-offset) index, so
@@ -84,7 +86,7 @@ def pack_two(x: jax.Array, pair_axis: int) -> jax.Array:
 
 @scopes.role(scopes.RELAYOUT)
 def unpack_two(C: jax.Array, pair_axis: int, *, nh: Optional[int] = None,
-               fold: bool = False, use_pallas: bool = False) -> jax.Array:
+               fold: bool = False) -> jax.Array:
     """Split the FFT of a packed block into the two half spectra.
 
     ``C`` is the z-transform of ``pack_two(x)``; the result restores the
@@ -97,11 +99,8 @@ def unpack_two(C: jax.Array, pair_axis: int, *, nh: Optional[int] = None,
                 the shard-aligned layout the distributed pipeline carries.
     """
     n = C.shape[-1]
-    if fold:
-        if n % 2:
-            raise ValueError("fold=True needs an even transform size")
-        if use_pallas and C.dtype == jnp.complex64:
-            return _unpack_fold_pallas(C, pair_axis)
+    if fold and n % 2:
+        raise ValueError("fold=True needs an even transform size")
     rev = jnp.conj(negate_freq(C, -1))
     A = 0.5 * (C + rev)
     B = -0.5j * (C - rev)
@@ -124,7 +123,7 @@ def unpack_two(C: jax.Array, pair_axis: int, *, nh: Optional[int] = None,
 
 @scopes.role(scopes.RELAYOUT)
 def repack_halves(S: jax.Array, pair_axis: int, nz: int, *,
-                  folded: bool = False, use_pallas: bool = False) -> jax.Array:
+                  folded: bool = False) -> jax.Array:
     """Inverse of :func:`unpack_two`: rebuild the full packed z-spectrum.
 
     Given the two half spectra stacked along ``pair_axis`` (``folded``
@@ -137,8 +136,6 @@ def repack_halves(S: jax.Array, pair_axis: int, nz: int, *,
     SA = jax.lax.slice_in_dim(S, 0, m // 2, axis=pair_axis)
     SB = jax.lax.slice_in_dim(S, m // 2, m, axis=pair_axis)
     if folded:
-        if use_pallas and S.dtype == jnp.complex64:
-            return _hermitian_extend_pallas(SA, SB, nz)
         # bin 0 carries (DC, Nyquist) of each spectrum in (real, imag)
         a0, b0 = SA[..., 0], SB[..., 0]
         c0 = jax.lax.complex(jnp.real(a0), jnp.real(b0))      # A[0] + i B[0]
@@ -178,27 +175,30 @@ def split_pairs(c: jax.Array, pair_axis: int) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# the same steps on the schedule executor's stacked planes (a leading
-# plane axis: 2 = real/imaginary of a complex block, 1 = a real block;
-# ``local_fft.to_planes``).  Axis indices count the plane axis.
+# the same steps on stacked planes, as the transforms carry their blocks
+# (a leading plane axis: 2 = real/imaginary of a complex block, 1 = a
+# real block; ``local_fft.to_planes``).  Axis indices count the plane
+# axis; the complex forms above stay as their references.
 # ---------------------------------------------------------------------------
 
 @scopes.role(scopes.RELAYOUT)
 def pack_two_planes(x: jax.Array, pair_axis: int) -> jax.Array:
     """:func:`pack_two` on planes: real (1, ...) -> complex (2, ...), the
     two halves along ``pair_axis`` becoming the two planes."""
-    m = x.shape[pair_axis]
+    a = pair_axis % x.ndim
+    m = x.shape[a]
     if m % 2:
         raise ValueError(f"pair axis extent {m} must be even to pack two-for-one")
-    return jnp.concatenate(
-        [jax.lax.slice_in_dim(x, 0, m // 2, axis=pair_axis),
-         jax.lax.slice_in_dim(x, m // 2, m, axis=pair_axis)], axis=0)
+    halves = x[0].reshape(x.shape[1:a] + (2, m // 2) + x.shape[a + 1:])
+    return jnp.moveaxis(halves, a - 1, 0)
 
 
 @scopes.role(scopes.RELAYOUT)
 def split_pairs_planes(p: jax.Array, pair_axis: int) -> jax.Array:
     """:func:`split_pairs` on planes: complex (2, ...) -> real (1, ...)."""
-    return jnp.concatenate([p[:1], p[1:]], axis=pair_axis)
+    a = pair_axis % p.ndim
+    q = jnp.moveaxis(p, 0, a - 1)
+    return q.reshape(q.shape[:a - 1] + (2 * p.shape[a],) + q.shape[a + 1:])[None]
 
 
 def _rows(p: jax.Array) -> tuple:
@@ -207,63 +207,100 @@ def _rows(p: jax.Array) -> tuple:
     return p[0].reshape(rows, p.shape[-1]), p[1].reshape(rows, p.shape[-1])
 
 
+def hermitian_halves(p: jax.Array, rev: jax.Array) -> tuple:
+    """The two-for-one split on planes: with ``C = p`` and ``C~ = rev``
+    (``C`` at the negated frequencies), the planes of
+    ``A = (C + conj C~) / 2`` and ``B = (C - conj C~) / 2i``."""
+    return (0.5 * jnp.stack([p[0] + rev[0], p[1] - rev[1]]),
+            0.5 * jnp.stack([p[1] + rev[1], rev[0] - p[0]]))
+
+
+def bins(n: int) -> jax.Array:
+    """The bin index along the last axis.  The planes forms pick single
+    bins with it (``where(bins(n) == 0, ...)``) instead of cutting out
+    and concatenating one-bin slices, so each step stays one elementwise
+    pass: on the TPU a one-bin slice pads to a whole lane tile."""
+    return jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
+
+
+def pad_last(x: jax.Array, before: int, after: int) -> jax.Array:
+    """``x`` padded with zeros along its last axis."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(before, after)])
+
+
 def unpack_two_planes(p: jax.Array, pair_axis: int, *,
+                      nh: Optional[int] = None, fold: bool = True,
                       use_pallas: bool = False) -> jax.Array:
-    """The folded :func:`unpack_two` on planes; the Pallas kernel reads
-    and writes planes, the jnp form converts at the op."""
-    from repro.core.local_fft import from_planes, to_planes
-    if not (use_pallas and p.dtype == jnp.float32):
-        return to_planes(unpack_two(from_planes(p), pair_axis - 1, fold=True))
-    from repro.kernels import hermitian
+    """:func:`unpack_two` on planes (``fold`` and ``nh`` as there).  The
+    folded Pallas kernel reads and writes planes."""
+    n = p.shape[-1]
+    if fold and n % 2:
+        raise ValueError("fold=True needs an even transform size")
+    if fold and use_pallas and p.dtype == jnp.float32:
+        from repro.kernels import hermitian
+        with jax.named_scope(scopes.RELAYOUT):
+            ar, ai, br, bi = hermitian.unpack_two_for_one_planes(*_rows(p))
+            half = p.shape[:-1] + (n // 2,)
+            return jnp.concatenate([jnp.stack([ar, ai]).reshape(half),
+                                    jnp.stack([br, bi]).reshape(half)],
+                                   axis=pair_axis)
+    nh = n // 2 + 1 if fold or nh is None else nh
     with jax.named_scope(scopes.RELAYOUT):
-        ar, ai, br, bi = hermitian.unpack_two_for_one_planes(*_rows(p))
-        half = p.shape[:-1] + (p.shape[-1] // 2,)
-        return jnp.concatenate([jnp.stack([ar, ai]).reshape(half),
-                                jnp.stack([br, bi]).reshape(half)],
-                               axis=pair_axis)
+        # C at the negated bins 0, n-1, ..., n-nh+1 (only the bins kept
+        # are read twice): the flipped tail, shifted in by one
+        k = bins(nh)
+        rev = jnp.where(k == 0, p[..., :1],
+                        pad_last(jnp.flip(p[..., n - nh + 1:], -1), 1, 0))
+        A, B = hermitian_halves(p[..., :nh], rev)
+        if fold:
+            # DC and Nyquist of a real transform are real: Nyquist rides
+            # in DC's imaginary plane -> exactly n/2 bins, none lost
+            nz2 = n // 2
+            A, B = (jnp.stack([S[0, ..., :nz2],
+                               jnp.where(bins(nz2) == 0, S[0, ..., nz2:],
+                                         S[1, ..., :nz2])])
+                    for S in (A, B))
+        return jnp.concatenate([A, B], axis=pair_axis)
 
 
 def repack_halves_planes(p: jax.Array, pair_axis: int, nz: int, *,
+                         folded: bool = True,
                          use_pallas: bool = False) -> jax.Array:
-    """The folded :func:`repack_halves` on planes; the Pallas kernel
-    reads and writes planes, the jnp form converts at the op."""
-    from repro.core.local_fft import from_planes, to_planes
-    if not (use_pallas and p.dtype == jnp.float32):
-        return to_planes(repack_halves(from_planes(p), pair_axis - 1, nz,
-                                       folded=True))
-    from repro.kernels import hermitian
+    """:func:`repack_halves` on planes (``folded`` as there).  The folded
+    Pallas kernel reads and writes planes."""
+    m = p.shape[pair_axis]
     with jax.named_scope(scopes.RELAYOUT):
-        m = p.shape[pair_axis]
         sa = jax.lax.slice_in_dim(p, 0, m // 2, axis=pair_axis)
         sb = jax.lax.slice_in_dim(p, m // 2, m, axis=pair_axis)
-        cr, ci = hermitian.hermitian_extend_planes(*_rows(sa), *_rows(sb))
-        return jnp.stack([cr, ci]).reshape(sa.shape[:-1] + (nz,))
-
-
-# ---------------------------------------------------------------------------
-# Pallas dispatch: flatten to (rows, bins) f32 planes, run the fused
-# kernel, restore shape/dtype.  complex64 only (kernels are f32-plane
-# kernels, matching kernels/spectral_scale.py).
-# ---------------------------------------------------------------------------
-
-def _unpack_fold_pallas(C: jax.Array, pair_axis: int) -> jax.Array:
-    from repro.kernels import hermitian
-    n = C.shape[-1]
-    rows = math.prod(C.shape[:-1])
-    cr = jnp.real(C).reshape(rows, n)
-    ci = jnp.imag(C).reshape(rows, n)
-    ar, ai, br, bi = hermitian.unpack_two_for_one_planes(cr, ci)
-    half = C.shape[:-1] + (n // 2,)
-    A = jax.lax.complex(ar, ai).reshape(half)
-    B = jax.lax.complex(br, bi).reshape(half)
-    return jnp.concatenate([A, B], axis=pair_axis)
-
-
-def _hermitian_extend_pallas(SA: jax.Array, SB: jax.Array, nz: int) -> jax.Array:
-    from repro.kernels import hermitian
-    nz2 = SA.shape[-1]
-    rows = math.prod(SA.shape[:-1])
-    planes = [jnp.real(SA), jnp.imag(SA), jnp.real(SB), jnp.imag(SB)]
-    planes = [p.reshape(rows, nz2) for p in planes]
-    cr, ci = hermitian.hermitian_extend_planes(*planes)
-    return jax.lax.complex(cr, ci).reshape(SA.shape[:-1] + (nz,))
+    if folded and use_pallas and p.dtype == jnp.float32:
+        from repro.kernels import hermitian
+        with jax.named_scope(scopes.RELAYOUT):
+            cr, ci = hermitian.hermitian_extend_planes(*_rows(sa), *_rows(sb))
+            return jnp.stack([cr, ci]).reshape(sa.shape[:-1] + (nz,))
+    ar, ai, br, bi = sa[0], sa[1], sb[0], sb[1]
+    nh = sa.shape[-1]
+    k = bins(nh)
+    with jax.named_scope(scopes.RELAYOUT):
+        # C[k] = A[k] + iB[k] up to the middle, conj(A - iB)[nz - k] past it
+        tail_r, tail_i = ar + bi, br - ai
+        if folded:
+            # bin 0 carries (DC, Nyquist) of each spectrum in its planes:
+            # C[0] = (ar, br)[0] and C[nz/2] = (ai, bi)[0]
+            head = jnp.stack([jnp.where(k == 0, ar, ar - bi),
+                              jnp.where(k == 0, br, ai + br)])
+            tail = jnp.stack([
+                jnp.where(k == 0, ai[..., :1],
+                          pad_last(jnp.flip(tail_r[..., 1:], -1), 1, 0)),
+                jnp.where(k == 0, bi[..., :1],
+                          pad_last(jnp.flip(tail_i[..., 1:], -1), 1, 0))])
+        else:
+            # DC (and, for even nz, Nyquist) keep their real parts only,
+            # as numpy's irfft does (see repack_halves)
+            real = k == 0
+            if nz % 2 == 0 and nh - 1 == nz // 2:
+                real = real | (k == nh - 1)
+            head = jnp.stack([jnp.where(real, ar, ar - bi),
+                              jnp.where(real, br, ai + br)])
+            tail = jnp.flip(jnp.stack([tail_r, tail_i])[..., 1:1 + nz - nh],
+                            -1)
+        return jnp.concatenate([head, tail], axis=-1)

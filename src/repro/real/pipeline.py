@@ -240,6 +240,37 @@ def fold_dc_plane(y: jax.Array, nz: int) -> jax.Array:
     return jnp.concatenate([g[..., None], y[..., 1:nz2]], axis=-1)
 
 
+def _negate_plane(p: jax.Array) -> jax.Array:
+    """(kx, ky) -> (-kx, -ky) over the last two axes."""
+    return packing.negate_freq(packing.negate_freq(p, -1), -2)
+
+
+@scopes.role(scopes.RELAYOUT)
+def unfold_dc_plane_planes(packed: jax.Array) -> jax.Array:
+    """:func:`unfold_dc_plane` on planes: (2, ..., Nz2) -> (2, ..., Nz2 + 1),
+    one elementwise pass (``packing.bins`` picks the two end bins)."""
+    nz2 = packed.shape[-1]
+    g = packed[..., 0]
+    dc, nyq = packing.hermitian_halves(g, _negate_plane(g))
+    k = packing.bins(nz2 + 1)
+    return jnp.where(k == 0, dc[..., None],
+                     jnp.where(k == nz2, nyq[..., None],
+                               packing.pad_last(packed, 0, 1)))
+
+
+@scopes.role(scopes.RELAYOUT)
+def fold_dc_plane_planes(y: jax.Array, nz: int) -> jax.Array:
+    """:func:`fold_dc_plane` on planes: (2, ..., Nh) -> (2, ..., Nz/2),
+    one elementwise pass over the bins kept."""
+    def hermitian(g):  # as _hermitian_plane
+        return packing.hermitian_halves(g, _negate_plane(g))[0]
+
+    nz2 = nz // 2
+    dc, nyq = hermitian(y[..., 0]), hermitian(y[..., nz2])
+    g = jnp.stack([dc[0] - nyq[1], dc[1] + nyq[0]])      # dc + i nyq
+    return jnp.where(packing.bins(nz2) == 0, g[..., None], y[..., :nz2])
+
+
 # ---------------------------------------------------------------------------
 # public entry points
 # ---------------------------------------------------------------------------

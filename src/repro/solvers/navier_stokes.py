@@ -14,8 +14,12 @@ takes the pressure's place), and classical RK4 with a = (1/6, 1/3, 1/3,
 1/6) and b = (1/2, 1/2, 1).  One substage (:meth:`NavierStokes.substage`):
 
 1. omega_hat = i k x U, in plain jnp;
-2. six c2r transforms, u and omega, in one call of the plan's inverse
-   on the stacked six fields;
+2. six c2r transforms, u and omega, in two calls of the plan's inverse
+   on three stacked fields each.  Each stage of a transform holds its
+   input and output planes whole, 3.2 GB each for six fields at 512^3:
+   compiled for a v5e, one six-field call took 12.8 GiB of temporaries
+   besides the 4.5 GiB state, more than the chip's 16 GiB, and two
+   three-field calls take 7.5 GiB;
 3. u x omega in physical space, in plain jnp;
 4. three r2c transforms of the product, one call of the plan's forward;
 5-8. mask, projection, viscous term and the RK4 update of (U, U0, U1)
@@ -143,10 +147,9 @@ class NavierStokes:
         k = wavenumbers(self.shape)
         with scopes.stage("ns-curl"), jax.named_scope(scopes.SCALE):
             w_hat = curl(u_hat, k)
-            both = jnp.concatenate([u_hat, w_hat])
-        fields = self._inverse(both)                  # (6, Nx, Ny, Nz)
+        u, w = self._inverse(u_hat), self._inverse(w_hat)   # (3, Nx, Ny, Nz)
         with scopes.stage("ns-cross"), jax.named_scope(scopes.SCALE):
-            product = cross(fields[:3], fields[3:])
+            product = cross(u, w)
         n_hat = self._forward(product)                # (3, Nx, Ny, Nh)
         with scopes.stage("ns-update"):
             with jax.named_scope(scopes.RELAYOUT):

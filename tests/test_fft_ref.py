@@ -62,6 +62,35 @@ def test_fft3d_local(rng):
     np.testing.assert_allclose(xb, x, atol=2e-4 * np.abs(x).max())
 
 
+@pytest.mark.parametrize("sign", [-1, +1])
+@pytest.mark.parametrize("impl", [
+    ("matmul", "stockham", "xla"), ("xla", "matmul", "stockham"),
+    ("stockham", "xla", "matmul"), "matmul", "xla"])
+def test_fft3d_local_mixed_impls_match_numpy(impl, sign):
+    """Per-stage impl tuples: ``matmul`` stages run on the planes, the
+    others convert at the op; a leading batch axis rides along."""
+    rng = np.random.default_rng(7)
+    shape = (2, 8, 16, 32)
+    x = (rng.standard_normal(shape)
+         + 1j * rng.standard_normal(shape)).astype(np.complex64)
+    y = np.asarray(lf.fft3d_local(jnp.asarray(x), sign, impl=impl,
+                                  norm="none"))
+    x64 = x.astype(np.complex128)
+    axes = (1, 2, 3)
+    ref = (np.fft.fftn(x64, axes=axes) if sign == -1
+           else np.fft.ifftn(x64, axes=axes) * np.prod(shape[1:]))
+    np.testing.assert_allclose(y, ref, rtol=0, atol=2e-6 * np.abs(ref).max())
+
+
+def test_fft3d_local_of_a_real_block(rng):
+    """A real block enters as one plane: stage 1 contracts its real rows
+    only."""
+    x = rng.randn(8, 16, 128).astype(np.float32)
+    y = np.asarray(lf.fft3d_local(jnp.asarray(x)))
+    ref = np.fft.fftn(x.astype(np.float64))
+    np.testing.assert_allclose(y, ref, rtol=0, atol=2e-6 * np.abs(ref).max())
+
+
 def test_rfft3d_local(rng):
     from repro.core.rfft import rfft3d, irfft3d
     x = rng.randn(8, 4, 16).astype(np.float32)

@@ -8,6 +8,7 @@ on 8 virtual CPU devices in subprocesses (see conftest.run_multidevice).
 
 import dataclasses
 import json
+import math
 import os
 import subprocess
 import sys
@@ -142,14 +143,17 @@ def test_repack_inverts_unpack(folded, rng):
 
 @pytest.mark.parametrize("n", [16, 64, 256])
 def test_hermitian_kernels_match_reference(n, rng):
+    from repro.core.local_fft import from_planes, to_planes
     C = (rng.randn(8, 4, n) + 1j * rng.randn(8, 4, n)).astype(np.complex64)
     Cj = jnp.asarray(C)
-    ref = packing.unpack_two(Cj, 1, fold=True, use_pallas=False)
-    ker = packing.unpack_two(Cj, 1, fold=True, use_pallas=True)
-    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=1e-6)
-    ref2 = packing.repack_halves(ref, 1, n, folded=True, use_pallas=False)
-    ker2 = packing.repack_halves(ref, 1, n, folded=True, use_pallas=True)
-    np.testing.assert_allclose(np.asarray(ker2), np.asarray(ref2), atol=1e-6)
+    ref = packing.unpack_two(Cj, 1, fold=True)
+    ker = packing.unpack_two_planes(to_planes(Cj), 2, use_pallas=True)
+    np.testing.assert_allclose(np.asarray(from_planes(ker)), np.asarray(ref),
+                               atol=1e-6)
+    ref2 = packing.repack_halves(ref, 1, n, folded=True)
+    ker2 = packing.repack_halves_planes(to_planes(ref), 2, n, use_pallas=True)
+    np.testing.assert_allclose(np.asarray(from_planes(ker2)),
+                               np.asarray(ref2), atol=1e-6)
 
 
 @pytest.mark.parametrize("use_pallas", [False, True])
@@ -177,6 +181,75 @@ def test_planes_packing_steps_match_complex_forms(use_pallas, rng):
     xb = packing.split_pairs_planes(to_planes(c), 2)
     assert xb.shape == (1,) + x.shape
     np.testing.assert_array_equal(np.asarray(from_planes(xb)), x)
+
+
+@pytest.mark.parametrize("batch", [(), (3,)])
+@pytest.mark.parametrize("pair", [-2, -3])
+@pytest.mark.parametrize("n,fold", [(16, True), (16, False), (15, False)])
+def test_planes_unpack_repack_jnp_forms(n, fold, pair, batch, rng):
+    """The plane-native jnp forms of the two-for-one split and its
+    inverse equal the complex forms, folded and not (odd n has no
+    Nyquist bin), pairing along y or x, under leading batch axes; and
+    the split of a packed FFT is the two pencils' rfft."""
+    from repro.core.local_fft import from_planes, to_planes
+    shape = batch + (4, 6, n)
+    x = rng.randn(*shape).astype(np.float32)
+    C = jnp.fft.fft(packing.pack_two(jnp.asarray(x), pair), axis=-1)
+    nh = n // 2 + 1
+    S = packing.unpack_two(C, pair, nh=nh, fold=fold)
+    ps = packing.unpack_two_planes(to_planes(C), pair, nh=nh, fold=fold)
+    np.testing.assert_allclose(np.asarray(from_planes(ps)), np.asarray(S),
+                               atol=1e-5)
+    if not fold:
+        np.testing.assert_allclose(np.asarray(S), np.fft.rfft(x, axis=-1),
+                                   atol=1e-4)
+    C2 = packing.repack_halves(S, pair, n, folded=fold)
+    pc2 = packing.repack_halves_planes(to_planes(S), pair, n, folded=fold)
+    np.testing.assert_allclose(np.asarray(from_planes(pc2)), np.asarray(C2),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(C2), np.asarray(C), atol=1e-4)
+
+
+@pytest.mark.parametrize("norm", [None, "backward", "ortho", "none"])
+@pytest.mark.parametrize("shape,impl", [
+    ((8, 4, 15), ("xla", "matmul", "xla")),     # odd Nz: z by xla, y planes
+    ((8, 9, 16), ("matmul", "xla", "matmul")),  # odd Ny: pairs along x
+    ((3, 8, 4, 16), "matmul"),                  # a stack of three fields
+    ((2, 2, 4, 8, 16), "matmul"),               # two leading axes
+    ((2, 8, 4, 9), ("xla", "matmul", "xla")),   # odd Nz, stacked
+])
+def test_local_packed_planes_paths_match_rfftn(shape, impl, norm, rng):
+    """The single-device packed r2c/c2r on planes against numpy over the
+    last three axes, at odd Nz and odd Ny, on stacked fields, under
+    every norm ("none" scales neither way)."""
+    x = rng.randn(*shape).astype(np.float32)
+    opts = FFTOptions(local_impl=impl)
+    axes = (-3, -2, -1)
+    ref = np.fft.rfftn(x, axes=axes,
+                       norm="ortho" if norm == "ortho" else "backward")
+    y = rfft3d(jnp.asarray(x), opts=opts, strategy="packed", norm=norm)
+    assert y.shape == ref.shape and y.dtype == jnp.complex64
+    np.testing.assert_allclose(np.asarray(y), ref,
+                               atol=3e-6 * np.abs(ref).max())
+    xb = np.asarray(irfft3d(y, shape[-1], opts=opts, strategy="packed",
+                            norm=norm))
+    want = x * math.prod(shape[-3:]) if norm == "none" else x
+    np.testing.assert_allclose(xb, want, atol=2e-6 * np.abs(want).max())
+
+
+def test_local_packed_fields_of_a_stack_come_out_as_alone(rng):
+    """A stack through the batched entries: each field bitwise as the
+    field alone (leading axes are dot batch dims, not longer rows)."""
+    from repro.core import Croft3D
+    plan = Croft3D((8, 8, 16), None, problem="r2c", strategy="packed")
+    x = jnp.asarray(rng.randn(4, 8, 8, 16).astype(np.float32))
+    many = plan.forward_batched(x)
+    back = plan.inverse_batched(many)
+    for b in range(4):
+        np.testing.assert_array_equal(np.asarray(many[b]),
+                                      np.asarray(plan.forward(x[b])))
+        np.testing.assert_array_equal(np.asarray(back[b]),
+                                      np.asarray(plan.inverse(many[b])))
 
 
 def test_pallas_impl_end_to_end(rng):

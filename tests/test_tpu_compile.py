@@ -178,6 +178,48 @@ def test_ns_substage_fits_one_chip_at_512(one_chip):
     assert mem.alias_size_in_bytes == 3 * 3 * 512 * 512 * 257 * 8
 
 
+def _opcodes(compiled) -> list:
+    import re
+    return re.findall(r"= \S+ ([\w\-]+)\(", compiled.as_text())
+
+
+def test_ns_substage_runs_local_fft_on_planes(one_chip):
+    """dns-512's substage with the local packed transforms on stacked
+    real/imag planes: each axis of each transform is two real
+    contractions, and the substage makes three transform calls of three
+    fields (u, omega, then u x omega): 3 x 3 x 2 = 18 convolutions.  The
+    complex einsums before compiled to 36 in two calls (six fields,
+    three fields) and accessed 325.8 GB; this program accesses 228.1 GB.
+    One six-field c2r call would make it 12 convolutions, but that
+    program takes 12.8 GiB of temporaries and does not fit the chip
+    (see ``test_ns_substage_fits_one_chip_at_512``)."""
+    plan = Croft3D((512,) * 3, None, problem="r2c", strategy="packed")
+    ns = NavierStokes(plan.forward, plan.inverse, plan.shape, nu=1e-3,
+                      dt=1e-3)
+    compiled = ns.lower(one_chip).compile()
+    assert _opcodes(compiled).count("convolution") == 18
+    assert compiled.cost_analysis()["bytes accessed"] <= 270e9
+
+
+# pme-128's two programs: the packed r2c with the fused filter and the
+# c2r, each three axes of two real contractions.  On complex einsums
+# they compiled to 18 convolutions each and accessed 699.7 MB (forward)
+# and 457.5 MB (inverse); on planes 442.8 MB and 301.8 MB.
+@pytest.mark.parametrize("entry,most_bytes", [("forward_filtered", 500e6),
+                                              ("inverse", 350e6)])
+def test_pme128_programs_run_on_planes(one_chip, entry, most_bytes):
+    plan = Croft3D((128,) * 3, None, problem="r2c", strategy="packed")
+    grid = jax.ShapeDtypeStruct((128,) * 3, F32, sharding=one_chip)
+    half = jax.ShapeDtypeStruct((128, 128, 65), jnp.complex64,
+                                sharding=one_chip)
+    if entry == "inverse":
+        compiled = jax.jit(plan.inverse).lower(half).compile()
+    else:
+        compiled = jax.jit(plan.forward_filtered).lower(grid, half).compile()
+    assert _opcodes(compiled).count("convolution") == 6
+    assert compiled.cost_analysis()["bytes accessed"] <= most_bytes
+
+
 # every kernel carries its own name into the program: the device trace
 # shows it as the custom call's instruction name
 @pytest.mark.parametrize("name", [
