@@ -25,7 +25,8 @@ attaches the 1/(-k²) multiply to the forward transform's schedule
 (``Croft3D.forward_filtered`` -> ``Schedule.with_epilogue`` /
 ``kernels/spectral_scale.py``), so the whole solve is one forward
 dispatch plus one inverse — no separate pass over the spectrum
-(``benchmarks/rfft_bench.py`` gates this at parity-or-better).
+(``tests/test_real_fft.py`` holds its compiled bytes below forward plus
+a separate multiply).
 """
 
 import argparse

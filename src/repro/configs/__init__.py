@@ -1,4 +1,4 @@
-"""Config registry: ``get_config("<arch>")`` / ``--arch`` lookup.
+"""Config registry: ``get_config("<arch>")``.
 
 Ten assigned architectures + the paper's own FFT workloads + one bonus
 spectral LM.  Each module exposes ``full()`` and ``smoke()``.

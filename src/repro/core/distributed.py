@@ -38,8 +38,7 @@ The FFTW3 baseline the paper benchmarks against is represented two ways:
 slab decomposition (its scaling model) and ``transpose_impl="pairwise"``
 (its communication pattern: P-1 *blocking* sendrecv exchanges placed
 through a serial chain, reproducing the "864 calls vs 64 calls" profile
-of figs 12-15).  ``benchmarks/overlap_bench.py`` sweeps all three
-transpose impls x K and gates ring at parity-or-better.
+of figs 12-15).
 """
 
 from __future__ import annotations
@@ -57,14 +56,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import local_fft
 from repro.core import schedule as schedule_lib
 from repro.core.decomposition import Decomposition
-
-AxisName = Union[str, tuple]
-
-# re-exports: the executor primitives moved into the schedule IR but remain
-# addressable here (models/ and older call sites import them from this module)
-_axis_size = schedule_lib._axis_size
-_all_to_all = schedule_lib._all_to_all
-
 
 @dataclasses.dataclass(frozen=True)
 class FFTOptions:
@@ -196,24 +187,6 @@ def _canon_stage_tuple(name: str, value: Union[str, tuple]) -> Union[str, tuple]
         if len(set(value)) == 1:
             value = value[0]
     return value
-
-
-def _stage(blk: jax.Array, *, fft_axis: Optional[int], comm_axis: Optional[AxisName],
-           split_axis: int, concat_axis: int, chunk_axis: int, sign: int,
-           opts: FFTOptions, stage: int = 0) -> jax.Array:
-    """One ad-hoc pipeline stage (K-chunked FFT -> all_to_all).
-
-    Thin shim over :func:`repro.core.schedule.run_stage` kept for callers
-    that use the CROFT overlap pattern outside a full 3-D schedule
-    (``models/spectral.py`` sequence FFTs, ``models/moe_sharded.py``
-    expert dispatch).
-    """
-    st = schedule_lib.Stage("ad-hoc", fft_axis=fft_axis, comm_axis=comm_axis,
-                            split_axis=split_axis, concat_axis=concat_axis,
-                            chunk_axis=chunk_axis, impl_stage=stage)
-    out = schedule_lib.run_stage(local_fft.to_planes(blk), st, sign, opts,
-                                 off=1)
-    return local_fft.from_planes(out)
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,8 @@ Two layers:
               residual-free for the linear transforms, and the only way
               to differentiate the pairwise transpose at all (XLA has no
               rule for ``optimization_barrier``).
+``filter``    the learned spectral filter trained through a plan: the
+              workload ``Croft3D.tuned(..., grad=True)`` plans for.
 
 ``fft3d``/``ifft3d``/``rfft3d``/``irfft3d`` and the ``Croft3D`` methods
 pick this up automatically; nothing here needs to be called directly

@@ -1,1 +1,2 @@
-"""Launch layer: production mesh, multi-pod dry-run, train/serve drivers."""
+"""Launch layer: meshes, the compile cache, HLO cost and roofline
+reading, and the transform-service driver."""

@@ -50,7 +50,7 @@ DEVICE_PEAKS = {
                '4 links'),
 }
 
-#: the chip the dry runs compile for (``launch/dryrun.py``)
+#: the chip the planner's priors describe (``tuning/cost_model.py``)
 TARGET_DEVICE_KIND = "TPU v5 lite"
 
 

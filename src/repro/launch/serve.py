@@ -1,16 +1,10 @@
-"""Serving driver for the transform service (and the legacy LM loop).
+"""Serving driver for the transform service.
 
-Default mode drives :class:`repro.serve.TransformService` with a
-synthetic open-loop request stream and prints latency / occupancy /
-plan-cache stats — the operational entry point for ROADMAP item 2:
+Drives :class:`repro.serve.TransformService` with a synthetic open-loop
+request stream and prints latency / occupancy / plan-cache stats:
 
 ``python -m repro.launch.serve --shape 32,32,32 --problem mix
 --requests 64 --qps 50 --wisdom wisdom.json``
-
-Passing ``--arch`` selects the legacy LM prefill+decode loop instead:
-
-``python -m repro.launch.serve --arch rwkv6-3b --smoke --prompt-len 32
---gen-len 32 --batch 4``
 """
 
 from __future__ import annotations
@@ -20,14 +14,11 @@ import math
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 
-
-# -- transform-service mode (default) ---------------------------------------
 
 def _mesh_for_transforms():
     """Pencil mesh over whatever devices exist; None = single device
@@ -96,73 +87,9 @@ def transforms_main(args) -> None:
           f"{ {k.split('|')[0] + '|' + k.split('|')[-1]: v['state'] for k, v in cache['plans'].items()} }")
 
 
-# -- legacy LM prefill/decode loop (``--arch``) -----------------------------
-
-def lm_main(args) -> None:
-    from repro.configs import get_config
-    from repro.launch.mesh import make_local_mesh
-    from repro.models import init_caches, init_params
-    from repro.train import make_serve_steps
-    from repro.train.data import synth_tokens
-    from repro.train.train_step import temperature_sample
-
-    cfg = get_config(args.arch, smoke=args.smoke)
-    if not cfg.supports_decode:
-        raise SystemExit(f"{cfg.name} is encoder-only: no decode step")
-    mesh = make_local_mesh()
-    params = init_params(jax.random.PRNGKey(args.seed), cfg)
-
-    max_len = args.prompt_len + args.gen_len \
-        + (cfg.n_frontend_tokens if cfg.prefix_lm else 0)
-    prefill_fn, decode_fn = make_serve_steps(
-        cfg, mesh, args.batch, max_len, kv_block=args.kv_block)
-
-    prompts = synth_tokens(args.seed, 0, args.batch, args.prompt_len,
-                           cfg.vocab)
-    enc_len = cfg.n_frontend_tokens if cfg.encoder is not None else 0
-    caches = init_caches(cfg, args.batch, max_len, enc_len=enc_len,
-                         dtype=jnp.bfloat16)
-    kwargs = {}
-    rng = np.random.default_rng(args.seed)
-    if cfg.encoder is not None:
-        kwargs["frames"] = jnp.asarray(rng.standard_normal(
-            (args.batch, cfg.n_frontend_tokens, cfg.d_model), np.float32))
-    elif cfg.frontend == "vision":
-        kwargs["prefix_embeds"] = jnp.asarray(rng.standard_normal(
-            (args.batch, cfg.n_frontend_tokens, cfg.d_model), np.float32))
-
-    with jax.set_mesh(mesh):
-        t0 = time.monotonic()
-        logits, caches = prefill_fn(params, jnp.asarray(prompts), caches,
-                                    **kwargs)
-        logits.block_until_ready()
-        t_prefill = time.monotonic() - t0
-        key = jax.random.PRNGKey(args.seed)
-        tok = temperature_sample(key, logits, args.temperature)[:, None]
-        out = [tok]
-        prefix = cfg.n_frontend_tokens if cfg.prefix_lm else 0
-        t0 = time.monotonic()
-        for i in range(args.gen_len - 1):
-            t = prefix + args.prompt_len + i
-            logits, caches = decode_fn(params, tok, caches, t)
-            key, sub = jax.random.split(key)
-            tok = temperature_sample(sub, logits, args.temperature)[:, None]
-            out.append(tok)
-        jax.block_until_ready(out[-1])
-        t_decode = time.monotonic() - t0
-
-    gen = np.concatenate([np.asarray(t) for t in out], axis=1)
-    tps = args.batch * (args.gen_len - 1) / max(t_decode, 1e-9)
-    print(f"prefill: {t_prefill:.3f}s for {args.batch}x{args.prompt_len} tok")
-    print(f"decode : {t_decode:.3f}s for {args.gen_len-1} steps "
-          f"({tps:.1f} tok/s)")
-    print(f"sample generations (first 16 ids):\n{gen[:, :16]}")
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--seed", type=int, default=0)
-    # transform-service mode
     ap.add_argument("--shape", default="32,32,32",
                     help="3-D transform shape, e.g. 64,64,64")
     ap.add_argument("--problem", default="mix",
@@ -178,21 +105,9 @@ def main(argv=None):
     ap.add_argument("--measure-after", type=int, default=None,
                     help="dispatches of a key before the background "
                          "measure-mode upgrade")
-    # legacy LM mode
-    ap.add_argument("--arch", default=None,
-                    help="run the legacy LM prefill/decode loop instead")
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen-len", type=int, default=32)
-    ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--kv-block", type=int, default=512)
     args = ap.parse_args(argv)
     use_compile_cache()
-    if args.arch:
-        lm_main(args)
-    else:
-        transforms_main(args)
+    transforms_main(args)
 
 
 if __name__ == "__main__":

@@ -33,9 +33,10 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.distributed import _stage, FFTOptions
+from repro.core.distributed import FFTOptions
 from repro.models.config import MoESpec
 from repro.models.layers import ffn_fwd
+from repro.models.spectral import _croft_stage
 
 
 def _local_dispatch(xt, router_w, m: MoESpec, cap: int):
@@ -120,13 +121,11 @@ def moe_fwd_sharded(params, x, m: MoESpec, *, mesh: Mesh, dp, cp_axis,
             buf, meta = _local_dispatch(xt, router_w, m, cap)  # (E, C, D)
             # CROFT transpose: expert dim scattered out, capacity gathered
             # (E, C, D) -> (E/tp, C*tp, D); chunked for comm/compute overlap
-            buf = _stage(buf, fft_axis=None, comm_axis=tp_axis,
-                         split_axis=0, concat_axis=1, chunk_axis=2,
-                         sign=-1, opts=fft_opts)
+            buf = _croft_stage(buf, comm_axis=tp_axis, split_axis=0,
+                               concat_axis=1, chunk_axis=2, opts=fft_opts)
             y = _experts_swiglu(buf, w_gate, w_up, w_down)
-            y = _stage(y, fft_axis=None, comm_axis=tp_axis,
-                       split_axis=1, concat_axis=0, chunk_axis=2,
-                       sign=-1, opts=fft_opts)
+            y = _croft_stage(y, comm_axis=tp_axis, split_axis=1,
+                             concat_axis=0, chunk_axis=2, opts=fft_opts)
             out = _local_combine(y, meta, bb * ss, d, x_loc.dtype)
             return out.reshape(bb, ss, d)
 

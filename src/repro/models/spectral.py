@@ -20,7 +20,22 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import local_fft
-from repro.core.distributed import _stage  # K-chunked (fft -> all_to_all)
+from repro.core import schedule as schedule_lib
+from repro.core.distributed import FFTOptions
+
+
+def _croft_stage(blk: jax.Array, *, comm_axis: str, split_axis: int,
+                 concat_axis: int, chunk_axis: int,
+                 opts: FFTOptions) -> jax.Array:
+    """One K-chunked CROFT transpose (``schedule.run_stage`` with no FFT)
+    inside ``shard_map``: the sequence FFTs here and the expert dispatch
+    of ``models/moe_sharded.py``."""
+    st = schedule_lib.Stage("ad-hoc", fft_axis=None, comm_axis=comm_axis,
+                            split_axis=split_axis, concat_axis=concat_axis,
+                            chunk_axis=chunk_axis)
+    out = schedule_lib.run_stage(local_fft.to_planes(blk), st, -1, opts,
+                                 off=1)
+    return local_fft.from_planes(out)
 
 
 def _fft_last(x: jax.Array) -> jax.Array:
@@ -49,76 +64,15 @@ def distributed_seq_fft(xc: jax.Array, axis_name: str, mesh, batch_spec,
 
     local (B, S/P, D) --a2a--> (B, S, D/P) --fft(S)--> --a2a--> (B, S/P, D)
     """
-    from repro.core.distributed import FFTOptions
-
     opts = FFTOptions(overlap_k=overlap_k)
 
     def body(blk):  # (B, S/P, D)
-        blk = _stage(blk, fft_axis=None, comm_axis=axis_name, split_axis=2,
-                     concat_axis=1, chunk_axis=0, sign=-1, opts=opts)
+        blk = _croft_stage(blk, comm_axis=axis_name, split_axis=2,
+                           concat_axis=1, chunk_axis=0, opts=opts)
         blk = jnp.moveaxis(_fft_last(jnp.moveaxis(blk, 1, -1)), -1, 1)
-        blk = _stage(blk, fft_axis=None, comm_axis=axis_name, split_axis=1,
-                     concat_axis=2, chunk_axis=0, sign=-1, opts=opts)
+        blk = _croft_stage(blk, comm_axis=axis_name, split_axis=1,
+                           concat_axis=2, chunk_axis=0, opts=opts)
         return blk
 
     spec = P(batch_spec, axis_name, None)
     return shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)(xc)
-
-
-# --------------------------------------------------------------------------
-# Learned spectral filter — the CROFT-side training workload
-# --------------------------------------------------------------------------
-#
-# A two-parameter "spectral layer" over a distributed 3-D field:
-#
-#     y_hat(theta; x) = F( gate . x ) . filter
-#
-# with a learnable real-space gate (full grid) and a learnable k-space
-# filter (half spectrum for r2c plans, full for c2c).  The transform is
-# a planned Croft3D: the k-space multiply fuses as the plan's spectral
-# epilogue (``forward_filtered``) and gradients replay the *adjoint
-# schedule* (``repro.grad``) instead of XLA differentiating through
-# shard_map collectives.  This is the workload ``tuned(grad=True)``
-# plans for and ``benchmarks/train_bench.py`` gates.
-
-
-def spectral_filter_shapes(plan) -> tuple:
-    """(gate shape, filter shape) for a plan's learned spectral layer."""
-    return tuple(plan.shape), tuple(plan.spectrum_shape)
-
-
-def init_spectral_filter_params(key, plan, scale: float = 0.0,
-                                dtype=jnp.float32):
-    """Near-identity init: gate = 1 + scale*eps, filter = 1 + scale*eps.
-
-    Real parameters in both domains (a real filter is the common
-    physical case — attenuation per mode); ``scale=0`` gives the exact
-    identity layer, useful as a deterministic oracle start.
-    """
-    gshape, fshape = spectral_filter_shapes(plan)
-    kg, kf = jax.random.split(key)
-    dt = jnp.dtype(dtype)
-    return {
-        "gate": (jnp.ones(gshape, dt)
-                 + scale * jax.random.normal(kg, gshape, dt)),
-        "filter": (jnp.ones(fshape, dt)
-                   + scale * jax.random.normal(kf, fshape, dt)),
-    }
-
-
-def place_spectral_filter_params(plan, params):
-    """Shard the layer's params the way the plan wants its operands: the
-    gate with the input field, the filter with the output spectrum."""
-    if plan.mesh is None:
-        return params
-    return {
-        "gate": jax.device_put(params["gate"], plan.input_sharding),
-        "filter": jax.device_put(params["filter"], plan.output_sharding),
-    }
-
-
-def spectral_filter_apply(plan, params, x: jax.Array) -> jax.Array:
-    """``F(gate . x) . filter`` through the plan's fused epilogue."""
-    gated = (params["gate"] * x).astype(plan.input_dtype)
-    h = params["filter"].astype(plan.dtype)
-    return plan.forward_filtered(gated, h)

@@ -1,11 +1,10 @@
 """Metrics registry: counters, gauges, log-bucketed histograms.
 
 One named registry per subsystem (the transform service owns one; the
-tuner and benchmarks share the process-default one).  Two export
+tuner and the wisdom store share the process-default one).  Two export
 formats from the same objects:
 
-  * :meth:`MetricsRegistry.snapshot` — JSON-able dict, embedded into
-    ``BENCH_*.json`` so bench artifacts and live metrics share a schema;
+  * :meth:`MetricsRegistry.snapshot` — JSON-able dict;
   * :meth:`MetricsRegistry.to_prometheus` — Prometheus text exposition
     (``# TYPE`` headers, ``_bucket{le=...}`` cumulative histograms) for
     scraping a long-running service.

@@ -13,8 +13,8 @@ down.  The ladder (most to least sophisticated):
 Every rung is bitwise-equal to every other on finite inputs (the
 transpose-impl/K/strategy parity matrix pinned since PR 5), so walking
 down trades only performance, never correctness — which is exactly what
-``benchmarks/chaos_bench.py`` gates: a degraded bucket's results must
-equal the direct fallback-plan transform bit for bit.
+``tests/test_resil.py``'s chaos script asserts: a degraded bucket's
+results must equal the direct fallback-plan transform bit for bit.
 
 All repro imports are function-local so this module is importable from
 anywhere (``repro.core`` included) without import cycles.

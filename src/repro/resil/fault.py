@@ -1,16 +1,10 @@
-"""Fault tolerance: preemption handling, straggler detection, elastic
-re-meshing.
+"""Process-level faults: preemption handling and straggler detection.
 
-At 1000+ nodes three failure classes dominate (DESIGN.md §4):
-  * planned preemption  -> SIGTERM handler flips a flag; the train loop
-    checkpoints and exits cleanly at the next step boundary;
-  * node loss           -> restart picks up the latest checkpoint and, if
-    the device count changed, restores onto a *new* mesh (checkpoints store
-    logical shapes only — see checkpoint.py);
-  * stragglers          -> per-step wall times feed an EMA z-score monitor;
-    flagged hosts are logged and (policy hook) can be drained or have their
-    data shards reassigned — reassignment is trivial because the data
-    pipeline is stateless in (seed, step, shard).
+  * planned preemption  -> a SIGTERM handler flips a flag;
+    ``TransformService(preemption=PreemptionHandler())`` serves what is
+    pending, stops cleanly and refuses new work;
+  * stragglers          -> per-step wall times feed an EMA z-score
+    monitor; flagged steps are logged and passed to a policy hook.
 """
 
 from __future__ import annotations
@@ -21,13 +15,9 @@ import signal
 import time
 from typing import Callable, Optional
 
-import jax
-
-from repro.launch.mesh import make_mesh
-
 
 class PreemptionHandler:
-    """SIGTERM/SIGINT -> graceful checkpoint-and-exit flag."""
+    """SIGTERM/SIGINT -> graceful drain-and-exit flag."""
 
     def __init__(self, signals=(signal.SIGTERM,)):
         self._requested = False
@@ -103,13 +93,3 @@ class StragglerMonitor:
             if self.on_straggler:
                 self.on_straggler(stats)
         return stats
-
-
-def elastic_mesh(axis_names=("data", "model"), prefer_model: int = 16):
-    """Build the largest valid mesh from the devices that are actually
-    alive — the restart path after losing nodes.  Keeps the model axis at
-    ``prefer_model`` when divisible, shrinking the data axis."""
-    n = len(jax.devices())
-    model = math.gcd(n, prefer_model)
-    data = n // model
-    return make_mesh((data, model), axis_names)

@@ -19,10 +19,9 @@ shape diversity.
                           measure_after=32) as svc:
         spectrum = svc.transform(field, problem="r2c")
 
-Benchmarked by ``benchmarks/serve_bench.py`` (``BENCH_serve.json``):
-p50/p99 latency vs offered QPS under a synthetic open-loop load, batch
-occupancy, plan-cache hit rate, and a deterministic collective-count
-batching gate.
+``tests/test_serve.py::test_batched_forward_compiles_to_single_request_collectives``
+holds the batching premise: a (B, ...) stacked dispatch compiles to
+the single request's collective counts, with bytes scaled by B.
 """
 
 from repro.serve.batcher import Batcher, Bucket, padded_size, stack_and_pad

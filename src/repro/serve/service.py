@@ -76,7 +76,7 @@ class TransformService:
         self.dispatch_retries = dispatch_retries
         self.retry_backoff_s = retry_backoff_s
         self.nan_guard = nan_guard
-        #: train.fault.PreemptionHandler (or None): when its flag flips
+        #: resil.fault.PreemptionHandler (or None): when its flag flips
         #: (SIGTERM), the worker drains pending buckets and stops cleanly
         self.preemption = preemption
         # every serving number lives in the metrics registry (repro.obs);

@@ -1,12 +1,9 @@
-"""Training/serving substrate: optimizer, steps, data, checkpoints, fault
-tolerance."""
+"""Training/serving substrate: optimizer, steps, data, checkpoints."""
 
 from repro.train.optimizer import OptConfig, adamw_update, init_opt_state
 from repro.train.train_step import (init_train_state, loss_fn,
                                     make_serve_steps, make_shard_ctx,
-                                    make_spectral_train_step,
-                                    make_train_step, spectral_loss_fn)
+                                    make_train_step)
 
 __all__ = ["OptConfig", "adamw_update", "init_opt_state", "init_train_state",
-           "loss_fn", "make_serve_steps", "make_shard_ctx",
-           "make_spectral_train_step", "make_train_step", "spectral_loss_fn"]
+           "loss_fn", "make_serve_steps", "make_shard_ctx", "make_train_step"]

@@ -149,48 +149,6 @@ def make_train_step(cfg: ModelConfig, opt_cfg: opt_lib.OptConfig,
 
 
 # --------------------------------------------------------------------------
-# spectral-layer training (the CROFT gradient workload)
-# --------------------------------------------------------------------------
-
-
-def spectral_loss_fn(plan, params, x, target):
-    """Normalized spectral MSE of the learned filter layer
-    (``repro.models.spectral``) against a target half/full spectrum.
-
-    Normalizing by N^3 undoes the unnormalized forward transform's
-    energy blow-up (Parseval), so per-mode curvature w.r.t. the filter
-    is O(1) and plain SGD converges with an O(0.1) learning rate.
-    """
-    from repro.models import spectral as spectral_lib
-    pred = spectral_lib.spectral_filter_apply(plan, params, x)
-    d = pred - target
-    n3 = float(plan.shape[0] * plan.shape[1] * plan.shape[2])
-    return jnp.sum(jnp.real(d * jnp.conj(d))) / n3
-
-
-def make_spectral_train_step(plan, lr: float = 0.05):
-    """SGD step for the learned spectral filter over a planned transform.
-
-    Returns ``(step, loss_fn)``: ``step(params, x, target) -> (params,
-    loss)`` is jitted; ``loss_fn(params, x, target)`` is the raw scalar
-    loss (what the benchmark differentiates for its oracle checks).
-    Gradients flow through the plan's custom VJP — the backward pass
-    replays the tuned schedule's adjoint (``repro.grad``), which is what
-    ``Croft3D.tuned(grad=True)`` optimizes for.
-    """
-
-    def loss_fn(params, x, target):
-        return spectral_loss_fn(plan, params, x, target)
-
-    def step(params, x, target):
-        loss, grads = jax.value_and_grad(loss_fn)(params, x, target)
-        new = jax.tree.map(lambda p, g: p - lr * g, params, grads)
-        return new, loss
-
-    return jax.jit(step), loss_fn
-
-
-# --------------------------------------------------------------------------
 # serving
 # --------------------------------------------------------------------------
 
@@ -227,13 +185,3 @@ def make_serve_steps(cfg: ModelConfig, mesh: Optional[Mesh],
 
     return (jax.jit(prefill, donate_argnums=(2,)),
             jax.jit(decode, donate_argnums=(2,)))
-
-
-def greedy_sample(logits: jax.Array) -> jax.Array:
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-
-def temperature_sample(key, logits: jax.Array, temperature: float = 1.0):
-    if temperature == 0.0:
-        return greedy_sample(logits)
-    return jax.random.categorical(key, logits / temperature).astype(jnp.int32)

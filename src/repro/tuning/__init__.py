@@ -45,25 +45,21 @@ searches per-stage ``local_impl`` 3-tuples, and ``batch=B`` plans for
 vmapped transforms (volume terms scale by B, collective launch counts do
 not; the wisdom key gains ``|b{B}``).
 
-The collective cost constants (alpha latency / beta inverse-bandwidth)
-are calibrated, not guessed, when data exists: ``benchmarks/
-collective_profile.py`` publishes its fitted alpha/beta to the metrics
-registry and a calibration JSON (``$CROFT_CALIBRATION``), and
-``cost_model.collective_constants`` picks them up with hardcoded
-fallbacks.
+The collective cost constants (alpha latency, beta inverse bandwidth)
+are the priors ``cost_model.COLLECTIVE_LATENCY_S`` and
+``1 / cost_model.PRIOR_LINK_BW``; fitting them to chip traces is ROADMAP
+queue 1, item 8.
 
-Entry points: :func:`tune` below, ``Croft3D.tuned(...)`` /
-``Croft3D(..., tune="model")`` in ``repro.core.api``, and the
-``benchmarks/tuning_bench.py`` / ``benchmarks/rfft_bench.py`` sweeps
-(``BENCH_tuning.json`` / ``BENCH_rfft.json``).
+Entry points: :func:`tune` below, and ``Croft3D.tuned(...)`` /
+``Croft3D(..., tune="model")`` in ``repro.core.api``.
 """
 
 from repro.tuning.candidates import (PROBLEMS, Candidate, default_candidate,
                                      decompositions_for, enumerate_candidates,
                                      split_grad)
 from repro.tuning.cost_model import (CostBreakdown, analytic_cost,
-                                     collective_constants, hlo_collectives,
-                                     per_stage_costs, rank_candidates)
+                                     hlo_collectives, per_stage_costs,
+                                     rank_candidates)
 from repro.tuning.measure import (measure_candidate, time_forward,
                                   time_train_step)
 from repro.tuning.planner import MODES, TuneResult, tune, upgrade_wisdom
@@ -72,8 +68,8 @@ from repro.tuning.wisdom import (Wisdom, WisdomEntry, load_seed,
 
 __all__ = [
     "Candidate", "CostBreakdown", "MODES", "PROBLEMS", "TuneResult",
-    "Wisdom", "WisdomEntry", "analytic_cost", "collective_constants",
-    "decompositions_for", "default_candidate", "enumerate_candidates",
+    "Wisdom", "WisdomEntry", "analytic_cost", "decompositions_for",
+    "default_candidate", "enumerate_candidates",
     "hlo_collectives", "load_seed", "measure_candidate", "merge_entries",
     "per_stage_costs", "rank_candidates", "split_grad", "time_forward",
     "time_train_step", "tune", "upgrade_wisdom", "wisdom_key",

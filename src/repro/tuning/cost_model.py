@@ -78,64 +78,8 @@ IMPL_EFFICIENCY = {
 }
 _DEFAULT_EFFICIENCY = 0.08
 LOCAL_PASSES = 10          # HBM round trips over the local block
-COLLECTIVE_LATENCY_S = 2e-6
+COLLECTIVE_LATENCY_S = 2e-6  # alpha: seconds per collective launch
 REPLAN_PASSES = 6          # twiddle re-materialization, options 1/3
-
-#: environment variable naming a calibration JSON (written by
-#: ``benchmarks/collective_profile.py``) with fitted
-#: ``collective_alpha_s`` / ``collective_beta_s_per_byte``
-CALIBRATION_ENV = "CROFT_CALIBRATION"
-_calibration_file_cache: dict = {}
-
-
-def _calibration_from_file() -> Optional[tuple]:
-    import json
-    import os
-    path = os.environ.get(CALIBRATION_ENV)
-    if not path or not os.path.exists(path):
-        return None
-    try:
-        mtime = os.path.getmtime(path)
-        cached = _calibration_file_cache.get(path)
-        if cached is not None and cached[0] == mtime:
-            return cached[1]
-        with open(path) as f:
-            d = json.load(f)
-        vals = (float(d["collective_alpha_s"]),
-                float(d["collective_beta_s_per_byte"]))
-        _calibration_file_cache[path] = (mtime, vals)
-        return vals
-    except (OSError, ValueError, KeyError, TypeError):
-        return None
-
-
-def collective_constants() -> tuple:
-    """(alpha seconds-per-launch, beta seconds-per-byte) for collectives.
-
-    Precedence: live calibration published through the ``repro.obs``
-    metrics registry (``benchmarks/collective_profile.py``'s lstsq fit —
-    gauges ``collective_alpha_s`` / ``collective_beta_s_per_byte``) >
-    a saved calibration JSON named by ``$CROFT_CALIBRATION`` > the
-    hardcoded roofline constants.  Non-positive fits are ignored (a
-    degenerate lstsq on noisy walls can go negative — the hardcoded
-    floor is better than a nonsense model).
-    """
-    alpha, beta = COLLECTIVE_LATENCY_S, 1.0 / PRIOR_LINK_BW
-    file_vals = _calibration_from_file()
-    if file_vals is not None:
-        fa, fb = file_vals
-        alpha = fa if fa > 0 else alpha
-        beta = fb if fb > 0 else beta
-    try:
-        from repro.obs import metrics as metrics_lib
-        reg = metrics_lib.get_registry()
-        ga = reg.gauge("collective_alpha_s").value
-        gb = reg.gauge("collective_beta_s_per_byte").value
-        alpha = ga if ga and ga > 0 else alpha
-        beta = gb if gb and gb > 0 else beta
-    except Exception:
-        pass
-    return alpha, beta
 
 
 @dataclasses.dataclass(frozen=True)
@@ -237,7 +181,7 @@ def _schedule_cost(shape: Sequence[int], cand: Candidate, sched: Schedule,
     decomp, opts = cand.decomp, cand.opts
     itemsize = jnp.dtype(dtype).itemsize
     p = decomp.n_procs(axis_sizes)
-    alpha, beta = collective_constants()
+    alpha, beta = COLLECTIVE_LATENCY_S, 1.0 / PRIOR_LINK_BW
 
     # compute: one event per local FFT, at the schedule's reported size
     flops = 0.0
@@ -339,7 +283,7 @@ def _searched_schedule_cost(shape: Sequence[int], cand, sched: Schedule,
     opts = cand.opts
     itemsize = jnp.dtype(dtype).itemsize
     p = cand.decomp.n_procs(axis_sizes)
-    alpha, beta = collective_constants()
+    alpha, beta = COLLECTIVE_LATENCY_S, 1.0 / PRIOR_LINK_BW
 
     flops = 0.0
     compute_s = 0.0
@@ -408,7 +352,7 @@ def _searched_schedule_cost(shape: Sequence[int], cand, sched: Schedule,
 def predicted_collectives(sched: Schedule, shape: Sequence[int],
                           axis_sizes: Mapping[str, int], opts) -> dict:
     """Per-kind collective-op counts the executor will emit for this
-    schedule — what ``benchmarks/search_bench.py`` pins compiled HLO
+    schedule — what ``tests/test_schedule_search.py`` pins compiled HLO
     against: one ``all-to-all`` per effective chunk of a fused stage,
     ``K_eff * (P-1)`` ``collective-permute`` rounds for ring/pairwise,
     one fused all-to-all per out-of-body reshard."""
@@ -457,7 +401,7 @@ def _stage_rows(shape, cand, sched, axis_sizes, dtype, batch,
                 direction) -> list:
     opts = cand.opts
     itemsize = jnp.dtype(dtype).itemsize
-    _, beta = collective_constants()
+    beta = 1.0 / PRIOR_LINK_BW
     eff_ks = iter(sched.effective_k(shape, axis_sizes, opts.overlap_k))
 
     from repro.core.schedule import (_flat, stage_category,

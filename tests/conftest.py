@@ -7,6 +7,7 @@ those children are CPU-mesh rehearsals pinned to ``JAX_PLATFORMS=cpu``, so
 they never compete with a parent for an accelerator.
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -32,6 +33,14 @@ def run_multidevice(code: str, n_devices: int = 8, timeout: int = 480) -> str:
             f"subprocess failed (rc={proc.returncode})\n"
             f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr[-4000:]}")
     return proc.stdout
+
+
+def multidevice_report(code: str, **kw) -> dict:
+    """``run_multidevice`` for a snippet that prints one ``REPORT <json>``
+    line: the parsed JSON, for several tests to assert on."""
+    out = run_multidevice(code, **kw)
+    line = next(ln for ln in out.splitlines() if ln.startswith("REPORT "))
+    return json.loads(line[len("REPORT "):])
 
 
 @pytest.fixture(scope="session")

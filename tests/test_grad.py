@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from conftest import run_multidevice
+from conftest import multidevice_report, run_multidevice
 from repro.core import fft3d, ifft3d, irfft3d, rfft3d
 
 
@@ -213,3 +213,134 @@ rel_x = (float(jnp.max(jnp.abs(gx1 - gx0)))
 assert rel_x < 1e-4, rel_x
 print("OK folded filter fwd+grads", rel_y, rel_g, rel_x)
 """, timeout=900)
+
+
+# --- training through the transform (repro.grad.filter) ----------------------
+#
+# The learned spectral filter over a packed r2c plan on a 4x2 pencil,
+# trained with seeded SGD, and its gradients held against two oracles
+# that share no code with the adjoint schedules.
+
+_FILTER_CODE = """
+import json
+import numpy as np, jax, jax.numpy as jnp
+from repro.core import Croft3D, Decomposition, FFTOptions
+from repro.grad.filter import (init_spectral_filter_params,
+                               make_spectral_train_step,
+                               place_spectral_filter_params,
+                               spectral_filter_apply, spectral_loss_fn)
+from repro.launch import hlo_cost
+from repro.launch.mesh import make_mesh
+from repro.tuning import Candidate, per_stage_costs
+
+N, STEPS = 16, 10
+shape = (N, N, N)
+mesh = make_mesh((4, 2), ("y", "x"))
+dec = Decomposition("pencil", ("y", "x"))
+report = {}
+
+def collective_counts(fn, *args):
+    txt = jax.jit(fn).lower(*args).compile().as_text()
+    return {k: int(v["count"])
+            for k, v in hlo_cost.analyze(txt).collectives.items()}
+
+plan = Croft3D(shape, mesh, dec, FFTOptions(), problem="r2c",
+               strategy="packed")
+rng = np.random.RandomState(0)
+x = jax.device_put(jnp.asarray(rng.randn(*shape), plan.input_dtype),
+                   plan.input_sharding)
+true = place_spectral_filter_params(plan, {
+    "gate": jnp.asarray(1.0 + 0.3 * rng.randn(*shape), jnp.float32),
+    "filter": jnp.asarray(1.0 + 0.3 * rng.randn(*plan.spectrum_shape),
+                          jnp.float32)})
+target = spectral_filter_apply(plan, true, x)
+step, loss_fn = make_spectral_train_step(plan, lr=0.05)
+params = place_spectral_filter_params(
+    plan, init_spectral_filter_params(jax.random.PRNGKey(1), plan))
+losses = []
+for _ in range(STEPS):
+    params, loss = step(params, x, target)
+    losses.append(float(loss))
+report["losses"] = losses
+
+# oracle 1: central finite differences; the loss is quadratic along any
+# single-coordinate line, so they are exact up to float32 rounding
+g = jax.jit(jax.grad(loss_fn))(params, x, target)
+report["fd_rel"] = {}
+for field in ("gate", "filter"):
+    rel = 0.0
+    for ij in [(1, 2, 3), (0, 0, 0), (3, 1, 2)]:
+        def loss_at(v):
+            pp = dict(params)
+            pp[field] = params[field].at[ij].add(v)
+            return float(loss_fn(pp, x, target))
+        fd = (loss_at(0.5) - loss_at(-0.5)) / 1.0
+        an = float(g[field][ij])
+        rel = max(rel, abs(fd - an) / max(abs(fd), abs(an), 1e-6))
+    report["fd_rel"][field] = rel
+
+# oracle 2: the embed strategy, XLA autodiff over the Hermitian glue
+embed = Croft3D(shape, mesh, dec, FFTOptions(), problem="r2c",
+                strategy="embed")
+ge = jax.jit(jax.grad(lambda p, v, t: spectral_loss_fn(embed, p, v, t)))(
+    params, jax.device_put(x, embed.input_sharding), target)
+report["embed_rel"] = {
+    f: float(jnp.linalg.norm(g[f] - ge[f])) / float(jnp.linalg.norm(g[f]))
+    for f in ("gate", "filter")}
+
+# the c2c core's backward compiles to the forward's collectives, and its
+# all-to-all count is the adjoint schedule's per-stage prediction
+report["hlo"] = {}
+for tag, opts in {
+    "alltoall-natural": FFTOptions(),
+    "alltoall-spectral": FFTOptions(output_layout="spectral"),
+    "ring": FFTOptions(output_layout="spectral", transpose_impl="ring"),
+    "pairwise": FFTOptions(output_layout="spectral",
+                           transpose_impl="pairwise"),
+}.items():
+    cplan = Croft3D(shape, mesh, dec, opts)
+    xc = jax.device_put(jnp.zeros(shape, jnp.complex64), cplan.input_sharding)
+    y, pull = jax.vjp(cplan._fwd, xc)
+    rec = {"fwd": collective_counts(cplan._fwd, xc),
+           "bwd": collective_counts(pull, jnp.ones_like(y))}
+    if opts.transpose_impl == "alltoall":
+        rows = per_stage_costs(shape, Candidate(dec, opts, problem="c2c_grad"),
+                               dict(mesh.shape))
+        rec["predicted_bwd_all_to_all"] = sum(
+            int(r["k_eff"]) for r in rows
+            if r["direction"] == "bwd" and r["collective_s"] > 0)
+    report["hlo"][tag] = rec
+print("REPORT " + json.dumps(report))
+"""
+
+
+@pytest.fixture(scope="module")
+def filter_training():
+    return multidevice_report(_FILTER_CODE, timeout=900)
+
+
+def test_filter_training_loss_decreases_and_halves(filter_training):
+    losses = filter_training["losses"]
+    assert all(b < a for a, b in zip(losses, losses[1:])), losses
+    assert losses[-1] < 0.5 * losses[0], losses
+
+
+@pytest.mark.parametrize("field", ["gate", "filter"])
+def test_filter_grad_matches_finite_differences(filter_training, field):
+    assert filter_training["fd_rel"][field] < 1e-2
+
+
+@pytest.mark.parametrize("field", ["gate", "filter"])
+def test_filter_packed_grad_matches_embed(filter_training, field):
+    """Two gradient routes through different code: the packed plan's
+    adjoint schedule and XLA autodiff over the embed strategy."""
+    assert filter_training["embed_rel"][field] < 1e-4
+
+
+@pytest.mark.parametrize("tag", ["alltoall-natural", "alltoall-spectral",
+                                 "ring", "pairwise"])
+def test_filter_backward_collectives_mirror_adjoint(filter_training, tag):
+    rec = filter_training["hlo"][tag]
+    assert rec["fwd"] and rec["bwd"] == rec["fwd"], rec
+    if "predicted_bwd_all_to_all" in rec:
+        assert rec["bwd"]["all-to-all"] == rec["predicted_bwd_all_to_all"]

@@ -5,7 +5,8 @@ deadlines, retries, NaN isolation, preemption, upgrade rollback, wisdom
 integrity) on meshless plans; the distributed story — HLO byte-identity
 with an armed injector, executor-output poisoning, quarantine -> ladder
 degradation with bitwise fallback parity — runs once in an 8-virtual-
-device subprocess.
+device subprocess, and a seeded chaos script over every fault kind runs
+in one more.
 """
 
 import json
@@ -21,11 +22,12 @@ from repro.core import Croft3D
 from repro.resil import (CrashMidWrite, FaultPlan, FaultSpec, InjectedFault,
                          TransientFault, degrade, inject, injection,
                          seeded_times)
+from repro.resil.fault import PreemptionHandler, StragglerMonitor
 from repro.serve import (PRIORITY_HIGH, PRIORITY_LOW, PlanCache, ShedResult,
                          TransformService)
 from repro.tuning import wisdom as wisdom_lib
 from repro.tuning.candidates import default_candidate
-from conftest import run_multidevice
+from conftest import multidevice_report, run_multidevice
 
 N = 8
 
@@ -271,10 +273,30 @@ def test_nan_payload_isolated_healthy_batchmates_redispatch(rng):
         assert snap["serve_poison_redispatches"]["value"] == 2
 
 
+def test_straggler_monitor_flags_outlier():
+    mon = StragglerMonitor(z_threshold=3.0, warmup_steps=2)
+    flagged = []
+    mon.on_straggler = flagged.append
+    for i in range(10):
+        mon.start_step()
+        mon._t0 -= 0.1  # simulate 100ms step
+        mon.end_step(i)
+    mon.start_step()
+    mon._t0 -= 3.0      # 3s straggler
+    st = mon.end_step(99)
+    assert st.is_straggler and flagged and flagged[0].step == 99
+
+
+def test_preemption_handler_flag():
+    h = PreemptionHandler()
+    assert not h.preemption_requested
+    h._handle(15, None)
+    assert h.preemption_requested
+
+
 def test_preemption_drains_and_refuses_new_work(rng):
     """Satellite 3: SIGTERM flips the PreemptionHandler flag; the worker
     serves everything pending, stops cleanly, and submit() refuses."""
-    from repro.train.fault import PreemptionHandler
     old = signal.getsignal(signal.SIGTERM)
     try:
         svc = TransformService(max_batch=8, max_wait_ms=60000.0,
@@ -427,3 +449,206 @@ print("RESIL_MULTIDEVICE_OK")
 def test_resil_multidevice_poison_quarantine_parity():
     out = run_multidevice(_MULTIDEVICE_CODE, n_devices=8, timeout=480)
     assert "RESIL_MULTIDEVICE_OK" in out
+
+
+# --- the seeded chaos script ------------------------------------------------
+#
+# One deterministic fault script through a TransformService on the 2x4
+# mesh.  Every fault is armed at an exact invocation index, so the
+# resilience events it must cause are known before the run:
+#   A. transient dispatch faults on the r2c bucket at attempts 0, 1
+#      -> 2 retries, then success;
+#   B. persistent dispatch faults on the primary c2c bucket with
+#      quarantine_after=2 -> 2 failures, 1 quarantine, 1 degradation,
+#      then bitwise fallback parity;
+#   C. one NaN payload co-batched with two healthy requests -> 1 poisoned
+#      request, 2 individual re-dispatches;
+#   D. a deadline storm (6 requests, deadline_s=0) -> 6 typed misses;
+#   E. bounded-queue shedding (max_queue=4, 4 HIGH + 3 LOW pending)
+#      -> exactly the 3 LOWs shed;
+#   F. one wisdom-store corruption + one crash mid-write -> 1 quarantined
+#      file, the store stays loadable.
+
+_CHAOS_CODE = """
+import concurrent.futures, json, os, tempfile
+import numpy as np, jax
+
+from repro.core import Croft3D
+from repro.obs import metrics as metrics_lib
+from repro.resil import CrashMidWrite, FaultSpec, degrade, injection
+from repro.serve import (PRIORITY_HIGH, PRIORITY_LOW, PlanCache, ShedResult,
+                         TransformService)
+from repro.tuning import wisdom as wisdom_lib
+from repro.tuning.candidates import default_candidate
+from repro.launch.mesh import make_mesh
+
+N = 16
+AXES = {"y": 2, "z": 4}
+mesh = make_mesh((2, 4), ("y", "z"))
+rng = np.random.RandomState(0)
+xc = (rng.randn(N, N, N) + 1j * rng.randn(N, N, N)).astype(np.complex64)
+xr = rng.randn(N, N, N).astype(np.float32)
+
+resolved, hung = [], []
+healthy = []     # (label, ok, bitwise parity or None)
+predicted = {}   # counter name -> exact predicted value
+
+def resolve(label, fut):
+    try:
+        r = fut.result(timeout=120)
+    except concurrent.futures.TimeoutError:
+        hung.append(label)
+        return None
+    resolved.append(label)
+    return r
+
+def served(label, r, ref=None):
+    ok = r is not None and r.ok
+    healthy.append((label, ok, None if ref is None
+                    else ok and bool(np.array_equal(r.value, ref))))
+
+# the primary c2c plan comes from measured wisdom, so it is born warm
+# and never arms a background upgrade: the stock K=2 candidate, one rung
+# above the ladder's K=1 bottom
+wisdom = os.path.join(tempfile.mkdtemp(), "w.json")
+cand = default_candidate((N, N, N), AXES)
+key_c2c = wisdom_lib.wisdom_key((N, N, N), AXES, np.complex64,
+                                jax.default_backend())
+wisdom_lib.merge_entries(wisdom, {key_c2c: wisdom_lib.WisdomEntry.from_candidate(
+    cand, source="measure", measured_s=1e-3)})
+
+reg = metrics_lib.MetricsRegistry()
+cache = PlanCache(mesh, wisdom_path=wisdom, quarantine_after=2, registry=reg)
+svc = TransformService(mesh, max_batch=4, max_wait_ms=150.0, cache=cache,
+                       registry=reg, retry_backoff_s=0.0)
+svc.start()
+# the scripted error matches the primary plan's token only: once the
+# quarantine swaps the bottom rung in, the fault stops matching
+token_c2c = cache.get((N, N, N), np.complex64, "c2c").plan_token
+script = [
+    FaultSpec("serve.dispatch", times=(0, 1), kind="transient", match="|r2c"),
+    FaultSpec("serve.dispatch", times=(0, 1), kind="error", match=token_c2c),
+]
+with injection(script) as fault_plan:
+    r = resolve("A:r2c", svc.submit(xr, problem="r2c"))           # A
+    plan_r = cache.get((N, N, N), np.complex64, "r2c").plan
+    served("A:r2c", r, np.asarray(plan_r.forward(jax.device_put(
+        xr.astype(plan_r.input_dtype), plan_r.input_sharding))))
+    predicted["serve_dispatch_retries"] = 2
+    storm = [resolve(f"B:storm{i}", svc.submit(xc)) for i in range(2)]  # B
+    predicted["plan_dispatch_failures"] = 2
+    predicted["plan_quarantines"] = 1
+    predicted["plan_degradations"] = 1
+
+bottom = degrade.bottom_candidate((N, N, N), AXES)
+fallback = Croft3D((N, N, N), mesh, bottom.decomp, bottom.opts)
+ref_c = np.asarray(fallback.forward(jax.device_put(xc, fallback.input_sharding)))
+cp = cache.get((N, N, N), np.complex64, "c2c")
+for i in range(2):
+    served(f"B:degraded{i}", resolve(f"B:degraded{i}", svc.submit(xc)), ref_c)
+
+bad = xc.copy()                                                       # C
+bad[0, 0, 0] = np.nan
+f_bad = svc.submit(bad)
+f_mates = [svc.submit(xc) for _ in range(2)]
+r_bad = resolve("C:poisoned", f_bad)
+for i, f in enumerate(f_mates):
+    served(f"C:mate{i}", resolve(f"C:mate{i}", f), ref_c)
+predicted["serve_poisoned_requests"] = 1
+predicted["serve_poison_redispatches"] = 2
+predicted["serve_nan_outputs"] = 0
+predicted["serve_failures"] = 2 + 1   # B's storm + C's poisoned request
+
+misses = [resolve(f"D:storm{i}", svc.submit(xc, deadline_s=0.0))      # D
+          for i in range(6)]
+predicted["serve_deadline_misses"] = 6
+
+# E: a meshless service whose 60 s wait budget keeps everything pending
+svc2 = TransformService(max_batch=8, max_wait_ms=60000.0, max_queue=4)
+svc2.start()
+x8 = (rng.randn(8, 8, 8) + 1j * rng.randn(8, 8, 8)).astype(np.complex64)
+highs = [svc2.submit(x8, priority=PRIORITY_HIGH) for _ in range(4)]
+lows = [resolve(f"E:low{i}", svc2.submit(x8, priority=PRIORITY_LOW))
+        for i in range(3)]
+svc2.stop()  # the drain serves the HIGHs
+for i, f in enumerate(highs):
+    served(f"E:high{i}", resolve(f"E:high{i}", f))
+predicted["serve_shed_requests"] = 3
+svc.stop()
+
+blob = json.load(open(wisdom))                                        # F
+blob["entries"][key_c2c]["model_s"] = 1e9   # tamper: checksum now stale
+json.dump(blob, open(wisdom, "w"))
+loaded_after_tamper = len(wisdom_lib.Wisdom.load(wisdom))
+entry = wisdom_lib.WisdomEntry.from_candidate(cand, source="model",
+                                              model_s=1e-3)
+crashed = False
+try:
+    with injection([FaultSpec("wisdom.write.crash", times=(0,),
+                              kind="crash")]):
+        wisdom_lib.merge_entries(wisdom, {key_c2c: entry})
+except CrashMidWrite:
+    crashed = True
+tmp_left = os.path.exists(wisdom + ".tmp")
+wisdom_lib.merge_entries(wisdom, {key_c2c: entry})
+predicted["wisdom_corrupt_files"] = 1
+
+snaps = (reg.snapshot(), svc2.registry.snapshot(),
+         metrics_lib.get_registry().snapshot())
+observed = {name: int(sum(s[name]["value"] for s in snaps if name in s))
+            for name in predicted}
+print("REPORT " + json.dumps({
+    "resolved": len(resolved), "hung": hung,
+    "healthy": healthy, "predicted": predicted, "observed": observed,
+    "fired": fault_plan.fired_counts(),
+    "predicted_fired": fault_plan.predicted_counts(),
+    "storm_failed": [r is not None and not r.ok for r in storm],
+    "degraded": [cp.rung, cp.quarantined],
+    "poisoned": r_bad is not None and not r_bad.ok
+                and "poisoned payload" in r_bad.error,
+    "deadline_misses": [isinstance(r, ShedResult)
+                        and r.shed_reason == "deadline" for r in misses],
+    "lows_shed": [isinstance(r, ShedResult) for r in lows],
+    "wisdom": {"tamper_quarantined": loaded_after_tamper == 0
+                   and os.path.exists(wisdom + ".corrupt-1"),
+               "crash_left_tmp": crashed and tmp_left,
+               "rebuilt": sorted(wisdom_lib.Wisdom.load(wisdom).entries)
+                   == [key_c2c],
+               "tmp_cleaned": not os.path.exists(wisdom + ".tmp")},
+}))
+"""
+
+
+@pytest.fixture(scope="module")
+def chaos():
+    return multidevice_report(_CHAOS_CODE)
+
+
+def test_chaos_script_counters_match_prediction(chaos):
+    """Every injected fault maps to exactly one retry / quarantine /
+    shed / degradation event: never zero, never two."""
+    assert chaos["observed"] == chaos["predicted"]
+    assert chaos["storm_failed"] == [True, True]
+    assert chaos["degraded"] == ["default", True]
+    assert chaos["poisoned"]
+    assert chaos["deadline_misses"] == [True] * 6
+    assert chaos["lows_shed"] == [True] * 3
+    assert all(chaos["wisdom"].values()), chaos["wisdom"]
+
+
+def test_chaos_script_faults_fired_as_predicted(chaos):
+    assert chaos["fired"] == chaos["predicted_fired"] == {"serve.dispatch": 4}
+
+
+def test_chaos_script_healthy_requests_available_and_bitwise(chaos):
+    """Every request the script did not target succeeds, bitwise equal
+    to the direct plan call (the degraded bucket to the bottom rung)."""
+    healthy = chaos["healthy"]
+    assert len(healthy) == 1 + 2 + 2 + 4
+    assert all(ok for _, ok, _ in healthy), healthy
+    assert all(p for _, _, p in healthy if p is not None), healthy
+
+
+def test_chaos_script_leaves_no_hung_future(chaos):
+    assert chaos["hung"] == []
+    assert chaos["resolved"] == 1 + 2 + 2 + 1 + 2 + 6 + 3 + 4

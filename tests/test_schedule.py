@@ -573,6 +573,32 @@ def test_cost_model_transpose_impl_split():
     assert any("/ring" in l for l in labels)
 
 
+def test_ring_moves_fewer_collective_bytes_than_alltoall():
+    """Compiled 64^3 forwards on the 2x4 pencil at K=1: the ring's
+    (2-1) + (4-1) collective-permute rounds move strictly fewer bytes
+    than the fused all-to-alls, because a rank's own piece never
+    crosses the wire."""
+    run_multidevice("""
+from repro.core import Croft3D, Decomposition, FFTOptions
+from repro.launch.mesh import make_mesh
+from repro.tuning import cost_model
+mesh = make_mesh((2, 4), ("y", "z"))
+dec = Decomposition("pencil", ("y", "z"))
+hlo = {impl: cost_model.hlo_collectives(Croft3D(
+           (64, 64, 64), mesh, dec,
+           FFTOptions(overlap_k=1, transpose_impl=impl,
+                      output_layout="spectral")))
+       for impl in ("alltoall", "ring")}
+ring, a2a = hlo["ring"], hlo["alltoall"]
+permutes = sum(v["count"] for k, v in ring["collectives"].items()
+               if "permute" in k)
+assert permutes == (2 - 1) + (4 - 1), ring["collectives"]
+assert ring["collective_bytes"] < a2a["collective_bytes"], (
+    ring["collective_bytes"], a2a["collective_bytes"])
+print("OK")
+""")
+
+
 # --- fused epilogue ----------------------------------------------------------
 
 def test_with_epilogue_structure():
@@ -625,7 +651,7 @@ def test_adjoint_mirrors_layouts_and_comm_volume():
     """The adjoint runs output-layout -> input-layout with the same
     transpose count and the same total moved bytes — the symbolic
     foundation under the ``_grad`` cost model and the backward-HLO
-    mirror gate in ``benchmarks.train_bench``."""
+    mirror gate in ``test_grad.py``."""
     shape = (32, 32, 32)
     cases = [
         (build_schedule(PENCIL, FFTOptions()), SIZES),

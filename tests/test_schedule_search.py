@@ -26,9 +26,9 @@ from repro.tuning.candidates import ScheduleCandidate, StageSpec
 SIZES = {"data": 2, "model": 4}
 PENCIL = Decomposition("pencil", ("data", "model"))
 
-# the gate-A shape: z so short that stage 0's chunk axis cannot split,
-# which is what makes mixed per-stage impls win (see benchmarks/
-# search_bench.py)
+# the gate shape: z so short that stage 0's chunk axis cannot split,
+# which is what makes mixed per-stage impls win (see
+# test_search_beats_every_fixed_builder_at_gate_point)
 GATE_SHAPE = (512, 512, 4)
 
 MIXED_KEY = ("sched:pencil[data,model]|k1/matmul/spectral/alltoall/"
@@ -253,6 +253,58 @@ def test_mixed_beats_homogeneous_at_gate_point():
          for c in (mixed, hom_ring, hom_a2a_k2)}
     assert t[mixed] < t[hom_ring]
     assert t[mixed] < t[hom_a2a_k2]
+
+
+@pytest.fixture(scope="module")
+def gate_rankings():
+    """Best fixed-builder plan and best searched pipeline at the gate
+    point, both priced by the same per-stage combine (fixed candidates
+    wrapped as schedule candidates)."""
+    fixed = [ScheduleCandidate.from_candidate(c)
+             for c in cand_lib.enumerate_candidates(GATE_SHAPE, SIZES)]
+    searched = cand_lib.enumerate_schedule_candidates(GATE_SHAPE, SIZES)
+    return (cost_model.rank_candidates(GATE_SHAPE, fixed, SIZES)[0],
+            cost_model.rank_candidates(GATE_SHAPE, searched, SIZES)[0])
+
+
+def test_search_beats_every_fixed_builder_at_gate_point(gate_rankings):
+    (_, c_fixed), (_, c_searched) = gate_rankings
+    assert c_searched.total_s < c_fixed.total_s
+
+
+def test_search_winner_is_fixed_inexpressible(gate_rankings):
+    """The winning pipeline is a point no fixed builder can emit."""
+    _, (best, _) = gate_rankings
+    assert best.as_options_candidate() is None, best.plan_key
+
+
+def test_search_winner_compiles_to_predicted_collectives(gate_rankings):
+    """The gate winner's structure compiled at (32, 32, 4) on the 2x4
+    mesh holds exactly the per-stage predicted collectives: ring stages
+    K_eff*(P-1) collective-permutes, alltoall stages K_eff all-to-alls.
+    A per-stage override the executor ignored would compile to the
+    homogeneous counts."""
+    _, (best, _) = gate_rankings
+    run_multidevice(f"""
+from repro.core import Croft3D
+from repro.launch import hlo_cost
+from repro.launch.mesh import make_mesh
+from repro.tuning import cost_model
+from repro.tuning.candidates import ScheduleCandidate
+shape = (32, 32, 4)
+axes = {SIZES!r}
+cand = ScheduleCandidate.from_plan_key({best.plan_key!r})
+cand.validate(shape, axes)
+pred = cost_model.predicted_collectives(cand.build_schedule(), shape, axes,
+                                        cand.opts)
+plan = Croft3D(shape, mesh=make_mesh(tuple(axes.values()), tuple(axes)),
+               schedule=cand)
+got = hlo_cost.analyze(plan.lower_forward().compile().as_text()).collectives
+got = {{k: int(v["count"]) for k, v in got.items() if v["count"]}}
+pred = {{k: v for k, v in pred.items() if v}}
+assert got == pred and "collective-permute" in got, (got, pred)
+print("OK", got)
+""")
 
 
 def test_fixed_candidate_costs_unchanged():

@@ -19,7 +19,7 @@ from repro.serve import (Batcher, PlanCache, TransformRequest,
                          TransformService, bucket_key, padded_size,
                          stack_and_pad)
 from repro.tuning import wisdom as wisdom_lib
-from conftest import run_multidevice
+from conftest import multidevice_report, run_multidevice
 
 N = 8
 
@@ -424,3 +424,57 @@ print("SERVE_MULTIDEVICE_OK")
 def test_service_multidevice_lifecycle():
     out = run_multidevice(_MULTIDEVICE_CODE, n_devices=8, timeout=480)
     assert "SERVE_MULTIDEVICE_OK" in out
+
+
+# --- batched dispatch amortizes collectives ---------------------------------
+
+_BATCHED_COLLECTIVES_CODE = """
+import json, os, tempfile
+import numpy as np, jax
+from repro.core import Croft3D, Decomposition
+from repro.launch import hlo_cost
+from repro.launch.mesh import make_mesh
+from repro.serve import PlanCache
+
+mesh = make_mesh((2, 4), ("y", "z"))
+cache = PlanCache(mesh, wisdom_path=os.path.join(tempfile.mkdtemp(), "w.json"))
+plans = {
+    # the plan the service itself would pick for a c2c request
+    "c2c": cache.get((32, 32, 32), np.complex64, "c2c").plan,
+    # packed r2c: its batched path is the native leading-batch pipeline
+    "r2c": Croft3D((32, 32, 32), mesh, Decomposition("pencil", ("y", "z")),
+                   problem="r2c", strategy="packed"),
+}
+
+def collectives(lowered):
+    return hlo_cost.analyze(lowered.compile().as_text()).collectives
+
+report = {}
+for name, plan in plans.items():
+    report[name] = {"single": collectives(plan.lower_forward())}
+    for b in (1, 4):
+        spec = jax.ShapeDtypeStruct((b,) + plan.shape, plan.input_dtype,
+                                    sharding=plan.batched_sharding("input"))
+        report[name][b] = collectives(plan._batched_fn("forward").lower(spec))
+print("REPORT " + json.dumps(report))
+"""
+
+
+@pytest.fixture(scope="module")
+def batched_collectives():
+    return multidevice_report(_BATCHED_COLLECTIVES_CODE)
+
+
+@pytest.mark.parametrize("problem", ["c2c", "r2c"])
+@pytest.mark.parametrize("batch", [1, 4])
+def test_batched_forward_compiles_to_single_request_collectives(
+        batched_collectives, problem, batch):
+    """A (B, ...) stacked forward compiles to exactly the single
+    request's collective ops, each moving B times the bytes: batching
+    amortizes launches, it never adds one."""
+    rec = batched_collectives[problem]
+    single, got = rec["single"], rec[str(batch)]
+    assert single and set(got) == set(single), (single, got)
+    for kind, op in single.items():
+        assert got[kind]["count"] == op["count"], (kind, single, got)
+        assert got[kind]["bytes"] == batch * op["bytes"], (kind, single, got)

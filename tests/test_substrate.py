@@ -1,5 +1,5 @@
-"""Substrate tests: optimizer, data pipeline, checkpointing, loss, fault
-tolerance, compression math."""
+"""Substrate tests: optimizer, data pipeline, checkpointing, loss,
+compression math."""
 
 import os
 import time
@@ -13,7 +13,6 @@ from repro.train.optimizer import (OptConfig, adamw_update, init_opt_state,
                                    schedule, global_norm)
 from repro.train.data import SyntheticDataset, Prefetcher, synth_tokens
 from repro.train.checkpoint import CheckpointManager
-from repro.train.fault import StragglerMonitor, PreemptionHandler
 from repro.parallel.loss import chunked_cross_entropy
 from repro.parallel.compression import (compress_residual, dequantize_int8,
                                         quantize_int8, topk_densify,
@@ -214,31 +213,6 @@ def test_chunked_ce_ignores_padding(rng):
     labels = jnp.asarray([[1, 2, 3, -1, -1, -1, -1, -1]])
     _, metrics = chunked_cross_entropy(hidden, labels, head, n_chunks=2)
     assert int(metrics["n_tokens"]) == 3
-
-
-# --------------------------------------------------------------------------
-# fault tolerance
-# --------------------------------------------------------------------------
-
-def test_straggler_monitor_flags_outlier():
-    mon = StragglerMonitor(z_threshold=3.0, warmup_steps=2)
-    flagged = []
-    mon.on_straggler = flagged.append
-    for i in range(10):
-        mon.start_step()
-        mon._t0 -= 0.1  # simulate 100ms step
-        mon.end_step(i)
-    mon.start_step()
-    mon._t0 -= 3.0      # 3s straggler
-    st = mon.end_step(99)
-    assert st.is_straggler and flagged and flagged[0].step == 99
-
-
-def test_preemption_handler_flag():
-    h = PreemptionHandler()
-    assert not h.preemption_requested
-    h._handle(15, None)
-    assert h.preemption_requested
 
 
 # --------------------------------------------------------------------------

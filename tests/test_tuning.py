@@ -366,45 +366,6 @@ def test_wisdom_cli_tolerates_grad_and_foreign_entries(tmp_path, capsys):
     assert "|grad" in out
 
 
-# --- calibrated collective constants -----------------------------------------
-
-def test_collective_constants_calibration_precedence(tmp_path, monkeypatch):
-    """(alpha, beta) precedence: live obs-registry gauges > the
-    ``$CROFT_CALIBRATION`` JSON > hardcoded roofline constants; a
-    non-positive fit is ignored rather than trusted."""
-    from repro.obs import metrics as metrics_lib
-    from repro.tuning import cost_model
-    reg = metrics_lib.get_registry()
-    ga = reg.gauge("collective_alpha_s")
-    gb = reg.gauge("collective_beta_s_per_byte")
-    old = (ga.value, gb.value)
-    ga.set(0.0)
-    gb.set(0.0)
-    monkeypatch.delenv(cost_model.CALIBRATION_ENV, raising=False)
-    try:
-        assert cost_model.collective_constants() == (
-            cost_model.COLLECTIVE_LATENCY_S, 1.0 / cost_model.PRIOR_LINK_BW)
-        path = str(tmp_path / "calibration.json")
-        with open(path, "w") as f:
-            json.dump({"collective_alpha_s": 3e-6,
-                       "collective_beta_s_per_byte": 2e-11}, f)
-        monkeypatch.setenv(cost_model.CALIBRATION_ENV, path)
-        assert cost_model.collective_constants() == (3e-6, 2e-11)
-        ga.set(5e-6)
-        gb.set(-1.0)  # degenerate lstsq fit: must fall through
-        assert cost_model.collective_constants() == (5e-6, 2e-11)
-        # the calibrated constants actually move the model
-        base = tuning.analytic_cost(SHAPE, tuning.Candidate(
-            Decomposition("pencil", ("data", "model")), FFTOptions()), SIZES)
-        ga.set(5e-3)
-        slow = tuning.analytic_cost(SHAPE, tuning.Candidate(
-            Decomposition("pencil", ("data", "model")), FFTOptions()), SIZES)
-        assert slow.latency_s > base.latency_s
-    finally:
-        ga.set(old[0])
-        gb.set(old[1])
-
-
 def test_candidate_label_distinguishes_overlap_mode():
     """Regression: the planner's measured={label: t} dict used to alias
     candidates differing only in overlap_mode."""
